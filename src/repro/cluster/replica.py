@@ -25,6 +25,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Optional
 
+import jax
 import numpy as np
 
 import dataclasses
@@ -163,7 +164,8 @@ class Replica:
                  pinned_lease: Optional[PinnedLease] = None,
                  fault_plan: Optional[FaultPlan] = None,
                  tenant_manager=None,
-                 context_budget=None, pinned_budget=None):
+                 context_budget=None, pinned_budget=None,
+                 device: Optional[jax.Device] = None):
         self.replica_id = replica_id
         self.tenant = tenant
         self.lease = lease
@@ -211,7 +213,7 @@ class Replica:
                       if self.cfg.staging_arena_bytes else None)
         self.gateway = TransferGateway(
             bridge, defaults, clock=self.clock,
-            pool_workers=lease.n_contexts, arena=self.arena)
+            pool_workers=lease.n_contexts, arena=self.arena, device=device)
         # in-tenant fabric transport (DESIGN.md §12): p2p crossings consult
         # the tenant's fabric-manager health and this replica's attestation
         # standing per crossing — lapsed evidence reprices the same bytes at

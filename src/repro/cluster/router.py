@@ -29,6 +29,8 @@ import dataclasses
 import enum
 from typing import Optional
 
+import jax
+
 from repro.core.bridge import TPU_V5E, BridgeModel, BridgeProfile
 from repro.obs import Observatory
 from repro.resilience import FaultPlan
@@ -334,6 +336,9 @@ def build_cluster(model, *, profile: BridgeProfile = TPU_V5E,
     replica; replica i draws from an independent stream at
     ``seed = fault_plan.seed + i`` so a fleet's faults decorrelate the way
     independent channels do.  None = fault-free (the default fast path).
+
+    Replica i serves on ``jax.devices()[i % len(jax.devices())]``: one
+    replica per chip on a four-chip host, all on the one device otherwise.
     """
     cfg = replica_cfg or ReplicaConfig()
     if cfg.tp_degree > partition_size:
@@ -349,6 +354,7 @@ def build_cluster(model, *, profile: BridgeProfile = TPU_V5E,
     budget = SecureContextBudget(profile, cc_on=cc_on)
     pinned = PinnedBudget(host_pinned_bytes)
     grants = budget.fair_share(n_replicas, cfg.contexts_requested)
+    devices = jax.devices()
     replicas = []
     for i in range(n_replicas):
         tenant = tm.provision(f"tenant-{i}", partition_size,
@@ -365,7 +371,8 @@ def build_cluster(model, *, profile: BridgeProfile = TPU_V5E,
         replicas.append(Replica(f"replica-{i}", model, tenant, lease, bridge,
                                 cfg, seed=seed + i, pinned_lease=pinned_lease,
                                 fault_plan=plan_i, tenant_manager=tm,
-                                context_budget=budget, pinned_budget=pinned))
+                                context_budget=budget, pinned_budget=pinned,
+                                device=devices[i % len(devices)]))
     return ClusterRouter(replicas, routing=routing,
                          max_cluster_queue=max_cluster_queue,
                          tenant_manager=tm, budget=budget,

@@ -53,8 +53,6 @@ COMPUTE_SPECS = {
     "tpu-v5e": ComputeSpec(peak_flops=197e12, hbm_bw=819e9),
 }
 
-DEFAULT_SPEC = COMPUTE_SPECS["tpu-v5e"]
-
 
 def spec_for_profile(profile_name: str) -> ComputeSpec:
     """Roofline for a bridge profile — unknown names are an error.

@@ -13,8 +13,9 @@ loader and the KV-offload policy move bytes across the bridge.  It
   * implements the CC-aware disciplines: small-crossing batching, drained
     submission, and context-pooled bulk transfers.
 
-On a real TPU deployment the virtual-clock charge is replaced by the actual
-transfer (the discipline is the same); here it lets every policy be costed.
+On every platform, a TPU included, the transfer is real and the charge is
+modelled: the virtual clock prices the bridge the profile describes, so
+every policy can be costed, and it is never a measured transfer time.
 """
 
 from __future__ import annotations

@@ -1,15 +1,1 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
-
-from jax.experimental.pallas import tpu as pltpu
-
-
-def tpu_compiler_params(**kwargs):
-    """Pallas-TPU compiler params across JAX versions.
-
-    The class was renamed TPUCompilerParams -> CompilerParams around
-    jax 0.4.3x/0.5; accept either spelling so the kernels run on both.
-    """
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
+"""Pallas TPU kernels of the serving path, each with a jnp reference."""

@@ -22,8 +22,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 
 #: grid tile: rows of quant blocks widened per step (lane dim is the
 #: 128-value block itself)
@@ -34,10 +34,7 @@ def _code_dtype(codec: str):
     if codec == "int8":
         return jnp.int8
     if codec == "fp8":
-        fp8 = getattr(jnp, "float8_e4m3fn", None)
-        if fp8 is None:
-            raise ValueError("float8_e4m3fn unavailable in this jax build")
-        return fp8
+        return jnp.float8_e4m3fn
     raise ValueError(f"unknown codec {codec!r}")
 
 
@@ -63,7 +60,7 @@ def dequant_kernel(codes: jax.Array, scales: jax.Array, *, codec: str,
         ],
         out_specs=pl.BlockSpec((ROW_TILE, block), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nblocks, block), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(codes, scales)

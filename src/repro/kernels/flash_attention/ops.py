@@ -1,8 +1,8 @@
 """jit'd public wrapper for the flash-attention kernel.
 
-On TPU backends this dispatches to the Pallas kernel; elsewhere (this CPU
-container) it runs the kernel in interpret mode when small enough, falling
-back to the oracle for shapes where interpretation would be pathological.
+On TPU backends this dispatches to the compiled Pallas kernel.  Elsewhere
+it runs the jnp oracle, or, with ``force_kernel``, the kernel in interpret
+mode (the CPU tests compare the two).
 """
 
 from __future__ import annotations

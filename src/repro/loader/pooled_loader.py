@@ -34,7 +34,7 @@ from repro.core.bridge import BridgeModel, Crossing, Direction, StagingKind
 from repro.core.channels import SecureChannelPool, VirtualClock
 from repro.core.gateway import TransferGateway
 from repro.trace import opclasses as oc
-from .sharded_weights import ShardedCheckpoint, _np_dtype
+from .sharded_weights import ShardedCheckpoint
 
 GB = 1e9
 
@@ -185,7 +185,7 @@ class PooledLoader:
         wire = 0
         for name in ckpt.shard_tensors(shard):
             meta = ckpt.index["tensors"][name]
-            dt = _np_dtype(meta["dtype"])
+            dt = np.dtype(meta["dtype"])
             count = int(np.prod(meta["shape"])) if meta["shape"] else 1
             wire += quant_wire(count * dt.itemsize, itemsize=dt.itemsize)
         return wire

@@ -13,19 +13,8 @@ import os
 from dataclasses import dataclass
 from typing import Iterator
 
+import ml_dtypes  # noqa: F401  (registers bfloat16 etc. with numpy)
 import numpy as np
-
-try:
-    import ml_dtypes
-except ImportError:  # pragma: no cover
-    ml_dtypes = None
-
-
-def _np_dtype(name: str):
-    try:
-        return np.dtype(name)
-    except TypeError:
-        return np.dtype(getattr(ml_dtypes, name))
 
 
 def save_sharded(out_dir: str, tensors: dict, *, n_shards: int = 4) -> None:
@@ -71,7 +60,7 @@ class ShardedCheckpoint:
 
     def read_tensor(self, name: str) -> np.ndarray:
         meta = self.index["tensors"][name]
-        dtype = _np_dtype(meta["dtype"])
+        dtype = np.dtype(meta["dtype"])
         count = int(np.prod(meta["shape"])) if meta["shape"] else 1
         with open(os.path.join(self.path, f"shard_{meta['shard']}.bin"), "rb") as f:
             f.seek(meta["offset"])

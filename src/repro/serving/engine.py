@@ -24,6 +24,7 @@ Policy structure per decode step (paper §5):
 from __future__ import annotations
 
 import dataclasses
+import functools
 import queue
 import threading
 import time
@@ -43,7 +44,7 @@ from repro.core.gateway import TransferGateway
 from repro.core.policy import RuntimeDefaults, SchedulingPolicy, cc_aware_defaults
 from repro.obs import Observatory
 from repro.trace import opclasses as oc
-from repro.models.model import Model
+from repro.models.model import Model, prefill
 from .kv_cache import RaggedBatch, gather_slot_cache, scatter_slot_cache
 from .overlap import OverlapScheduler
 from .sampler import SamplingParams, sample
@@ -88,6 +89,28 @@ class StepTrace:
     #: §10); 0 on legacy dense steps — so `packed == active` on every packed
     #: step and the trace distinguishes the two execution shapes
     packed: int = 0
+
+
+@functools.partial(jax.jit, static_argnums=(0, 3))
+def prefill_logits_cache(cfg, params, tokens, max_len: int):
+    """One compiled prompt pass: (last-position logits, max_len cache).
+
+    Shared by every engine: jit keys on (cfg, max_len, prompt shape,
+    device), so each prompt length compiles once per process.  The next
+    cache index is the prompt length."""
+    logits, caches, _ = prefill(params, cfg, {"tokens": tokens}, max_len)
+    return logits, caches
+
+
+def packed_step(model: Model, params, caches, tokens, index, slots):
+    """Packed ragged decode (DESIGN.md §10): gather the packed rows out of
+    the resident cache, decode at the packed width, scatter them back."""
+    packed = gather_slot_cache(caches, slots,
+                               scan_layers=model.cfg.scan_layers)
+    logits, packed = model.decode_step(params, packed, tokens, index)
+    caches = scatter_slot_cache(caches, packed, slots,
+                                scan_layers=model.cfg.scan_layers)
+    return logits, caches
 
 
 class ServingEngine:
@@ -147,8 +170,13 @@ class ServingEngine:
         if self.obs is not None:
             self.obs.attach_gateway(self.gateway)
 
-        self.params = model.init(jax.random.PRNGKey(seed))
-        self.caches = model.init_cache(max_batch, max_len)
+        #: params and cache are built on the default device, so one seed
+        #: gives the same bits everywhere, then committed to the gateway's
+        #: device: every jitted step runs on that chip (one replica per chip)
+        self.device = self.gateway.device
+        self.params, self.caches = jax.device_put(
+            (model.init(jax.random.PRNGKey(seed)),
+             model.init_cache(max_batch, max_len)), self.device)
         self.key = jax.random.PRNGKey(seed + 1)
 
         self.free_slots = list(range(max_batch))
@@ -157,6 +185,11 @@ class ServingEngine:
         self.finished: list[Request] = []
         self.trace: list[StepTrace] = []
         self.step_count = 0
+        #: shapes this engine has already executed; a step that meets a new
+        #: one (prompt length, packed width) compiles, and sets
+        #: ``step_compiled`` so the scheduler does not time it as a straggler
+        self._seen_shapes: set = set()
+        self.step_compiled = False
         self._worker: Optional[threading.Thread] = None
         self._drain_q: "queue.Queue" = queue.Queue()
         #: worker-drain join deadline at close(); a thread that outlives it
@@ -166,13 +199,11 @@ class ServingEngine:
         self.closed_dirty = False
         self._decode = jax.jit(
             lambda p, c, t, i: self.model.decode_step(p, c, t, i))
-        # packed ragged decode (DESIGN.md §10): gather the packed rows out
-        # of the resident cache, run the forward at the packed width, and
-        # scatter the updated rows back.  jit caches one trace per packed
-        # width; `_bucket` pads widths to powers of two so the trace count
-        # stays O(log max_batch) even when the ready set raggedly shrinks
-        # slot by slot as requests finish.
-        self._packed_decode = jax.jit(self._packed_step)
+        # packed ragged decode: jit caches one trace per packed width;
+        # `_bucket` pads widths to powers of two so the trace count stays
+        # O(log max_batch) even when the ready set raggedly shrinks slot by
+        # slot as requests finish.
+        self._packed_decode = jax.jit(functools.partial(packed_step, model))
 
         # worker x coalescer composition: with a coalescer the drains queue
         # and the worker's seat becomes a secure channel the fused flushes
@@ -185,13 +216,10 @@ class ServingEngine:
             else:
                 self.gateway.pool.prewarm()
 
-    def _packed_step(self, params, caches, tokens, index, slots):
-        packed = gather_slot_cache(caches, slots,
-                                   scan_layers=self.cfg.scan_layers)
-        logits, packed = self.model.decode_step(params, packed, tokens, index)
-        caches = scatter_slot_cache(caches, packed, slots,
-                                    scan_layers=self.cfg.scan_layers)
-        return logits, caches
+    def _note_shape(self, key: tuple) -> None:
+        if key not in self._seen_shapes:
+            self._seen_shapes.add(key)
+            self.step_compiled = True
 
     def _bucket(self, n: int) -> int:
         """Smallest power-of-two >= n, capped at max_batch: the executed
@@ -326,9 +354,10 @@ class ServingEngine:
             self.coalescer.h2d(prompt, op_class=oc.PROMPT_H2D)
         else:
             self.gateway.h2d(prompt, op_class=oc.PROMPT_H2D)
-        batch = {"tokens": jnp.asarray(prompt)}
-        logits, pre_cache, idx0 = self.model.prefill(
-            self.params, batch, max_len=self.max_len)
+        self._note_shape(("prefill", prompt.shape[1]))
+        logits, pre_cache = prefill_logits_cache(
+            self.cfg, self.params, jnp.asarray(prompt), self.max_len)
+        idx0 = prompt.shape[1]
         # prompt processing is device compute, charged like any interval;
         # warm (restored / externally-priced) tokens skip the forward
         if self.compute is not None:
@@ -515,6 +544,7 @@ class ServingEngine:
         ``max_batch``.  Token streams are byte-identical to the dense path
         under greedy decode.
         """
+        self.step_compiled = False
         self._sync_ladder()
         self._admit()
         if not self.active:
@@ -557,6 +587,7 @@ class ServingEngine:
 
         self._whole_batch_barrier(slots)
 
+        self._note_shape(("dense",))
         logits, self.caches = self._decode(
             self.params, self.caches, jnp.asarray(tokens), jnp.asarray(index))
         # the forward+sample is a first-class clock charge: this is what
@@ -660,6 +691,7 @@ class ServingEngine:
             exec_tokens = np.concatenate(
                 [tokens, np.repeat(tokens[:1], pad, axis=0)])
             exec_index = np.concatenate([index, np.repeat(index[:1], pad)])
+        self._note_shape(("packed", width))
         logits, self.caches = self._packed_decode(
             self.params, self.caches, jnp.asarray(exec_tokens),
             jnp.asarray(exec_index), jnp.asarray(exec_slots))

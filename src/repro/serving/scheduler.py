@@ -5,7 +5,9 @@ deployment needs at thousand-node scale.
 Straggler policy: a request whose per-step wall time exceeds
 `straggler_factor` x the fleet EMA for `patience` consecutive steps is
 preempted and requeued (its slot freed) — the serving-side analogue of the
-train loop's straggler watchdog.  Preemption also fires on pool exhaustion:
+train loop's straggler watchdog.  A step that compiled (the engine met a
+new prompt length or packed width) is not timed: compile time says nothing
+about the requests in the batch.  Preemption also fires on pool exhaustion:
 newest requests yield pages first (LIFO), matching vLLM semantics.
 """
 
@@ -49,8 +51,8 @@ class Scheduler:
         t0 = time.perf_counter()
         stepped = self.engine.step()
         dt = time.perf_counter() - t0
-        if stepped == 0:
-            return 0
+        if stepped == 0 or self.engine.step_compiled:
+            return stepped
         self._ema_dt = dt if self._ema_dt is None else \
             0.9 * self._ema_dt + 0.1 * dt
         if self._ema_dt and dt > self.cfg.straggler_factor * self._ema_dt:

@@ -30,12 +30,8 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+import ml_dtypes  # noqa: F401  (registers bfloat16 etc. with numpy)
 import numpy as np
-
-try:
-    import ml_dtypes  # registers bfloat16 etc. with numpy
-except ImportError:  # pragma: no cover
-    ml_dtypes = None
 
 _PENDING: list[threading.Thread] = []
 
@@ -51,13 +47,6 @@ def _leaf_paths(tree) -> list[tuple[str, Any]]:
 
 def _dtype_name(x) -> str:
     return str(x.dtype)
-
-
-def _np_dtype(name: str):
-    try:
-        return np.dtype(name)
-    except TypeError:
-        return np.dtype(getattr(ml_dtypes, name))
 
 
 def save(ckpt_dir: str, params, opt_state, step: int, *,
@@ -121,7 +110,7 @@ def _load_tree(d: str, template, manifest, prefix: str):
         key, leaf = key_leaf
         meta = leaves_meta[f"{prefix}/{key}" if key else prefix]
         with open(os.path.join(d, meta["file"]), "rb") as f:
-            arr = np.frombuffer(f.read(), dtype=_np_dtype(meta["dtype"])).reshape(meta["shape"])
+            arr = np.frombuffer(f.read(), dtype=np.dtype(meta["dtype"])).reshape(meta["shape"])
         if hasattr(leaf, "sharding") and leaf.sharding is not None:
             try:
                 return jax.device_put(arr, leaf.sharding)
@@ -156,7 +145,7 @@ def _manifest_subtree(d: str, manifest, prefix: str):
         if not key.startswith(prefix + "/") and key != prefix:
             continue
         with open(os.path.join(d, meta["file"]), "rb") as f:
-            arr = np.frombuffer(f.read(), dtype=_np_dtype(meta["dtype"])).reshape(meta["shape"])
+            arr = np.frombuffer(f.read(), dtype=np.dtype(meta["dtype"])).reshape(meta["shape"])
         parts = key[len(prefix) + 1:].split("/") if key != prefix else []
         node = root
         for p in parts[:-1]:

@@ -1,6 +1,12 @@
 """Cluster subsystem: budget, tenant manager, router, autoscaler, inventory
 exports, and an end-to-end two-tenant serving run."""
 
+import os
+import subprocess
+import sys
+import time
+from pathlib import Path
+
 import pytest
 
 from repro.cluster import (Autoscaler, AutoscalerConfig, BudgetExhausted,
@@ -17,6 +23,8 @@ from repro.serving.engine import Request
 from repro.serving.kv_cache import PagePool
 from repro.serving.offload import OffloadManager
 from repro.serving.sampler import SamplingParams
+
+SRC = Path(__file__).resolve().parents[1] / "src"
 
 
 class TestSecureContextBudget:
@@ -261,7 +269,49 @@ def tiny_model():
     return Model(smoke_config(all_configs()["olmo-1b"]))
 
 
+_FOUR_DEVICE_CLUSTER = """
+import jax
+from repro.cluster import build_cluster
+from repro.configs.base import get_config, smoke_config
+from repro.models.model import Model
+from repro.serving.engine import Request
+from repro.serving.sampler import SamplingParams
+
+cluster = build_cluster(Model(smoke_config(get_config("olmo-1b"))),
+                        n_replicas=4, partition_size=1)
+last = cluster.replicas[-1]
+last.submit(Request("r0", prompt=[1, 2, 3, 4, 5],
+                    sampling=SamplingParams(max_new_tokens=2)))
+assert cluster.run()["finished"] == 1 and last.engine.finished
+for r in cluster.replicas:
+    homes = {d for leaf in jax.tree.leaves((r.engine.params, r.engine.caches))
+             for d in leaf.devices()}
+    print(r.replica_id, *sorted(map(str, homes)))
+cluster.close()
+"""
+
+
 class TestClusterEndToEnd:
+    def test_replicas_land_on_their_own_devices(self):
+        """On a four-device host each replica's params and cache sit on
+        its own device, and the last replica's stay there after it serves
+        a request."""
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   XLA_FLAGS="--xla_force_host_platform_device_count=4",
+                   PYTHONPATH=os.pathsep.join(
+                       [str(SRC), os.environ.get("PYTHONPATH", "")]))
+        t0 = time.perf_counter()
+        out = subprocess.run([sys.executable, "-c", _FOUR_DEVICE_CLUSTER],
+                             env=env, capture_output=True, text=True,
+                             timeout=120)
+        elapsed = time.perf_counter() - t0
+        assert out.returncode == 0, out.stderr[-2000:]
+        rows = [line.split() for line in out.stdout.splitlines()]
+        assert len(rows) == 4
+        assert all(len(row) == 2 for row in rows), rows   # one device each
+        assert len({row[1] for row in rows}) == 4, rows
+        assert elapsed < 30.0, f"{elapsed:.1f} s"
+
     def test_two_tenants_serve_concurrently_and_stay_isolated(self, tiny_model):
         cluster = build_cluster(tiny_model, cc_on=True, n_replicas=2,
                                 partition_size=2,

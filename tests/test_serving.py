@@ -8,6 +8,7 @@ from repro.configs.base import all_configs, smoke_config
 from repro.core.bridge import B300, RTX_PRO_6000, TPU_V5E, BridgeModel
 from repro.core.gateway import TransferGateway
 from repro.core.policy import OffloadPolicy, SchedulingPolicy as SP, cc_aware_defaults
+from repro.launch.serve import greedy_reference, seeded_prompts, serve
 from repro.loader.pooled_loader import LoaderVariant, PooledLoader
 from repro.loader.sharded_weights import ShardedCheckpoint, save_sharded
 from repro.models.model import Model
@@ -81,10 +82,33 @@ class TestEngine:
         assert stats["finished"] == 8
 
 
+class TestServeEntryPoint:
+    """The contract chip_smoke.py checks on the chip, at smoke size."""
+
+    def test_engine_tokens_equal_straight_line_loop(self):
+        cfg = smoke_config(all_configs()["olmo-1b"])
+        # four prompts fill the four slots at once, so every packed step
+        # holds the same rows as the reference's fixed batch
+        prompts = seeded_prompts(4, cfg.vocab_size, min_len=4, max_len=40,
+                                 seed=1)
+        stats, finished = serve(cfg, prompts, max_batch=4, max_len=96,
+                                max_new_tokens=8, seed=3, cc_on=True)
+        assert stats["finished"] == len(prompts)
+        assert stats["preemptions"] == 0
+        assert not stats["closed_dirty"]
+        assert [r.prompt for r in finished] == prompts
+        ref, finite = greedy_reference(
+            cfg, Model(cfg).init(jax.random.PRNGKey(3)), prompts,
+            max_new_tokens=8, max_len=96)
+        assert finite
+        assert [r.output_tokens for r in finished] == ref
+
+
 class _FakeEngine:
     """Minimal engine surface for deterministic Scheduler tests."""
 
     def __init__(self, requests):
+        self.step_compiled = False
         self.queue = []
         self.active = {i: r for i, r in enumerate(requests)}
         for r in self.active.values():
@@ -133,6 +157,47 @@ class TestSchedulerPreemption:
         assert sched.preemptions == 1
         assert eng.released == [("new", "preempted")]   # newest, not oldest
         assert eng.queue and eng.queue[0].request_id == "new"
+
+    def test_compiling_steps_are_not_stragglers(self, monkeypatch):
+        """Ticks on which the engine compiled are neither timed into the
+        EMA nor counted toward a straggler streak."""
+        eng = _FakeEngine([_running_request("old", 0.0),
+                           _running_request("new", 5.0)])
+        sched = Scheduler(eng, SchedulerConfig(straggler_factor=4.0,
+                                               patience=1))
+        times = iter([0.0, 1.0,           # dt=1     (EMA seed)
+                      2.0, 102.0,         # dt=100   compiled
+                      110.0, 1110.0,      # dt=1000  compiled
+                      1200.0, 1201.0])    # dt=1
+        monkeypatch.setattr("repro.serving.scheduler.time.perf_counter",
+                            lambda: next(times))
+        for compiled in (False, True, True, False):
+            eng.step_compiled = compiled
+            sched.tick()
+        assert sched.preemptions == 0
+        assert sched._ema_dt == pytest.approx(1.0)
+
+    def test_engine_flags_steps_that_meet_a_new_shape(self, tiny_model):
+        eng = ServingEngine(tiny_model, max_batch=4, max_len=64)
+        flags = []
+
+        def step():
+            eng.step()
+            flags.append(eng.step_compiled)
+
+        eng.submit(Request("a", prompt=[1, 2, 3],
+                           sampling=SamplingParams(max_new_tokens=6)))
+        step()                            # new prompt length + width 1
+        step()                            # same shapes
+        eng.submit(Request("b", prompt=[4, 5, 6],
+                           sampling=SamplingParams(max_new_tokens=6)))
+        step()                            # width 2 is new
+        eng.submit(Request("c", prompt=[7, 8],
+                           sampling=SamplingParams(max_new_tokens=6)))
+        step()                            # new prompt length, width 4 new
+        step()                            # same shapes
+        eng.close()
+        assert flags == [True, False, True, True, False]
 
     def test_pool_exhaustion_preempts_lifo(self):
         """Newest requests yield pages first; oldest survive (vLLM order)."""
