@@ -46,6 +46,8 @@ import jax
 import numpy as np
 
 from repro.core.bridge import Crossing, Direction, StagingKind
+from repro.core.gateway import device_put, to_host
+from repro.obs import wall
 from repro.trace import opclasses as oc
 
 if TYPE_CHECKING:  # pragma: no cover
@@ -165,7 +167,7 @@ class CrossingCoalescer:
             dev = self.gateway.h2d(arr, op_class=op_class, reuse_staging=True)
             self.poll()   # the passthrough charge moved the clock
             return dev
-        dev = jax.device_put(arr, self.gateway.device)
+        dev = device_put(arr, self.gateway.device, op_class)
         self._enqueue(nbytes, Direction.H2D, op_class)
         return dev
 
@@ -182,7 +184,7 @@ class CrossingCoalescer:
             host = self.gateway.d2h(device_array, op_class=op_class)
             self.poll()   # the passthrough charge moved the clock
             return host
-        host = np.asarray(device_array)
+        host = to_host(device_array, op_class, nbytes)
         self._enqueue(nbytes, Direction.D2H, op_class)
         return host
 
@@ -198,16 +200,18 @@ class CrossingCoalescer:
         self._enqueue(nbytes, direction, op_class)
 
     def _enqueue(self, nbytes: int, direction: Direction, op_class: str) -> None:
-        self.poll()                 # aged queues flush before the append
-        q = self._q[direction]
-        q.append(_Pending(nbytes, op_class, self.gateway.clock.now))
-        self.stats.queued += 1
-        self.stats.queued_bytes += nbytes
-        self.stats.max_queue_depth = max(self.stats.max_queue_depth, len(q))
-        if self.pending_bytes(direction) >= self.watermark_bytes:
-            self.flush(direction, trigger="watermark")
-        elif len(q) >= self.max_queued:
-            self.flush(direction, trigger="queue_cap")
+        with wall.span("account", what="coalesce"):
+            self.poll()             # aged queues flush before the append
+            q = self._q[direction]
+            q.append(_Pending(nbytes, op_class, self.gateway.clock.now))
+            self.stats.queued += 1
+            self.stats.queued_bytes += nbytes
+            self.stats.max_queue_depth = max(self.stats.max_queue_depth,
+                                             len(q))
+            if self.pending_bytes(direction) >= self.watermark_bytes:
+                self.flush(direction, trigger="watermark")
+            elif len(q) >= self.max_queued:
+                self.flush(direction, trigger="queue_cap")
 
     def poll(self, *, source: str = "clock") -> float:
         """Fire the deadline trigger against the current virtual clock.
@@ -222,12 +226,13 @@ class CrossingCoalescer:
         waiting for that slot to submit again).  Returns the bridge time
         charged to the engine clock.
         """
-        self.stats.polls[source] = self.stats.polls.get(source, 0) + 1
-        charged = 0.0
-        now = self.gateway.clock.now
-        for d, q in self._q.items():
-            if q and now - q[0].enqueued_t >= self.deadline_s:
-                charged += self.flush(d, trigger="deadline")
+        with wall.span("account", what="coalescer_poll"):
+            self.stats.polls[source] = self.stats.polls.get(source, 0) + 1
+            charged = 0.0
+            now = self.gateway.clock.now
+            for d, q in self._q.items():
+                if q and now - q[0].enqueued_t >= self.deadline_s:
+                    charged += self.flush(d, trigger="deadline")
         return charged
 
     # -- flush -------------------------------------------------------------------------

@@ -27,6 +27,7 @@ import jax
 import numpy as np
 
 from repro.core.bridge import Crossing, Direction, StagingKind
+from repro.core.gateway import device_put
 from repro.trace import opclasses as oc
 
 if TYPE_CHECKING:  # pragma: no cover
@@ -69,7 +70,7 @@ def pipelined_h2d(gateway: "TransferGateway", payloads: Sequence[np.ndarray], *,
     total = sum(int(np.asarray(p).nbytes) for p in payloads)
     t0 = gateway.clock.now
     if total == 0:
-        return [jax.device_put(np.asarray(p), gateway.device) for p in payloads], \
+        return [device_put(p, gateway.device, op_class) for p in payloads], \
             PipelinedRestoreResult(0, 0, 0.0, t0, 0.0)
 
     gateway.pool.ensure_ready()
@@ -101,7 +102,7 @@ def pipelined_h2d(gateway: "TransferGateway", payloads: Sequence[np.ndarray], *,
     gateway.clock.advance_to(first_done)
     fill = gateway.clock.now - t0
     gateway.stats.bridge_time_s += fill
-    arrays = [jax.device_put(np.asarray(p), gateway.device) for p in payloads]
+    arrays = [device_put(p, gateway.device, op_class) for p in payloads]
     return arrays, PipelinedRestoreResult(
         n_chunks=n_chunks, total_bytes=total, fill_s=fill, done_t=last_done,
         overlap_s=max(0.0, last_done - first_done))

@@ -16,6 +16,11 @@ loader and the KV-offload policy move bytes across the bridge.  It
 On every platform, a TPU included, the transfer is real and the charge is
 modelled: the virtual clock prices the bridge the profile describes, so
 every policy can be costed, and it is never a measured transfer time.
+What the real transfers cost in wall time is what the wall-clock spans
+(``repro.obs.wall``) measure: each crossing's pricing and record run under
+``account``, each ``device_put`` under ``bridge.h2d``, and a drain under
+``bridge.wait`` (the device finishing the array) then ``bridge.d2h`` (the
+copy).
 """
 
 from __future__ import annotations
@@ -36,6 +41,29 @@ def _nbytes(x: Any) -> int:
     if hasattr(x, "nbytes"):
         return int(x.nbytes)
     return int(np.asarray(x).nbytes)
+
+
+def device_put(host: Any, device: jax.Device, op_class: str) -> jax.Array:
+    """One real host-to-device transfer, under a ``bridge.h2d`` span."""
+    host = np.asarray(host)
+    with wall.span("bridge.h2d", op_class=op_class, nbytes=int(host.nbytes)):
+        return jax.device_put(host, device)
+
+
+def to_host(device_array: Any, op_class: str,
+            nbytes: Optional[int] = None) -> np.ndarray:
+    """One real device-to-host copy: the wait for the array to be ready
+    (``bridge.wait``; ``np.asarray`` would block there anyway), then the
+    copy (``bridge.d2h``).  The copy is queued behind the array's producer
+    before the wait, as ``np.asarray`` alone would, so splitting the two
+    adds no round trip to the device."""
+    with wall.span("bridge.wait"):
+        if hasattr(device_array, "block_until_ready"):
+            device_array.copy_to_host_async()
+            device_array.block_until_ready()
+    with wall.span("bridge.d2h", op_class=op_class,
+                   nbytes=_nbytes(device_array) if nbytes is None else nbytes):
+        return np.asarray(device_array)
 
 
 @dataclass
@@ -137,14 +165,17 @@ class TransferGateway:
             reuse_staging: bool = True) -> jax.Array:
         """One host-to-device crossing: real device_put + bridge-law charge."""
         arr = np.asarray(host_array)
-        staging, tags = self._staging_kind(arr.shape, arr.dtype, int(arr.nbytes),
-                                           reuse_staging=reuse_staging)
-        crossing = Crossing(int(arr.nbytes), Direction.H2D, staging)
-        cost = self.bridge.crossing_time(crossing, n_contexts=self.pool.n_workers)
-        cost = self._faulted_cost(op_class, crossing, cost)
-        end = self.clock.advance(cost)
-        self._record(crossing, cost, op_class, t_end=end, tags=tags)
-        return jax.device_put(arr, self.device)
+        with wall.span("account", what="h2d"):
+            staging, tags = self._staging_kind(
+                arr.shape, arr.dtype, int(arr.nbytes),
+                reuse_staging=reuse_staging)
+            crossing = Crossing(int(arr.nbytes), Direction.H2D, staging)
+            cost = self.bridge.crossing_time(crossing,
+                                             n_contexts=self.pool.n_workers)
+            cost = self._faulted_cost(op_class, crossing, cost)
+            end = self.clock.advance(cost)
+            self._record(crossing, cost, op_class, t_end=end, tags=tags)
+        return device_put(arr, self.device, op_class)
 
     def d2h(self, device_array: jax.Array, *, op_class: str = "d2h",
             tags: tuple = (), raw_bytes: int = 0,
@@ -160,18 +191,20 @@ class TransferGateway:
         staging buffer, so drains stay REGISTERED.
         """
         nbytes = _nbytes(device_array)
-        if self.arena is not None:
-            staging, tag = self.arena.acquire(nbytes)
-            tags = tuple(tags) + (tag,)
-        else:
-            staging = StagingKind.REGISTERED
-        crossing = Crossing(nbytes, Direction.D2H, staging)
-        cost = self.bridge.crossing_time(crossing, n_contexts=self.pool.n_workers)
-        cost = self._faulted_cost(op_class, crossing, cost)
-        end = self.clock.advance(cost)
-        self._record(crossing, cost, op_class, t_end=end, tags=tags,
-                     raw_bytes=raw_bytes, codec=codec)
-        return np.asarray(device_array)
+        with wall.span("account", what="d2h"):
+            if self.arena is not None:
+                staging, tag = self.arena.acquire(nbytes)
+                tags = tuple(tags) + (tag,)
+            else:
+                staging = StagingKind.REGISTERED
+            crossing = Crossing(nbytes, Direction.D2H, staging)
+            cost = self.bridge.crossing_time(crossing,
+                                             n_contexts=self.pool.n_workers)
+            cost = self._faulted_cost(op_class, crossing, cost)
+            end = self.clock.advance(cost)
+            self._record(crossing, cost, op_class, t_end=end, tags=tags,
+                         raw_bytes=raw_bytes, codec=codec)
+        return to_host(device_array, op_class, nbytes)
 
     def batch_h2d(self, host_arrays: Sequence[np.ndarray], *,
                   op_class: str = "batch_h2d") -> list[jax.Array]:
@@ -189,21 +222,24 @@ class TransferGateway:
             # against the batched path measures *batching*, not staging abuse
             return [self.h2d(a, op_class=op_class, reuse_staging=True)
                     for a in host_arrays]
-        total = sum(_nbytes(a) for a in host_arrays)
-        if self.arena is not None:
-            staging, tag = self.arena.acquire(total)
-            tags: tuple[str, ...] = (tag,)
-        else:
-            staging, tags = StagingKind.REGISTERED, ()
-        crossing = Crossing(total, Direction.H2D, staging)
-        cost = self.bridge.crossing_time(crossing, n_contexts=self.pool.n_workers)
-        # one fused ciphertext: any constituent MAC reject re-pays the batch
-        cost = self._faulted_cost(op_class, crossing, cost,
-                                  n_units=len(host_arrays))
-        end = self.clock.advance(cost)
-        self._record(crossing, cost, op_class, t_end=end, tags=tags)
-        self.stats.batched_crossings_saved += len(host_arrays) - 1
-        return [jax.device_put(np.asarray(a), self.device) for a in host_arrays]
+        with wall.span("account", what="batch_h2d"):
+            total = sum(_nbytes(a) for a in host_arrays)
+            if self.arena is not None:
+                staging, tag = self.arena.acquire(total)
+                tags: tuple[str, ...] = (tag,)
+            else:
+                staging, tags = StagingKind.REGISTERED, ()
+            crossing = Crossing(total, Direction.H2D, staging)
+            cost = self.bridge.crossing_time(crossing,
+                                             n_contexts=self.pool.n_workers)
+            # one fused ciphertext: any constituent MAC reject re-pays the
+            # batch
+            cost = self._faulted_cost(op_class, crossing, cost,
+                                      n_units=len(host_arrays))
+            end = self.clock.advance(cost)
+            self._record(crossing, cost, op_class, t_end=end, tags=tags)
+            self.stats.batched_crossings_saved += len(host_arrays) - 1
+        return [device_put(a, self.device, op_class) for a in host_arrays]
 
     def bulk_h2d_pooled(self, host_arrays: Sequence[np.ndarray], *,
                         op_class: str = "bulk_h2d", tags: tuple = (),
@@ -216,22 +252,22 @@ class TransferGateway:
         while each record additionally carries the full-width byte count and
         codec id for the un-quantize replay counterfactual (DESIGN.md §13).
         """
-        self.pool.ensure_ready()
-        out = []
-        before = self.clock.now
-        for i, a in enumerate(host_arrays):
-            crossing = Crossing(_nbytes(a), Direction.H2D, StagingKind.REGISTERED)
-            ctx_id, start, done = self.pool.submit_ex(crossing)
-            raw_i = raw_bytes[i] if raw_bytes else 0
-            # per-crossing record carries its single-channel duration; the
-            # wall-clock charge comes from the drain below
-            self._record(crossing, done - start, op_class, charge=False,
-                         channel=ctx_id, t_end=done, tags=tags,
-                         raw_bytes=raw_i, codec=codec if raw_i else "")
-            out.append(jax.device_put(np.asarray(a), self.device))
-        self.pool.drain()
-        self.stats.bridge_time_s += self.clock.now - before
-        return out
+        with wall.span("account", what="bulk_h2d"):
+            self.pool.ensure_ready()
+            before = self.clock.now
+            for i, a in enumerate(host_arrays):
+                crossing = Crossing(_nbytes(a), Direction.H2D,
+                                    StagingKind.REGISTERED)
+                ctx_id, start, done = self.pool.submit_ex(crossing)
+                raw_i = raw_bytes[i] if raw_bytes else 0
+                # per-crossing record carries its single-channel duration;
+                # the virtual-clock charge comes from the drain below
+                self._record(crossing, done - start, op_class, charge=False,
+                             channel=ctx_id, t_end=done, tags=tags,
+                             raw_bytes=raw_i, codec=codec if raw_i else "")
+            self.pool.drain()
+            self.stats.bridge_time_s += self.clock.now - before
+        return [device_put(a, self.device, op_class) for a in host_arrays]
 
     def pooled_crossing(self, crossing: Crossing, *, op_class: str,
                         tags: tuple = (), sources: tuple = (),
@@ -245,10 +281,11 @@ class TransferGateway:
         and the worker-composed coalescer flushes its D2H queue here so the
         drain serializes on a worker channel instead of the engine clock.
         """
-        ctx_id, start, done = self.pool.submit_ex(crossing)
-        self._record(crossing, done - start, op_class, charge=False,
-                     channel=ctx_id, t_end=done, tags=tags, sources=sources,
-                     raw_bytes=raw_bytes, codec=codec)
+        with wall.span("account", what="pooled_crossing"):
+            ctx_id, start, done = self.pool.submit_ex(crossing)
+            self._record(crossing, done - start, op_class, charge=False,
+                         channel=ctx_id, t_end=done, tags=tags,
+                         sources=sources, raw_bytes=raw_bytes, codec=codec)
         return ctx_id, start, done
 
     def charge_crossing(self, nbytes: int, direction: Direction, *,
@@ -264,14 +301,17 @@ class TransferGateway:
         hand-rolling stats so the crossing still lands in the tape with a
         consistent interval.
         """
-        crossing = Crossing(int(nbytes), direction, staging)
-        cost = self.bridge.crossing_time(crossing, n_contexts=self.pool.n_workers)
-        # a coalesced flush is one ciphertext over len(sources) constituents
-        cost = self._faulted_cost(op_class, crossing, cost,
-                                  n_units=max(1, len(sources)))
-        end = self.clock.advance(cost)
-        self._record(crossing, cost, op_class, t_end=end, tags=tags,
-                     sources=sources, raw_bytes=raw_bytes, codec=codec)
+        with wall.span("account", what="charge_crossing"):
+            crossing = Crossing(int(nbytes), direction, staging)
+            cost = self.bridge.crossing_time(crossing,
+                                             n_contexts=self.pool.n_workers)
+            # a coalesced flush is one ciphertext over len(sources)
+            # constituents
+            cost = self._faulted_cost(op_class, crossing, cost,
+                                      n_units=max(1, len(sources)))
+            end = self.clock.advance(cost)
+            self._record(crossing, cost, op_class, t_end=end, tags=tags,
+                         sources=sources, raw_bytes=raw_bytes, codec=codec)
         return cost
 
     def record_modeled(self, nbytes: int, direction: Direction, cost: float, *,
@@ -407,3 +447,8 @@ class TransferGateway:
         self.records.append(rec)
         for hook in self.on_record:
             hook(rec)
+
+
+# imported last: repro.obs reaches repro.trace, whose recorder imports this
+# module's TransferGateway
+from repro.obs import wall  # noqa: E402

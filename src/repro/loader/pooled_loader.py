@@ -32,7 +32,7 @@ import numpy as np
 
 from repro.core.bridge import BridgeModel, Crossing, Direction, StagingKind
 from repro.core.channels import SecureChannelPool, VirtualClock
-from repro.core.gateway import TransferGateway
+from repro.core.gateway import TransferGateway, device_put
 from repro.trace import opclasses as oc
 from .sharded_weights import ShardedCheckpoint
 
@@ -268,7 +268,7 @@ class PooledLoader:
             shard_bytes = 0
             for name, arr in ckpt.iter_shard(shard):
                 shard_bytes += int(np.asarray(arr).nbytes)
-                tensors[name] = jax.device_put(arr, device)
+                tensors[name] = device_put(arr, device, oc.LOADER_SHARD_H2D)
             if self.gateway is not None:
                 # staging matches the toll component the cost embeds (fresh
                 # setup + alloc per shard without an arena; warm toll on

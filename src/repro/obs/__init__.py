@@ -1,6 +1,6 @@
 """repro.obs — the bridge observatory (telemetry on the virtual clock).
 
-One layer, four instruments, every subsystem reports through it:
+One layer, five instruments, every subsystem reports through it:
 
   * metrics.py   label-keyed counters/gauges/histograms with exact
                  percentiles, snapshot-able, associatively mergeable
@@ -10,6 +10,9 @@ One layer, four instruments, every subsystem reports through it:
   * stalls.py    §5.2-style stall attribution: every gap second of a tape
                  classified into the paper's causes, conserved exactly
   * timeline.py  Perfetto / chrome://tracing export of tapes + stalls
+  * wall.py      wall-clock spans of the serving step on the real clock,
+                 also written to the profiler's host plane (imported by
+                 name: ``from repro.obs import wall``)
 
 The observatory is passive: it never reads or advances the virtual clock,
 so enabling it cannot change a schedule, a tape, or a golden stream.

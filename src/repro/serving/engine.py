@@ -42,12 +42,18 @@ from repro.core.channels import VirtualClock
 from repro.core.compute import ComputeModel
 from repro.core.gateway import TransferGateway
 from repro.core.policy import RuntimeDefaults, SchedulingPolicy, cc_aware_defaults
-from repro.obs import Observatory
+from repro.obs import Observatory, wall
 from repro.trace import opclasses as oc
 from repro.models.model import Model, prefill
 from .kv_cache import RaggedBatch, gather_slot_cache, scatter_slot_cache
 from .overlap import OverlapScheduler
 from .sampler import SamplingParams, sample
+
+
+#: ``op_class`` of the uploads the engine makes itself, outside the gateway
+#: and its tape (an attr of their ``bridge.h2d`` wall-clock spans)
+PROMPT_INPUT = "prompt_input"
+DECODE_INPUT = "decode_input"
 
 
 @dataclass
@@ -113,6 +119,21 @@ def packed_step(model: Model, params, caches, tokens, index, slots):
     return logits, caches
 
 
+def packed_step_of(model: Model):
+    """``packed_step`` bound to ``model`` under its own name, so its jit
+    (and the program a profiler trace shows) is called ``packed_step``."""
+    def bound(params, caches, tokens, index, slots):
+        return packed_step(model, params, caches, tokens, index, slots)
+    bound.__name__ = bound.__qualname__ = "packed_step"
+    return bound
+
+
+def _upload(host: np.ndarray, op_class: str) -> jax.Array:
+    """An engine input the jitted programs take straight from the host."""
+    with wall.span("bridge.h2d", op_class=op_class, nbytes=int(host.nbytes)):
+        return jnp.asarray(host)
+
+
 class ServingEngine:
     def __init__(self, model: Model, *, max_batch: int = 8, max_len: int = 256,
                  gateway: Optional[TransferGateway] = None,
@@ -169,6 +190,11 @@ class ServingEngine:
             Observatory() if self.defaults.observability else None)
         if self.obs is not None:
             self.obs.attach_gateway(self.gateway)
+        #: attrs of this engine's root wall-clock span (``sched.tick``):
+        #: a labelled observatory's replica
+        self.span_attrs = ({"replica": self.obs.labels["replica"]}
+                           if self.obs is not None
+                           and "replica" in self.obs.labels else {})
 
         #: params and cache are built on the default device, so one seed
         #: gives the same bits everywhere, then committed to the gateway's
@@ -190,6 +216,8 @@ class ServingEngine:
         #: ``step_compiled`` so the scheduler does not time it as a straggler
         self._seen_shapes: set = set()
         self.step_compiled = False
+        #: requests the last step admitted
+        self.step_admitted = 0
         self._worker: Optional[threading.Thread] = None
         self._drain_q: "queue.Queue" = queue.Queue()
         #: worker-drain join deadline at close(); a thread that outlives it
@@ -203,7 +231,7 @@ class ServingEngine:
         # `_bucket` pads widths to powers of two so the trace count stays
         # O(log max_batch) even when the ready set raggedly shrinks slot by
         # slot as requests finish.
-        self._packed_decode = jax.jit(functools.partial(packed_step, model))
+        self._packed_decode = jax.jit(packed_step_of(model))
 
         # worker x coalescer composition: with a coalescer the drains queue
         # and the worker's seat becomes a secure channel the fused flushes
@@ -240,8 +268,9 @@ class ServingEngine:
                 item = self._drain_q.get()
                 if item is None:
                     return
-                arr, cb = item
-                host = self.gateway.d2h(arr, op_class=oc.WORKER_DRAIN)
+                arr, cb, parent = item
+                with wall.under(parent):
+                    host = self.gateway.d2h(arr, op_class=oc.WORKER_DRAIN)
                 cb(host)
         self._worker = threading.Thread(target=loop, daemon=True)
         self._worker.start()
@@ -304,7 +333,9 @@ class ServingEngine:
         # not inflate the admission price; with nothing ready the next step
         # pays a barrier, not a forward, and the cost is honestly zero.
         if self.compute is not None:
-            step_cost = self.compute.decode_step_masked_s(self._ready_lens())
+            with wall.span("account", what="admission_price"):
+                step_cost = self.compute.decode_step_masked_s(
+                    self._ready_lens())
         else:
             step_cost = 0.0
         i = 0
@@ -319,7 +350,10 @@ class ServingEngine:
                 continue
             self.queue.pop(i)
             slot = self.free_slots.pop()
-            self._prefill_into_slot(req, slot)
+            with wall.span("engine.prefill", req=req.request_id,
+                           prompt_len=len(req.prompt)):
+                self._prefill_into_slot(req, slot)
+            self.step_admitted += 1
             admitted = True
 
     def _ready_lens(self) -> list:
@@ -341,12 +375,13 @@ class ServingEngine:
         if self.obs is not None:
             self.obs.spans.on_admit(req.request_id, self.clock.now)
         # first read of restored KV happens here: the barrier is the law
-        waited = self.overlap.restore_barrier(req.request_id)
-        if waited:
-            if self.coalescer is not None:
-                self.coalescer.poll()   # the barrier wait moved the clock
-            if self.obs is not None:
-                self.obs.spans.on_restore_wait(req.request_id, waited)
+        with wall.span("account", what="restore_barrier"):
+            waited = self.overlap.restore_barrier(req.request_id)
+            if waited:
+                if self.coalescer is not None:
+                    self.coalescer.poll()   # the barrier wait moved the clock
+                if self.obs is not None:
+                    self.obs.spans.on_restore_wait(req.request_id, waited)
         prompt = np.asarray(req.prompt, np.int32)[None]     # (1, P)
         # prompt upload crosses the bridge (registered: steady-state serving
         # reuses the prompt staging buffer; coalesced when bridge_opt is on)
@@ -356,13 +391,14 @@ class ServingEngine:
             self.gateway.h2d(prompt, op_class=oc.PROMPT_H2D)
         self._note_shape(("prefill", prompt.shape[1]))
         logits, pre_cache = prefill_logits_cache(
-            self.cfg, self.params, jnp.asarray(prompt), self.max_len)
+            self.cfg, self.params, _upload(prompt, PROMPT_INPUT),
+            self.max_len)
         idx0 = prompt.shape[1]
         # prompt processing is device compute, charged like any interval;
         # warm (restored / externally-priced) tokens skip the forward
-        if self.compute is not None:
-            cold = max(0, len(req.prompt) - req.warm_tokens)
-            if cold:
+        cold = max(0, len(req.prompt) - req.warm_tokens)
+        if self.compute is not None and cold:
+            with wall.span("account", what="prefill_charge"):
                 charge = self.compute.prefill_charge(cold)
                 self.gateway.charge_compute(
                     charge.seconds, op_class=oc.PREFILL_COMPUTE,
@@ -372,9 +408,11 @@ class ServingEngine:
                 # TP prefill allreduces over the prompt's activations (the
                 # per-token payload is the same shape as a decode batch row)
                 self._charge_allreduce(cold)
-        self._insert_slot_cache(pre_cache, slot)
-        self.key, sk = jax.random.split(self.key)
-        first = sample(logits, sk, req.sampling)
+        with wall.span("engine.insert"):
+            self._insert_slot_cache(pre_cache, slot)
+        with wall.span("engine.sample"):
+            self.key, sk = jax.random.split(self.key)
+            first = sample(logits, sk, req.sampling)
         if self.coalescer is not None:
             first_host = self.coalescer.d2h(first, op_class=oc.SAMPLE_D2H)
         else:
@@ -451,10 +489,11 @@ class ServingEngine:
         ladder = self._ladder()
         if ladder is None:
             return
-        ladder.maybe_recover(self.clock.now)
-        if (self.coalescer is not None
-                and self.coalescer.bypass != ladder.coalescer_bypassed):
-            self.coalescer.set_bypass(ladder.coalescer_bypassed)
+        with wall.span("account", what="ladder"):
+            ladder.maybe_recover(self.clock.now)
+            if (self.coalescer is not None
+                    and self.coalescer.bypass != ladder.coalescer_bypassed):
+                self.coalescer.set_bypass(ladder.coalescer_bypassed)
 
     # -- tensor-parallel allreduce (DESIGN.md §12) --------------------------------------
 
@@ -545,13 +584,16 @@ class ServingEngine:
         under greedy decode.
         """
         self.step_compiled = False
+        self.step_admitted = 0
         self._sync_ladder()
-        self._admit()
+        with wall.span("engine.admit"):
+            self._admit()
         if not self.active:
             return 0
         self.step_count += 1
         slots = sorted(self.active)
-        ready, deferred = self._ready_slots(slots)
+        with wall.span("account", what="ready_slots"):
+            ready, deferred = self._ready_slots(slots)
         ladder = self._ladder()
         use_packed = self.defaults.packed_decode and not (
             ladder is not None and ladder.dense_step_forced)
@@ -588,39 +630,44 @@ class ServingEngine:
         self._whole_batch_barrier(slots)
 
         self._note_shape(("dense",))
-        logits, self.caches = self._decode(
-            self.params, self.caches, jnp.asarray(tokens), jnp.asarray(index))
+        with wall.span("engine.dispatch", width=b):
+            logits, self.caches = self._decode(
+                self.params, self.caches, _upload(tokens, DECODE_INPUT),
+                _upload(index, DECODE_INPUT))
         # the forward+sample is a first-class clock charge: this is what
         # ages coalescer queues toward their deadline and opens the window
         # pipelined restores drain into.  A masked step charges the *masked*
         # batch — the clock, the coalescer deadlines and the overlap windows
         # all see the true smaller charge, never the full-batch price.
         if self.compute is not None:
-            if deferred:
-                charge = self.compute.decode_charge_masked(
-                    [float(index[s]) for s in ready])
-                # one MASKED per masked step, one DEFERRED per slot it
-                # deferred: tape tag counts read as (masked steps,
-                # deferred slot-steps) without decoding StepTraces
-                self.gateway.charge_compute(
-                    charge.seconds, op_class=oc.DECODE_MASKED,
-                    tags=(oc.MASKED,) + (oc.DEFERRED,) * len(deferred)
-                    + self._degraded_tags(),
-                    bound=charge.bound)
-            else:
-                kv_len = float(np.mean([index[s] for s in ready]))
-                charge = self.compute.decode_charge(len(ready), kv_len=kv_len)
-                self.gateway.charge_compute(
-                    charge.seconds, op_class=oc.DECODE_COMPUTE,
-                    tags=self._degraded_tags(),
-                    bound=charge.bound)
-            self._charge_allreduce(len(ready))
-        self.key, sk = jax.random.split(self.key)
-        # batch sampling params come from the lowest *resident* slot — a
-        # mask-independent choice, so masking cannot change which request's
-        # params price the batch (the one-params-per-batch limitation itself
-        # predates masking)
-        next_tokens = sample(logits, sk, self.active[slots[0]].sampling)
+            with wall.span("account", what="decode_charge"):
+                if deferred:
+                    charge = self.compute.decode_charge_masked(
+                        [float(index[s]) for s in ready])
+                    # one MASKED per masked step, one DEFERRED per slot it
+                    # deferred: tape tag counts read as (masked steps,
+                    # deferred slot-steps) without decoding StepTraces
+                    self.gateway.charge_compute(
+                        charge.seconds, op_class=oc.DECODE_MASKED,
+                        tags=(oc.MASKED,) + (oc.DEFERRED,) * len(deferred)
+                        + self._degraded_tags(),
+                        bound=charge.bound)
+                else:
+                    kv_len = float(np.mean([index[s] for s in ready]))
+                    charge = self.compute.decode_charge(len(ready),
+                                                        kv_len=kv_len)
+                    self.gateway.charge_compute(
+                        charge.seconds, op_class=oc.DECODE_COMPUTE,
+                        tags=self._degraded_tags(),
+                        bound=charge.bound)
+                self._charge_allreduce(len(ready))
+        with wall.span("engine.sample"):
+            self.key, sk = jax.random.split(self.key)
+            # batch sampling params come from the lowest *resident* slot — a
+            # mask-independent choice, so masking cannot change which
+            # request's params price the batch (the one-params-per-batch
+            # limitation itself predates masking)
+            next_tokens = sample(logits, sk, self.active[slots[0]].sampling)
 
         # --- output drain (the policy-defining crossing) ---
         # mask-aware: only the ready slots' tokens drain — a deferred slot's
@@ -630,20 +677,22 @@ class ServingEngine:
         # exactly; stochastic sampling draws the rejoin token under a later
         # step's key, so token identity across the flag is a greedy-only
         # guarantee
-        drain_tokens = (next_tokens[jnp.asarray(np.asarray(ready, np.int32))]
-                        if deferred else next_tokens)
-        host_tokens = self._drain(drain_tokens)
+        drain_tokens = (
+            next_tokens[_upload(np.asarray(ready, np.int32), DECODE_INPUT)]
+            if deferred else next_tokens)
+        with wall.span("engine.drain"):
+            host_tokens = self._drain(drain_tokens)
 
-        self.trace.append(StepTrace(
-            step=self.step_count, active=len(ready),
-            prep_crossings=len(small_inputs),
-            prep_bytes=sum(a.nbytes for a in small_inputs),
-            drain_bytes=int(np.asarray(host_tokens).nbytes),
-            policy=self.policy.value, virtual_t=self.clock.now,
-            deferred=len(deferred)))
-
-        self._consume(ready, np.asarray(host_tokens),
-                      by_position=bool(deferred))
+        with wall.span("engine.consume"):
+            self.trace.append(StepTrace(
+                step=self.step_count, active=len(ready),
+                prep_crossings=len(small_inputs),
+                prep_bytes=sum(a.nbytes for a in small_inputs),
+                drain_bytes=int(np.asarray(host_tokens).nbytes),
+                policy=self.policy.value, virtual_t=self.clock.now,
+                deferred=len(deferred)))
+            self._consume(ready, np.asarray(host_tokens),
+                          by_position=bool(deferred))
         if self.coalescer is not None:
             # compute moved the clock this step: let aged queues meet their
             # deadline now instead of waiting for the next submission
@@ -683,48 +732,56 @@ class ServingEngine:
         # carry identical values, so bucketing is invisible to state —
         # and ALL accounting above/below prices the real size n.
         width = self._bucket(n)
-        exec_slots, exec_tokens, exec_index = batch.slot_array(), tokens, index
-        if width > n:
-            pad = width - n
-            exec_slots = np.concatenate(
-                [exec_slots, np.repeat(exec_slots[:1], pad)])
-            exec_tokens = np.concatenate(
-                [tokens, np.repeat(tokens[:1], pad, axis=0)])
-            exec_index = np.concatenate([index, np.repeat(index[:1], pad)])
-        self._note_shape(("packed", width))
-        logits, self.caches = self._packed_decode(
-            self.params, self.caches, jnp.asarray(exec_tokens),
-            jnp.asarray(exec_index), jnp.asarray(exec_slots))
+        with wall.span("engine.dispatch", width=width):
+            exec_slots, exec_tokens, exec_index = (batch.slot_array(), tokens,
+                                                   index)
+            if width > n:
+                pad = width - n
+                exec_slots = np.concatenate(
+                    [exec_slots, np.repeat(exec_slots[:1], pad)])
+                exec_tokens = np.concatenate(
+                    [tokens, np.repeat(tokens[:1], pad, axis=0)])
+                exec_index = np.concatenate([index, np.repeat(index[:1], pad)])
+            self._note_shape(("packed", width))
+            logits, self.caches = self._packed_decode(
+                self.params, self.caches, _upload(exec_tokens, DECODE_INPUT),
+                _upload(exec_index, DECODE_INPUT),
+                _upload(exec_slots, DECODE_INPUT))
 
         if self.compute is not None:
-            charge = self.compute.decode_charge_packed(
-                [float(k) for k in batch.kv_lens])
-            # one PACKED per packed step, one DEFERRED per slot it deferred
-            # (mirroring the MASKED/DEFERRED tag convention)
-            self.gateway.charge_compute(
-                charge.seconds, op_class=oc.DECODE_PACKED,
-                tags=(oc.PACKED,) + (oc.DEFERRED,) * len(deferred)
-                + self._degraded_tags(),
-                bound=charge.bound)
-            self._charge_allreduce(n)
-        self.key, sk = jax.random.split(self.key)
-        # sampling params come from the lowest *resident* slot — the dense
-        # path's mask-independent convention, kept so packed vs dense can
-        # never disagree on which request's params price the batch
-        next_tokens = sample(logits[:n], sk, self.active[slots[0]].sampling)
+            with wall.span("account", what="decode_charge"):
+                charge = self.compute.decode_charge_packed(
+                    [float(k) for k in batch.kv_lens])
+                # one PACKED per packed step, one DEFERRED per slot it
+                # deferred (mirroring the MASKED/DEFERRED tag convention)
+                self.gateway.charge_compute(
+                    charge.seconds, op_class=oc.DECODE_PACKED,
+                    tags=(oc.PACKED,) + (oc.DEFERRED,) * len(deferred)
+                    + self._degraded_tags(),
+                    bound=charge.bound)
+                self._charge_allreduce(n)
+        with wall.span("engine.sample"):
+            self.key, sk = jax.random.split(self.key)
+            # sampling params come from the lowest *resident* slot — the
+            # dense path's mask-independent convention, kept so packed vs
+            # dense can never disagree on which request's params price the
+            # batch
+            next_tokens = sample(logits[:n], sk,
+                                 self.active[slots[0]].sampling)
 
         # --- output drain: exactly the packed rows, nothing else
-        host_tokens = self._drain(next_tokens)
+        with wall.span("engine.drain"):
+            host_tokens = self._drain(next_tokens)
 
-        self.trace.append(StepTrace(
-            step=self.step_count, active=n,
-            prep_crossings=len(small_inputs),
-            prep_bytes=sum(a.nbytes for a in small_inputs),
-            drain_bytes=int(np.asarray(host_tokens).nbytes),
-            policy=self.policy.value, virtual_t=self.clock.now,
-            deferred=len(deferred), packed=n))
-
-        self._consume(ready, np.asarray(host_tokens), by_position=True)
+        with wall.span("engine.consume"):
+            self.trace.append(StepTrace(
+                step=self.step_count, active=n,
+                prep_crossings=len(small_inputs),
+                prep_bytes=sum(a.nbytes for a in small_inputs),
+                drain_bytes=int(np.asarray(host_tokens).nbytes),
+                policy=self.policy.value, virtual_t=self.clock.now,
+                deferred=len(deferred), packed=n))
+            self._consume(ready, np.asarray(host_tokens), by_position=True)
         if self.coalescer is not None:
             self.coalescer.poll()
         return n
@@ -755,14 +812,16 @@ class ServingEngine:
         resolved the stepping slots' restores — this is a no-op."""
         if self.defaults.slot_masked_decode or not self.overlap.pending:
             return
-        waited = 0.0
-        for s in slots:
-            w = self.overlap.restore_barrier(self.active[s].request_id)
-            if w and self.obs is not None:
-                self.obs.spans.on_restore_wait(self.active[s].request_id, w)
-            waited += w
-        if waited and self.coalescer is not None:
-            self.coalescer.poll()   # the barrier wait moved the clock
+        with wall.span("account", what="batch_barrier"):
+            waited = 0.0
+            for s in slots:
+                w = self.overlap.restore_barrier(self.active[s].request_id)
+                if w and self.obs is not None:
+                    self.obs.spans.on_restore_wait(self.active[s].request_id,
+                                                   w)
+                waited += w
+            if waited and self.coalescer is not None:
+                self.coalescer.poll()   # the barrier wait moved the clock
 
     def _drain(self, drain_tokens) -> Any:
         """Drain one step's sampled tokens to the host under the active
@@ -775,7 +834,8 @@ class ServingEngine:
             done = threading.Event()
             result = {}
             self._drain_q.put((drain_tokens, lambda h: (result.update(h=h),
-                                                        done.set())))
+                                                        done.set()),
+                               wall.current()))
             done.wait()
             return result["h"]
         op = (oc.DRAIN_D2H_NONBLOCKING

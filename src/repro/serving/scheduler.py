@@ -19,6 +19,7 @@ from typing import Optional
 
 import numpy as np
 
+from repro.obs import wall
 from .engine import Request, ServingEngine
 
 
@@ -49,8 +50,14 @@ class Scheduler:
     def tick(self) -> int:
         """One scheduled engine step with straggler accounting."""
         t0 = time.perf_counter()
-        stepped = self.engine.step()
-        dt = time.perf_counter() - t0
+        with wall.span("sched.tick", start_ns=int(t0 * 1e9),
+                       **self.engine.span_attrs) as span:
+            stepped = self.engine.step()
+            t1 = time.perf_counter()
+            span.end(int(t1 * 1e9), stepped=stepped,
+                     admitted=self.engine.step_admitted,
+                     compiled=self.engine.step_compiled)
+        dt = t1 - t0
         if stepped == 0 or self.engine.step_compiled:
             return stepped
         self._ema_dt = dt if self._ema_dt is None else \

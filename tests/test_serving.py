@@ -109,6 +109,8 @@ class _FakeEngine:
 
     def __init__(self, requests):
         self.step_compiled = False
+        self.step_admitted = 0
+        self.span_attrs = {}
         self.queue = []
         self.active = {i: r for i, r in enumerate(requests)}
         for r in self.active.values():
