@@ -160,6 +160,61 @@ def einsum_f32acc(spec: str, a: jax.Array, b: jax.Array) -> jax.Array:
     return jnp.einsum(spec, a.astype(jnp.float32), b.astype(jnp.float32))
 
 
+@dataclasses.dataclass(frozen=True)
+class CacheRows:
+    """Where a decode step's rows sit in one layer's cache leaves.
+
+    By default the leaves hold exactly the decoded rows: row ``i`` is leaf
+    row ``i``.  With ``slots`` they are the engine's resident cache of every
+    slot, and row ``i`` lives at ``leaf[at + (slots[i],)]``; ``at`` is
+    ``(layer,)`` inside a scan-stacked cache and ``()`` in a per-layer one.
+    Then a leaf with a position axis (K, V, pos, MLA latents) takes one new
+    entry a row in place (``write_at``) and gives back the rows the step
+    attends over (``read``); a recurrent state tree gives and takes whole
+    rows (``read_state``, ``write_state``).
+    """
+    slots: Optional[jax.Array] = None
+    at: tuple = ()
+
+    def write_at(self, leaf, pos, new):
+        """``leaf`` with each row's entry at position ``pos`` set to
+        ``new``."""
+        rows = ((jnp.arange(new.shape[0]),) if self.slots is None
+                else self.at + (self.slots,))
+        return leaf.at[rows + (pos,)].set(new.astype(leaf.dtype))
+
+    def read(self, leaf):
+        """The decoded rows of ``leaf``, one dynamic slice a row: a TPU
+        lowers a gather of them by copying the leaf's whole layer first
+        (the whole stacked leaf, where the layer is indexed in the same
+        gather)."""
+        if self.slots is None:
+            return leaf
+        lead = len(self.at) + 1
+        shape = leaf.shape[lead:]
+        return jnp.stack([
+            lax.dynamic_slice(leaf, self.at + (self.slots[i],)
+                              + (0,) * len(shape),
+                              (1,) * lead + shape).reshape(shape)
+            for i in range(self.slots.shape[0])])
+
+    def read_state(self, tree):
+        """The decoded rows of every leaf of a recurrent state tree (small,
+        so gathered)."""
+        if self.slots is None:
+            return tree
+        return jax.tree.map(lambda a: a[self.at + (self.slots,)], tree)
+
+    def write_state(self, tree, new):
+        """``tree`` with the decoded rows of every leaf replaced by ``new``'s
+        (a step rewrites a row's recurrent state whole)."""
+        if self.slots is None:
+            return new
+        return jax.tree.map(
+            lambda a, n: a.at[self.at + (self.slots,)].set(n.astype(a.dtype)),
+            tree, new)
+
+
 def _repeat_kv(k: jax.Array, n_rep: int) -> jax.Array:
     """(B,S,kv,d) -> (B,S,kv*n_rep,d) for GQA."""
     if n_rep == 1:
@@ -372,7 +427,7 @@ def init_mla(key, cfg) -> Params:
 
 def mla_block(p: Params, x: jax.Array, cfg, *, positions: jax.Array,
               cache: Optional[dict] = None, cache_index=None,
-              return_kv: bool = False):
+              return_kv: bool = False, rows: CacheRows = CacheRows()):
     """Multi-head latent attention.  Cache holds the *latent* c_kv (dc) plus
     the shared rope key (dr) — ~10x smaller than full GQA KV: residency the
     bridge law pays for (§8 rule 4).
@@ -380,7 +435,8 @@ def mla_block(p: Params, x: jax.Array, cfg, *, positions: jax.Array,
     Prefill (cache=None): decompress K/V per head; with return_kv=True the
     post-rope (c, k_pe) latents are returned for one-shot cache assembly.
     Decode (cache given): absorb W_uk into q and attend directly over the
-    latent cache (the DeepSeek-V2 inference trick).
+    latent cache (the DeepSeek-V2 inference trick); ``rows`` says where the
+    decoded rows sit in it (``CacheRows``).
     Returns (y, cache_or_latents_or_None).
     """
     b, s, d = x.shape
@@ -400,12 +456,10 @@ def mla_block(p: Params, x: jax.Array, cfg, *, positions: jax.Array,
     scale = 1.0 / math.sqrt(dn + dr)
     if cache is not None:
         # decode (s=1, per-sequence index): write latents, absorbed attention
-        cc, ck = cache["c"], cache["k_pe"]
         idx = jnp.broadcast_to(jnp.asarray(cache_index, jnp.int32), (b,))
-        rows = jnp.arange(b)
-        cc = cc.at[rows, idx].set(c[:, 0].astype(cc.dtype))
-        ck = ck.at[rows, idx].set(k_pe[:, 0].astype(ck.dtype))
-        cache = dict(cache, c=cc, k_pe=ck)
+        cache = dict(cache, c=rows.write_at(cache["c"], idx, c[:, 0]),
+                     k_pe=rows.write_at(cache["k_pe"], idx, k_pe[:, 0]))
+        cc, ck = rows.read(cache["c"]), rows.read(cache["k_pe"])
         # absorbed decode: q_c = q_nope @ W_uk  -> score against latent cache
         # (TPU: bf16 cache operands + f32 accumulation — no f32 cache copy)
         q_c = jnp.einsum("bshn,chn->bshc", q_nope, pvalue(p["wuk"]))

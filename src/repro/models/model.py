@@ -21,7 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .layers import make_param, apply_norm, init_norm, pvalue, shard_hint
+from .layers import (CacheRows, make_param, apply_norm, init_norm, pvalue,
+                     shard_hint)
 from .transformer import (block_apply, init_block, init_layer_cache,
                           scan_blocks, stack_params, _block_kind)
 
@@ -97,16 +98,19 @@ def _encoder_forward(params, cfg, frames):
 
 
 def _trunk(params, cfg, x, *, mode, positions, caches=None, cache_index=None,
-           enc_out=None):
-    """Run all decoder blocks.  caches layout mirrors params['blocks']."""
+           enc_out=None, slots=None):
+    """Run all decoder blocks.  caches layout mirrors params['blocks'];
+    with ``slots`` it is the resident cache of every slot (decode_step)."""
     aux = jnp.zeros((), F32)
+    rows = CacheRows(slots)
     start = 1 if "block0" in params else 0
     new_caches: dict = {}
     if start:
         c0 = caches["block0"] if caches else None
         x, nc0, a0 = block_apply(params["block0"], x, cfg, 0, mode=mode,
                                  positions=positions, cache=c0,
-                                 cache_index=cache_index, enc_out=enc_out)
+                                 cache_index=cache_index, enc_out=enc_out,
+                                 rows=rows)
         aux += a0
         if mode != "train":
             new_caches["block0"] = nc0
@@ -115,7 +119,7 @@ def _trunk(params, cfg, x, *, mode, positions, caches=None, cache_index=None,
         x, ncs, a = scan_blocks(params["blocks"], x, cfg, mode=mode,
                                 positions=positions, cache=blk_cache,
                                 cache_index=cache_index, enc_out=enc_out,
-                                layer0_offset=start)
+                                layer0_offset=start, slots=slots)
         aux += a
         if mode != "train":
             new_caches["blocks"] = ncs
@@ -125,7 +129,8 @@ def _trunk(params, cfg, x, *, mode, positions, caches=None, cache_index=None,
             c = None if blk_cache is None else _index_cache(blk_cache, i)
             x, nc, a = block_apply(p, x, cfg, start + i, mode=mode,
                                    positions=positions, cache=c,
-                                   cache_index=cache_index, enc_out=enc_out)
+                                   cache_index=cache_index, enc_out=enc_out,
+                                   rows=rows)
             aux += a
             ncs.append(nc)
         if mode != "train":
@@ -234,16 +239,24 @@ def prefill(params, cfg, batch, max_len: int):
     return logits, new_caches, total
 
 
-def decode_step(params, cfg, caches, tokens, index):
+def decode_step(params, cfg, caches, tokens, index, slots=None):
     """One decode step.  tokens: (B,1); index: scalar or (B,) per-sequence
-    current lengths (continuous batching)."""
+    current lengths (continuous batching).
+
+    Without ``slots`` the cache holds the B decoded rows, and the step
+    returns their new cache.  With ``slots`` (B,) it is the resident cache
+    of every slot: row i decodes slot ``slots[i]``, its new K/V/pos entries
+    are written where that slot resides (at ``index % cap``) before it is
+    attended over, and the cache returned is the same tree updated in place
+    — donate it.  Rows that share a slot must carry the same token and
+    index."""
     b = tokens.shape[0]
     x = _embed_tokens(params, cfg, tokens)
     index = jnp.broadcast_to(jnp.asarray(index, jnp.int32), (b,))
     positions = index[:, None]
     x, new_caches, _ = _trunk(params, cfg, x, mode="decode",
                               positions=positions, caches=caches,
-                              cache_index=index, enc_out=None)
+                              cache_index=index, enc_out=None, slots=slots)
     return _logits(params, cfg, x), new_caches
 
 
@@ -267,8 +280,8 @@ class Model:
     def prefill(self, params, batch, max_len: int):
         return prefill(params, self.cfg, batch, max_len)
 
-    def decode_step(self, params, caches, tokens, index):
-        return decode_step(params, self.cfg, caches, tokens, index)
+    def decode_step(self, params, caches, tokens, index, slots=None):
+        return decode_step(params, self.cfg, caches, tokens, index, slots)
 
     def init_cache(self, batch: int, max_len: int, enc_len: int = 0):
         return init_cache(self.cfg, batch, max_len, enc_len, dtype=self.cfg.dtype)

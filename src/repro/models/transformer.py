@@ -26,9 +26,10 @@ import jax.numpy as jnp
 from jax import lax
 
 from . import ssm
-from .layers import (Params, apply_norm, attention_block, init_attention,
-                     init_mla, init_moe, init_mlp, init_norm, make_param,
-                     mla_block, mlp_block, moe_block, pvalue, shard_hint)
+from .layers import (CacheRows, Params, apply_norm, attention_block,
+                     init_attention, init_mla, init_moe, init_mlp, init_norm,
+                     make_param, mla_block, mlp_block, moe_block, pvalue,
+                     shard_hint)
 
 F32 = jnp.float32
 
@@ -96,10 +97,15 @@ def _layer_window(cfg, layer_idx: int) -> Optional[int]:
 def block_apply(p: Params, x: jax.Array, cfg, layer_idx: int, *, mode: str,
                 positions: jax.Array, cache: Optional[dict] = None,
                 cache_index=None, enc_out: Optional[jax.Array] = None,
-                causal: bool = True):
+                causal: bool = True, rows: CacheRows = CacheRows()):
     """Apply one block.  Returns (x, new_cache, aux_loss).
 
     mode: "train" (no cache), "prefill" (build cache), "decode" (update).
+    In decode, ``rows`` says where the decoded rows sit in ``cache``
+    (``CacheRows``): by default the cache holds just them, and with slots it
+    is the resident cache, whose leaves are updated in place by kind — one
+    new entry a row in K/V/pos and latent leaves, the rows' recurrent state
+    rewritten, cross-attention K/V only read.
     """
     kind = _block_kind(cfg, layer_idx)
     aux = jnp.zeros((), F32)
@@ -108,6 +114,8 @@ def block_apply(p: Params, x: jax.Array, cfg, layer_idx: int, *, mode: str,
     if kind in ("mlstm", "slstm"):
         h = apply_norm(p["norm"], x, cfg.norm_kind)
         state = cache.get("ssm") if cache else None
+        if mode == "decode":
+            resident, state = state, rows.read_state(state)
         if kind == "mlstm":
             if mode == "decode":
                 y, state = ssm.mlstm_step(p["mix"], h, cfg, state)
@@ -115,6 +123,8 @@ def block_apply(p: Params, x: jax.Array, cfg, layer_idx: int, *, mode: str,
                 y, state = ssm.mlstm_chunked(p["mix"], h, cfg, state=state)
         else:
             y, state = ssm.slstm_forward(p["mix"], h, cfg, state)
+        if mode == "decode":
+            state = rows.write_state(resident, state)
         x = x + y
         if mode != "train":
             new_cache["ssm"] = state
@@ -142,17 +152,20 @@ def block_apply(p: Params, x: jax.Array, cfg, layer_idx: int, *, mode: str,
     else:  # decode
         if cfg.use_mla:
             y, kvc = mla_block(p["attn"], h, cfg, positions=positions,
-                               cache=cache["kv"], cache_index=cache_index)
+                               cache=cache["kv"], cache_index=cache_index,
+                               rows=rows)
         else:
             y, kvc = _ring_decode_attention(p["attn"], h, cfg, positions=positions,
                                             cache=cache["kv"], cache_index=cache_index,
-                                            window=window)
+                                            window=window, rows=rows)
         new_cache["kv"] = kvc
 
     if kind == "hybrid":
         sstate = cache.get("ssm") if cache else None
         if mode == "decode":
-            ys, sstate = ssm.mamba_step(p["ssm"], h, cfg, sstate)
+            ys, new_state = ssm.mamba_step(p["ssm"], h, cfg,
+                                           rows.read_state(sstate))
+            sstate = rows.write_state(sstate, new_state)
         else:
             ys, sstate = ssm.mamba_chunked(p["ssm"], h, cfg, state=sstate)
         y = 0.5 * (apply_norm(p["attn_out_norm"], y, "rmsnorm")
@@ -164,7 +177,7 @@ def block_apply(p: Params, x: jax.Array, cfg, layer_idx: int, *, mode: str,
     if "cross" in p:
         hc = apply_norm(p["cross_norm"], x, cfg.norm_kind)
         if mode == "decode":
-            kv = (cache["cross_k"], cache["cross_v"])
+            kv = (rows.read(cache["cross_k"]), rows.read(cache["cross_v"]))
         else:
             ek = jnp.einsum("bsd,dhk->bshk", enc_out, pvalue(p["cross"]["wk"]))
             ev = jnp.einsum("bsd,dhk->bshk", enc_out, pvalue(p["cross"]["wv"]))
@@ -172,7 +185,8 @@ def block_apply(p: Params, x: jax.Array, cfg, layer_idx: int, *, mode: str,
             if mode == "prefill":
                 new_cache["cross_k"], new_cache["cross_v"] = ek, ev
         if mode == "decode":
-            new_cache["cross_k"], new_cache["cross_v"] = kv
+            new_cache["cross_k"] = cache["cross_k"]
+            new_cache["cross_v"] = cache["cross_v"]
         yc, _ = attention_block(p["cross"], hc, cfg, positions=positions,
                                 kv_override=kv, causal=False)
         x = x + yc
@@ -226,8 +240,11 @@ def _assemble_mla_cache(latents: tuple, template: dict, positions) -> dict:
                 (c.shape[0], cap))}
 
 
-def _ring_decode_attention(p, h, cfg, *, positions, cache, cache_index, window):
-    """Decode attention against a (possibly ring) cache with explicit pos."""
+def _ring_decode_attention(p, h, cfg, *, positions, cache, cache_index, window,
+                           rows: CacheRows):
+    """Decode attention against a (possibly ring) cache with explicit pos:
+    each row's new K/V/pos entry is written at ``index % cap`` where ``rows``
+    places it, then the rows are read back and attended over."""
     import math as _math
     from .layers import _repeat_kv, apply_rope, rope_table
     b, s, d = h.shape
@@ -244,16 +261,14 @@ def _ring_decode_attention(p, h, cfg, *, positions, cache, cache_index, window):
         q = apply_rope(q, sin, cos)
         k = apply_rope(k, sin, cos)
 
-    ck, cv, cpos = cache["k"], cache["v"], cache["pos"]
-    cap = ck.shape[1]
+    cap = cache["pos"].shape[-1]
     idx = jnp.broadcast_to(jnp.asarray(cache_index, jnp.int32), (b,))
     slot = idx % cap
-    rows = jnp.arange(b)
-    ck = ck.at[rows, slot].set(k[:, 0].astype(ck.dtype))
-    cv = cv.at[rows, slot].set(v[:, 0].astype(cv.dtype))
-    cpos = cpos.at[rows, slot].set(idx)
+    new_cache = {"k": rows.write_at(cache["k"], slot, k[:, 0]),
+                 "v": rows.write_at(cache["v"], slot, v[:, 0]),
+                 "pos": rows.write_at(cache["pos"], slot, idx)}
+    ck, cv, cpos = (rows.read(new_cache[n]) for n in ("k", "v", "pos"))
     qpos = idx[:, None]
-    new_cache = {"k": ck, "v": cv, "pos": cpos}
 
     with jax.named_scope("KERNEL_paged_attention"):
         kk = _repeat_kv(ck, cfg.n_heads // cfg.n_kv_heads)
@@ -342,13 +357,34 @@ def _remat(fn, cfg):
 
 
 def scan_blocks(stacked: Params, x: jax.Array, cfg, *, mode: str, positions,
-                cache: Optional[dict], cache_index, enc_out, layer0_offset: int):
+                cache: Optional[dict], cache_index, enc_out, layer0_offset: int,
+                slots=None):
     """lax.scan over a homogeneous layer stack.
 
     cache (if given) is a per-layer pytree stacked on axis 0 (same order).
+    With ``slots`` (decode on the resident cache) the stacked cache rides in
+    the carry and each layer updates it in place at (layer, slot, ...), so
+    no layer's cache is sliced out and none is stacked again as the scan's
+    output.
     Returns (x, new_stacked_cache, total_aux).
     """
     values = jax.tree.map(lambda p: p.value, stacked, is_leaf=_is_param)
+
+    if slots is not None:
+        def resident_body(carry, xs):
+            h, aux, cache = carry
+            layer_values, layer = xs
+            h, cache, a = block_apply(
+                _slice_layer(stacked, layer_values), h, cfg, layer0_offset,
+                mode=mode, positions=positions, cache=cache,
+                cache_index=cache_index, rows=CacheRows(slots, (layer,)))
+            return (h, aux + a, cache), None
+
+        n_layers = jax.tree.leaves(values)[0].shape[0]
+        (x, aux, cache), _ = lax.scan(
+            resident_body, (x, jnp.zeros((), F32), cache),
+            (values, jnp.arange(n_layers)))
+        return x, cache, aux
 
     def body(carry, xs):
         h, aux = carry

@@ -45,7 +45,7 @@ from repro.core.policy import RuntimeDefaults, SchedulingPolicy, cc_aware_defaul
 from repro.obs import Observatory, wall
 from repro.trace import opclasses as oc
 from repro.models.model import Model, prefill
-from .kv_cache import RaggedBatch, gather_slot_cache, scatter_slot_cache
+from .kv_cache import RaggedBatch
 from .overlap import OverlapScheduler
 from .sampler import SamplingParams, sample
 
@@ -109,23 +109,20 @@ def prefill_logits_cache(cfg, params, tokens, max_len: int):
 
 
 def packed_step(model: Model, params, caches, tokens, index, slots):
-    """Packed ragged decode (DESIGN.md §10): gather the packed rows out of
-    the resident cache, decode at the packed width, scatter them back."""
-    packed = gather_slot_cache(caches, slots,
-                               scan_layers=model.cfg.scan_layers)
-    logits, packed = model.decode_step(params, packed, tokens, index)
-    caches = scatter_slot_cache(caches, packed, slots,
-                                scan_layers=model.cfg.scan_layers)
-    return logits, caches
+    """Packed ragged decode (DESIGN.md §10) on the resident cache: row i
+    decodes slot ``slots[i]`` at the packed width, writing its new entries
+    in place where the slot resides and attending over them there."""
+    return model.decode_step(params, caches, tokens, index, slots)
 
 
 def packed_step_of(model: Model):
-    """``packed_step`` bound to ``model`` under its own name, so its jit
-    (and the program a profiler trace shows) is called ``packed_step``."""
+    """``packed_step`` bound to ``model`` and jitted under its own name, so
+    the program a profiler trace shows is called ``packed_step``.  The
+    resident cache (argument 1) is donated: the step updates it in place."""
     def bound(params, caches, tokens, index, slots):
         return packed_step(model, params, caches, tokens, index, slots)
     bound.__name__ = bound.__qualname__ = "packed_step"
-    return bound
+    return jax.jit(bound, donate_argnums=(1,))
 
 
 def _upload(host: np.ndarray, op_class: str) -> jax.Array:
@@ -231,7 +228,9 @@ class ServingEngine:
         # `_bucket` pads widths to powers of two so the trace count stays
         # O(log max_batch) even when the ready set raggedly shrinks slot by
         # slot as requests finish.
-        self._packed_decode = jax.jit(packed_step_of(model))
+        self._packed_decode = packed_step_of(model)
+        #: packed steps run on the donated resident cache (``stats()``)
+        self.resident_decode_steps = 0
 
         # worker x coalescer composition: with a coalescer the drains queue
         # and the worker's seat becomes a secure channel the fused flushes
@@ -252,7 +251,7 @@ class ServingEngine:
     def _bucket(self, n: int) -> int:
         """Smallest power-of-two >= n, capped at max_batch: the executed
         width of a packed step.  Pad rows duplicate a real slot, so the
-        duplicate scatter writes carry identical values (deterministic) —
+        duplicate cache writes carry identical values (deterministic) —
         accounting (crossings, compute charge, drain) always prices the
         REAL packed size, never the bucket."""
         b = 1
@@ -703,9 +702,10 @@ class ServingEngine:
         """Packed ragged decode step (DESIGN.md §10, the default path).
 
         The step executes over exactly the ready slots: a ``RaggedBatch``
-        names the packed rows and their per-slot KV lengths, the forward
-        gathers those rows out of the resident cache, decodes at the packed
-        width, and scatters the updated rows back.  Prep crossings, the
+        names the packed rows and their per-slot KV lengths, and the forward
+        decodes them at the packed width on the donated resident cache,
+        writing each row's new entries where its slot resides.  Prep
+        crossings, the
         compute charge (``DECODE_PACKED``, priced per slot KV length — the
         same terms as the masked charge, which the parity property pins)
         and the drain all cover the packed set and nothing else, so a
@@ -728,7 +728,7 @@ class ServingEngine:
         self._whole_batch_barrier(slots)
 
         # --- packed forward at the bucketed width.  Pad rows (if any)
-        # duplicate the first packed slot: the duplicate scatter writes
+        # duplicate the first packed slot: the duplicate cache writes
         # carry identical values, so bucketing is invisible to state —
         # and ALL accounting above/below prices the real size n.
         width = self._bucket(n)
@@ -747,6 +747,7 @@ class ServingEngine:
                 self.params, self.caches, _upload(exec_tokens, DECODE_INPUT),
                 _upload(exec_index, DECODE_INPUT),
                 _upload(exec_slots, DECODE_INPUT))
+            self.resident_decode_steps += 1
 
         if self.compute is not None:
             with wall.span("account", what="decode_charge"):
@@ -886,6 +887,7 @@ class ServingEngine:
                           + self.gateway.stats.d2h_crossings),
             "mean_ttft_s": float(np.mean(ttfts)) if ttfts else 0.0,
             "steps": self.step_count,
+            "resident_decode_steps": self.resident_decode_steps,
             "overlap": self.overlap.stats_dict(),
             "closed_dirty": self.closed_dirty,
         }

@@ -12,10 +12,12 @@ The engine can run in two cache modes:
 
 Packed ragged decode (DESIGN.md §10) lives here too: ``RaggedBatch``
 describes one step's packed ready set (slot ids + per-slot KV lengths, no
-padding), and ``gather_slot_cache``/``scatter_slot_cache`` move exactly
-those slots' cache rows in and out of the full-resident cache tree so the
-forward runs over a packed batch instead of a dense one padded to
-``max_batch``.  TensorRT-LLM's ``gpt_attention.md`` argues packed
+padding).  The step decodes those slots in place on the resident cache
+(``models.model.decode_step`` with ``slots``), at a packed width instead of
+a dense one padded to ``max_batch``.  ``gather_slot_cache``/
+``scatter_slot_cache`` copy the slots' rows out of and back into the
+resident tree: around a plain ``decode_step`` they are the reference the
+in-place step is tested against.  TensorRT-LLM's ``gpt_attention.md`` argues packed
 (non-padded) batching is strictly better; the ragged block-table export
 (``ragged_block_tables``) is the paged-kernel shape of the same idea.
 """

@@ -7,6 +7,7 @@ fixture so that importing this file never loads the TPU library.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -18,7 +19,8 @@ from repro.kernels.dequant.ops import dequant
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.paged_attention.ops import paged_attention
 from repro.models.model import Model
-from repro.serving.engine import packed_step, prefill_logits_cache
+from repro.serving.engine import (packed_step, packed_step_of,
+                                  prefill_logits_cache)
 
 #: HBM of one TPU v5e chip (Google Cloud documentation, "TPU v5e")
 V5E_HBM_BYTES = 16e9
@@ -97,14 +99,18 @@ def test_dequant_compiles(one_chip, tpu_branch, codec):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _olmo_cache(model, sharding):
+    return jax.tree.map(
+        lambda s: _on(sharding, s.shape, s.dtype),
+        jax.eval_shape(lambda: model.init_cache(8, 1024)))
+
+
 def test_olmo_packed_decode_step_fits_one_chip(one_chip, tpu_branch, olmo):
-    """The engine's whole packed step (gather, 16-layer decode, scatter) at
-    max_batch=8, max_len=1024, with params and cache as the engine holds
-    them."""
+    """The engine's whole packed step (the 16-layer decode on the resident
+    cache) at max_batch=8, max_len=1024, with params and cache as the
+    engine holds them."""
     params = _olmo_params(olmo, one_chip)
-    caches = jax.tree.map(
-        lambda s: _on(one_chip, s.shape, s.dtype),
-        jax.eval_shape(lambda: olmo.init_cache(8, 1024)))
+    caches = _olmo_cache(olmo, one_chip)
     rows = _on(one_chip, (8,), jnp.int32)
     tokens = _on(one_chip, (8, 1), jnp.int32)
     compiled = _compile(functools.partial(packed_step, olmo),
@@ -113,6 +119,36 @@ def test_olmo_packed_decode_step_fits_one_chip(one_chip, tpu_branch, olmo):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
     assert total < V5E_HBM_BYTES, f"{total / 1e9:.2f} GB"
+
+
+#: ops that hand a whole buffer on without writing one
+_PASS_THROUGH = {"parameter", "get-tuple-element", "tuple", "while", "bitcast"}
+
+
+def test_olmo_packed_step_decodes_in_place(one_chip, tpu_branch, olmo):
+    """The engine's jitted packed step, cache donated, at width 8: the
+    resident cache is aliased to the output, the step needs next to no
+    scratch, and no op outside the layer loop copies or rebuilds a whole
+    K or V cache (the loop writes one row a layer and slot in place)."""
+    params = _olmo_params(olmo, one_chip)
+    caches = _olmo_cache(olmo, one_chip)
+    rows = _on(one_chip, (8,), jnp.int32)
+    tokens = _on(one_chip, (8, 1), jnp.int32)
+    compiled = packed_step_of(olmo).lower(params, caches, tokens, rows,
+                                          rows).compile()
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(leaf.size * leaf.dtype.itemsize
+                      for leaf in jax.tree.leaves(caches))
+    assert cache_bytes > 1e9
+    assert mem.temp_size_in_bytes < 0.1e9, mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes >= cache_bytes, mem.alias_size_in_bytes
+    hlo = compiled.as_text()
+    entry = hlo[hlo.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    whole = [m.group(1) for m in re.finditer(
+        r"= bf16\[16,8,1024,16,128\]\{[^}]*\} ([\w-]+)\(", entry)]
+    assert whole, "no whole-cache buffer found in ENTRY"
+    assert set(whole) <= _PASS_THROUGH, sorted(set(whole) - _PASS_THROUGH)
 
 
 def test_olmo_prefill_compiles_at_512_tokens(one_chip, tpu_branch, olmo):
