@@ -47,7 +47,15 @@ class ModelConfig:
     moe_top_k: int = 0
     d_expert: int = 0
     first_layer_dense: bool = True    # deepseek: layer 0 is a dense MLP
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25     # training only; serving drops no token
+    #: expert parallelism: this chip holds routed experts
+    #: [expert_offset, expert_offset + n_experts_held) of every MoE layer and
+    #: computes only their part of the result (0 = it holds all of them)
+    n_experts_held: int = 0
+    expert_offset: int = 0
+    #: rescale the top-k gates to sum to one (False: the softmax
+    #: probabilities themselves, as deepseek-v2 routes)
+    norm_topk_prob: bool = True
 
     # MLA
     use_mla: bool = False
@@ -55,6 +63,13 @@ class ModelConfig:
     rope_head_dim: int = 64
     nope_head_dim: int = 128
     v_head_dim: int = 128
+    #: YaRN rotary scaling of the rope dims (deepseek-v2; 0 = plain RoPE)
+    yarn_factor: float = 0.0
+    yarn_original_max_len: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # SSM / xLSTM / hybrid
     ssm_kind: str = ""                # xlstm | mamba
@@ -86,6 +101,11 @@ class ModelConfig:
     def __post_init__(self):
         if self.head_dim == 0:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+
+    @property
+    def experts_held(self) -> int:
+        """Routed experts whose weights this chip holds."""
+        return self.n_experts_held or self.n_routed_experts
 
     @property
     def is_attention_free(self) -> bool:

@@ -124,12 +124,56 @@ def apply_norm(params: Params, x: jax.Array, kind: str, eps: float = 1e-6) -> ja
 # Rotary position embeddings
 # ---------------------------------------------------------------------------------
 
-def rope_table(positions: jax.Array, dim: int, theta: float = 10000.0):
-    """(positions...) -> (sin, cos) of shape positions.shape + (dim/2,)."""
+def rope_table(positions: jax.Array, dim: int, theta: float = 10000.0,
+               inv_freq=None, mscale: float = 1.0):
+    """(positions...) -> (sin, cos) of shape positions.shape + (dim/2,);
+    ``inv_freq`` (dim/2,) replaces the plain frequencies ``theta^(-2i/dim)``
+    and ``mscale`` scales both (YaRN, ``yarn_rope``)."""
     half = dim // 2
-    freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
-    angles = positions.astype(jnp.float32)[..., None] * freqs
-    return jnp.sin(angles), jnp.cos(angles)
+    if inv_freq is None:
+        inv_freq = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    angles = positions.astype(jnp.float32)[..., None] * inv_freq
+    sin, cos = jnp.sin(angles), jnp.cos(angles)
+    if mscale != 1.0:
+        sin, cos = sin * mscale, cos * mscale
+    return sin, cos
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature term ``0.1 * mscale * ln(factor) + 1``."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1.0 else 1.0
+
+
+def yarn_rope(cfg, dim: int) -> tuple:
+    """(inv_freq, mscale) of ``rope_table`` for ``dim`` rope dims under
+    ``cfg``'s YaRN scaling: frequencies whose wavelength fits the original
+    context ``L0`` keep theirs (``f_extra = theta^(-2i/dim)``), the longest
+    ones are divided by the factor (``f_inter``), and a linear ramp over the
+    pairs ``i`` between the two correction dims blends them.  Plain RoPE
+    (``(None, 1.0)``) where ``cfg.yarn_factor`` is 0."""
+    factor = cfg.yarn_factor
+    if not factor:
+        return None, 1.0
+    base, l0 = cfg.rope_theta, cfg.yarn_original_max_len
+
+    def correction_dim(rotations):
+        return dim * math.log(l0 / (rotations * 2 * math.pi)) / (2 * math.log(base))
+
+    low = max(math.floor(correction_dim(cfg.yarn_beta_fast)), 0)
+    high = min(math.ceil(correction_dim(cfg.yarn_beta_slow)), dim - 1)
+    f_extra = 1.0 / (base ** (np.arange(0, dim, 2, dtype=np.float64) / dim))
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
+    inv_freq = f_extra / factor * ramp + f_extra * (1 - ramp)
+    mscale = (yarn_mscale(factor, cfg.yarn_mscale)
+              / yarn_mscale(factor, cfg.yarn_mscale_all_dim))
+    return jnp.asarray(inv_freq, jnp.float32), mscale
+
+
+def mla_softmax_scale(cfg) -> float:
+    """MLA's softmax scale: ``1/sqrt(nope + rope dims)``, times YaRN's
+    ``mscale(factor, mscale_all_dim)^2`` (one without YaRN)."""
+    return (yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+            / math.sqrt(cfg.nope_head_dim + cfg.rope_head_dim))
 
 
 def apply_rope(x: jax.Array, sin: jax.Array, cos: jax.Array) -> jax.Array:
@@ -226,11 +270,13 @@ def _repeat_kv(k: jax.Array, n_rep: int) -> jax.Array:
 
 def dense_attention(q, k, v, *, causal: bool, q_offset: int = 0,
                     window: Optional[int] = None,
-                    kv_len: Optional[jax.Array] = None) -> jax.Array:
-    """Plain softmax attention.  q:(B,Sq,H,D) k,v:(B,Sk,H,D)."""
+                    kv_len: Optional[jax.Array] = None,
+                    scale: Optional[float] = None) -> jax.Array:
+    """Plain softmax attention.  q:(B,Sq,H,D) k,v:(B,Sk,H,D); the logits
+    are scaled by ``scale`` (default ``1/sqrt(D)``)."""
     b, sq, h, d = q.shape
     sk = k.shape[1]
-    scale = 1.0 / math.sqrt(d)
+    scale = 1.0 / math.sqrt(d) if scale is None else scale
     logits = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                         k.astype(jnp.float32)) * scale
     qpos = q_offset + jnp.arange(sq)[:, None]
@@ -249,9 +295,11 @@ def dense_attention(q, k, v, *, causal: bool, q_offset: int = 0,
 
 
 def blockwise_attention(q, k, v, *, causal: bool, block_kv: int = 1024,
-                        q_offset: int = 0, window: Optional[int] = None) -> jax.Array:
+                        q_offset: int = 0, window: Optional[int] = None,
+                        scale: Optional[float] = None) -> jax.Array:
     """Flash-attention algorithm in pure jnp: lax.scan over KV blocks with
     running (max, sum, acc) — O(block) memory, the portable long-seq path.
+    The logits are scaled by ``scale`` (default ``1/sqrt(D)``).
     """
     b, sq, h, d = q.shape
     sk = k.shape[1]
@@ -260,7 +308,7 @@ def blockwise_attention(q, k, v, *, causal: bool, block_kv: int = 1024,
     if pad:
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    scale = 1.0 / math.sqrt(d)
+    scale = 1.0 / math.sqrt(d) if scale is None else scale
     qf = q.astype(jnp.float32) * scale
     kb = k.reshape(b, nblk, block_kv, h, d).astype(jnp.float32)
     vb = v.reshape(b, nblk, block_kv, h, d).astype(jnp.float32)
@@ -298,15 +346,19 @@ def blockwise_attention(q, k, v, *, causal: bool, block_kv: int = 1024,
 
 def attention_core(q, k, v, *, impl: str, causal: bool, q_offset: int = 0,
                    window: Optional[int] = None, kv_len=None,
-                   block_kv: int = 1024) -> jax.Array:
+                   block_kv: int = 1024,
+                   scale: Optional[float] = None) -> jax.Array:
     """All attention routes through here under a KERNEL_* named scope, so the
     dry-run HLO analyzer can attribute its HBM traffic and substitute the
-    Pallas kernel's (VMEM-resident) byte profile — see launch/hlo_analysis."""
+    Pallas kernel's (VMEM-resident) byte profile — see launch/hlo_analysis.
+    ``scale`` replaces the logits' default ``1/sqrt(D)``."""
     n_rep = q.shape[2] // k.shape[2]
     if impl == "pallas":
         # TPU kernels; GQA handled natively (no KV repeat materialization)
         from repro.kernels.flash_attention import ops as fa_ops
         if kv_len is None and q.shape[1] > 1:
+            if scale is not None:   # the kernel scales by 1/sqrt(D) itself
+                q = (q * (scale * math.sqrt(q.shape[-1]))).astype(q.dtype)
             return fa_ops.flash_attention(q, k, v, causal=causal, window=window)
         impl = "dense"  # decode path handled by paged kernel at cache level
     k = _repeat_kv(k, n_rep)
@@ -315,14 +367,15 @@ def attention_core(q, k, v, *, impl: str, causal: bool, q_offset: int = 0,
         if kv_len is not None:
             with jax.named_scope("KERNEL_paged_attention"):
                 return dense_attention(q, k, v, causal=causal, q_offset=q_offset,
-                                       window=window, kv_len=kv_len)
+                                       window=window, kv_len=kv_len, scale=scale)
         with jax.named_scope("KERNEL_flash_attention"):
             return blockwise_attention(q, k, v, causal=causal, q_offset=q_offset,
-                                       window=window, block_kv=block_kv)
+                                       window=window, block_kv=block_kv,
+                                       scale=scale)
     scope = "KERNEL_paged_attention" if kv_len is not None else "KERNEL_flash_attention"
     with jax.named_scope(scope):
         return dense_attention(q, k, v, causal=causal, q_offset=q_offset,
-                               window=window, kv_len=kv_len)
+                               window=window, kv_len=kv_len, scale=scale)
 
 
 # ---------------------------------------------------------------------------------
@@ -449,27 +502,31 @@ def mla_block(p: Params, x: jax.Array, cfg, *, positions: jax.Array,
     c, k_pe = ckv[..., :dc], ckv[..., dc:]
     c = apply_norm(p["kv_norm"], c, "rmsnorm")
 
-    sin, cos = rope_table(positions, dr, cfg.rope_theta)
+    inv_freq, mscale = yarn_rope(cfg, dr)
+    sin, cos = rope_table(positions, dr, cfg.rope_theta, inv_freq, mscale)
     q_pe = apply_rope(q_pe, sin, cos)
     k_pe = apply_rope(k_pe[:, :, None, :], sin, cos)[:, :, 0, :]  # shared across heads
 
-    scale = 1.0 / math.sqrt(dn + dr)
+    scale = mla_softmax_scale(cfg)
     if cache is not None:
         # decode (s=1, per-sequence index): write latents, absorbed attention
         idx = jnp.broadcast_to(jnp.asarray(cache_index, jnp.int32), (b,))
         cache = dict(cache, c=rows.write_at(cache["c"], idx, c[:, 0]),
                      k_pe=rows.write_at(cache["k_pe"], idx, k_pe[:, 0]))
-        cc, ck = rows.read(cache["c"]), rows.read(cache["k_pe"])
         # absorbed decode: q_c = q_nope @ W_uk  -> score against latent cache
         # (TPU: bf16 cache operands + f32 accumulation — no f32 cache copy)
         q_c = jnp.einsum("bshn,chn->bshc", q_nope, pvalue(p["wuk"]))
-        logits = (einsum_f32acc("bshc,btc->bhst", q_c, cc)
-                  + einsum_f32acc("bshr,btr->bhst", q_pe, ck)) * scale
-        tpos = jnp.arange(cc.shape[1])
-        mask = tpos[None, :] <= idx[:, None]             # (b, T)
-        logits = jnp.where(mask[:, None, None, :], logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1)
-        o_c = einsum_f32acc("bhst,btc->bshc", probs, cc)
+        with jax.named_scope("KERNEL_mla_decode"):
+            # the rows' latents, stacked as the scores read them (the TPU
+            # compiler emits most of the stack's fusions with no source op)
+            cc, ck = rows.read(cache["c"]), rows.read(cache["k_pe"])
+            logits = (einsum_f32acc("bshc,btc->bhst", q_c, cc)
+                      + einsum_f32acc("bshr,btr->bhst", q_pe, ck)) * scale
+            tpos = jnp.arange(cc.shape[1])
+            mask = tpos[None, :] <= idx[:, None]             # (b, T)
+            logits = jnp.where(mask[:, None, None, :], logits, -1e30)
+            probs = jax.nn.softmax(logits, axis=-1)
+            o_c = einsum_f32acc("bhst,btc->bshc", probs, cc)
         out = jnp.einsum("bshc,chv->bshv", o_c,
                          pvalue(p["wuv"]).astype(jnp.float32)).astype(x.dtype)
         y = jnp.einsum("bshv,hvd->bsd", out, pvalue(p["wo"]))
@@ -485,7 +542,7 @@ def mla_block(p: Params, x: jax.Array, cfg, *, positions: jax.Array,
     out = attention_core(q_full, k_full,
                          jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, dn + dr - dv))),
                          impl=cfg.attn_impl, causal=True,
-                         block_kv=cfg.attn_block_kv)[..., :dv]
+                         block_kv=cfg.attn_block_kv, scale=scale)[..., :dv]
     y = jnp.einsum("bshv,hvd->bsd", out, pvalue(p["wo"]))
     return y, ((c, k_pe) if return_kv else None)
 
@@ -533,11 +590,14 @@ def mlp_block(p: Params, x: jax.Array, cfg) -> jax.Array:
 # ---------------------------------------------------------------------------------
 
 def init_moe(key, cfg) -> Params:
+    """The router over all ``n_routed_experts`` and the weights of the
+    experts held here (``cfg.experts_held``)."""
     d, f = cfg.d_model, cfg.d_expert
-    e = cfg.n_routed_experts
+    e = cfg.experts_held
     ks = jax.random.split(key, 5)
     p = {
-        "router": make_param(ks[0], (d, e), ("embed", "expert"), fan_in=d, dtype=jnp.float32),
+        "router": make_param(ks[0], (d, cfg.n_routed_experts), ("embed", "expert"),
+                             fan_in=d, dtype=jnp.float32),
         "wi": make_param(ks[1], (e, d, f), ("expert", "embed", "mlp"), fan_in=d, dtype=cfg.dtype),
         "wg": make_param(ks[2], (e, d, f), ("expert", "embed", "mlp"), fan_in=d, dtype=cfg.dtype),
         "wo": make_param(ks[3], (e, f, d), ("expert", "mlp", "embed"), fan_in=f, dtype=cfg.dtype),
@@ -564,8 +624,8 @@ def _moe_expert_compute(x, idx, gates, wi, wg, wo, *, e_total: int,
     wi/wg/wo: (e_loc, ...) local expert weights.  Returns the local experts'
     contribution to y (t, d) — summed across EP shards by the caller's psum.
 
-    Dispatch is a local scatter into (e_loc, capacity+1, d): under shard_map
-    this is a single-device scatter (no GSPMD partitioner involvement — the
+    Dispatch is local, into (e_loc, capacity, d): under shard_map a
+    single-device scatter and gather (no GSPMD partitioner involvement — the
     whole point of this structure; see EXPERIMENTS.md §Perf moe iteration).
     """
     t, d = x.shape
@@ -583,10 +643,13 @@ def _moe_expert_compute(x, idx, gates, wi, wg, wo, *, e_total: int,
     keep = flat_local & (pos < capacity)
     slot = jnp.where(keep, pos, capacity)                   # waste slot absorbs
 
-    buf = jnp.zeros((e_loc, capacity + 1, d), dtype)
-    src = jnp.repeat(x, k, axis=0).astype(dtype)
-    buf = buf.at[flat_lidx, slot].add(jnp.where(keep[:, None], src, 0))
-    buf = buf[:, :capacity]
+    # a kept assignment owns its (expert, slot): scatter its token's index
+    # there (``t``, a row of zeros, where none is kept) and gather the rows;
+    # scattering the rows themselves takes the TPU compiler seconds a layer
+    token = jnp.full((e_loc, capacity + 1), t, jnp.int32).at[
+        flat_lidx, slot].set(jnp.where(keep, jnp.arange(t * k) // k, t))
+    rows = jnp.concatenate([x.astype(dtype), jnp.zeros((1, d), dtype)])
+    buf = rows[token[:, :capacity]]
 
     hg = jax.nn.silu(jnp.einsum("ecd,edf->ecf", buf, wg))
     hi = jnp.einsum("ecd,edf->ecf", buf, wi)
@@ -600,8 +663,16 @@ def _moe_expert_compute(x, idx, gates, wi, wg, wo, *, e_total: int,
 
 
 def moe_block(p: Params, x: jax.Array, cfg, *, capacity_factor: float = 1.25,
-              no_drop: bool = False) -> tuple[jax.Array, jax.Array]:
-    """Top-k routed + shared experts.  Returns (y, aux_loss).
+              no_drop: bool = False) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Top-k routed + shared experts.  Returns (y, aux_loss, routed).
+
+    The router scores all ``n_routed_experts``; the routed part of ``y`` is
+    that of the experts held here (``cfg.experts_held`` from
+    ``cfg.expert_offset``: one chip's share under expert parallelism, whose
+    other shares live on other chips), and the shared experts are added
+    once.  ``routed`` (held,) int32 counts the assignments to each held
+    expert.  The gates are the top-k softmax probabilities, rescaled to sum
+    to one where ``cfg.norm_topk_prob``.
 
     Expert parallelism via shard_map over the model axis: activations are
     replicated across EP shards (they already are, in the TP layout), each
@@ -616,12 +687,21 @@ def moe_block(p: Params, x: jax.Array, cfg, *, capacity_factor: float = 1.25,
     """
     g, s, d = x.shape
     e, k = cfg.n_routed_experts, cfg.moe_top_k
+    wi, wg, wo = pvalue(p["wi"]), pvalue(p["wg"]), pvalue(p["wo"])
+    held = wi.shape[0]
 
-    gate_logits = jnp.einsum("gsd,de->gse", x.astype(jnp.float32),
-                             pvalue(p["router"]))
-    probs = jax.nn.softmax(gate_logits, axis=-1)
-    gates, idx = lax.top_k(probs, k)                        # (g,s,k)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    with jax.named_scope("KERNEL_moe_route"):
+        # the router in float32, as published (f32 weights and activations)
+        gate_logits = jnp.einsum("gsd,de->gse", x.astype(jnp.float32),
+                                 pvalue(p["router"]),
+                                 precision=lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(gate_logits, axis=-1)
+        gates, idx = lax.top_k(probs, k)                    # (g,s,k)
+        if cfg.norm_topk_prob:
+            gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+        routed = jnp.sum(idx.reshape(-1, 1) == cfg.expert_offset
+                         + jnp.arange(held, dtype=idx.dtype), axis=0,
+                         dtype=jnp.int32)
 
     # load-balancing aux loss (Switch-style)
     me = probs.mean(axis=(0, 1))
@@ -631,18 +711,17 @@ def moe_block(p: Params, x: jax.Array, cfg, *, capacity_factor: float = 1.25,
     mesh = _MOE_CONTEXT["mesh"]
     model_axis = _MOE_CONTEXT["model_axis"]
     ep = mesh is not None and model_axis is not None and \
-        e % mesh.shape[model_axis] == 0
-
-    wi, wg, wo = pvalue(p["wi"]), pvalue(p["wg"]), pvalue(p["wo"])
+        held % mesh.shape[model_axis] == 0
 
     if not ep:
         t_loc = g * s
         capacity = t_loc if no_drop else int(
             max(k, math.ceil(t_loc * k / e * capacity_factor)))
-        y = _moe_expert_compute(
-            x.reshape(t_loc, d), idx.reshape(t_loc, k), gates.reshape(t_loc, k),
-            wi, wg, wo, e_total=e, capacity=capacity, dtype=cfg.dtype,
-            e_offset=0)
+        with jax.named_scope("KERNEL_moe_experts"):
+            y = _moe_expert_compute(
+                x.reshape(t_loc, d), idx.reshape(t_loc, k),
+                gates.reshape(t_loc, k), wi, wg, wo, e_total=e,
+                capacity=capacity, dtype=cfg.dtype, e_offset=cfg.expert_offset)
     else:
         from jax.sharding import PartitionSpec as P
         data_axes = _MOE_CONTEXT["data_axes"]
@@ -655,15 +734,17 @@ def moe_block(p: Params, x: jax.Array, cfg, *, capacity_factor: float = 1.25,
         t_loc = g_loc * s
         capacity = t_loc if no_drop else int(
             max(k, math.ceil(t_loc * k / e * capacity_factor)))
-        e_loc = e // n_model
+        e_loc = held // n_model
 
         def body(x_blk, idx_blk, gates_blk, wi_l, wg_l, wo_l):
             gb = x_blk.shape[0]
-            e_off = lax.axis_index(model_axis) * e_loc
-            y_loc = _moe_expert_compute(
-                x_blk.reshape(gb * s, d), idx_blk.reshape(gb * s, k),
-                gates_blk.reshape(gb * s, k), wi_l, wg_l, wo_l,
-                e_total=e, capacity=capacity, dtype=cfg.dtype, e_offset=e_off)
+            e_off = cfg.expert_offset + lax.axis_index(model_axis) * e_loc
+            with jax.named_scope("KERNEL_moe_experts"):
+                y_loc = _moe_expert_compute(
+                    x_blk.reshape(gb * s, d), idx_blk.reshape(gb * s, k),
+                    gates_blk.reshape(gb * s, k), wi_l, wg_l, wo_l,
+                    e_total=e, capacity=capacity, dtype=cfg.dtype,
+                    e_offset=e_off)
             y_loc = lax.psum(y_loc, model_axis)             # EP combine
             return y_loc.reshape(gb, s, d)
 
@@ -679,7 +760,7 @@ def moe_block(p: Params, x: jax.Array, cfg, *, capacity_factor: float = 1.25,
     y = y.astype(x.dtype).reshape(g, s, d)
     if cfg.n_shared_experts:
         y = y + mlp_block(p["shared"], x, cfg)
-    return y, aux
+    return y, aux, routed
 
 
 # ---------------------------------------------------------------------------------

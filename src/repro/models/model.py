@@ -87,56 +87,66 @@ def _encoder_forward(params, cfg, frames):
     positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
     enc = params["encoder"]
     if cfg.scan_layers:
-        x, _, _ = scan_blocks(enc["blocks"], x, enc_cfg, mode="train",
-                              positions=positions, cache=None, cache_index=None,
-                              enc_out=None, layer0_offset=0)
+        x, _, _, _ = scan_blocks(enc["blocks"], x, enc_cfg, mode="train",
+                                 positions=positions, cache=None,
+                                 cache_index=None, enc_out=None,
+                                 layer0_offset=0)
     else:
         for i, p in enumerate(enc["blocks"]):
-            x, _, _ = block_apply(p, x, enc_cfg, i, mode="train",
-                                  positions=positions, causal=False)
+            x, _, _, _ = block_apply(p, x, enc_cfg, i, mode="train",
+                                     positions=positions, causal=False)
     return apply_norm(enc["final_norm"], x, cfg.norm_kind)
 
 
 def _trunk(params, cfg, x, *, mode, positions, caches=None, cache_index=None,
            enc_out=None, slots=None):
     """Run all decoder blocks.  caches layout mirrors params['blocks'];
-    with ``slots`` it is the resident cache of every slot (decode_step)."""
+    with ``slots`` it is the resident cache of every slot (decode_step).
+    Returns (x, new_caches, aux, routed): ``routed`` (MoE layers, experts
+    held) int32 assignment counts, ``None`` in a model without experts."""
     aux = jnp.zeros((), F32)
     rows = CacheRows(slots)
     start = 1 if "block0" in params else 0
     new_caches: dict = {}
+    routed = []                 # (layers, held) pieces, in layer order
     if start:
         c0 = caches["block0"] if caches else None
-        x, nc0, a0 = block_apply(params["block0"], x, cfg, 0, mode=mode,
-                                 positions=positions, cache=c0,
-                                 cache_index=cache_index, enc_out=enc_out,
-                                 rows=rows)
+        x, nc0, a0, r0 = block_apply(params["block0"], x, cfg, 0, mode=mode,
+                                     positions=positions, cache=c0,
+                                     cache_index=cache_index, enc_out=enc_out,
+                                     rows=rows)
         aux += a0
+        if r0 is not None:
+            routed.append(r0[None])
         if mode != "train":
             new_caches["block0"] = nc0
     blk_cache = caches["blocks"] if caches else None
     if cfg.scan_layers:
-        x, ncs, a = scan_blocks(params["blocks"], x, cfg, mode=mode,
-                                positions=positions, cache=blk_cache,
-                                cache_index=cache_index, enc_out=enc_out,
-                                layer0_offset=start, slots=slots)
+        x, ncs, a, r = scan_blocks(params["blocks"], x, cfg, mode=mode,
+                                   positions=positions, cache=blk_cache,
+                                   cache_index=cache_index, enc_out=enc_out,
+                                   layer0_offset=start, slots=slots)
         aux += a
+        if r is not None:
+            routed.append(r)
         if mode != "train":
             new_caches["blocks"] = ncs
     else:
         ncs = []
         for i, p in enumerate(params["blocks"]):
             c = None if blk_cache is None else _index_cache(blk_cache, i)
-            x, nc, a = block_apply(p, x, cfg, start + i, mode=mode,
-                                   positions=positions, cache=c,
-                                   cache_index=cache_index, enc_out=enc_out,
-                                   rows=rows)
+            x, nc, a, r = block_apply(p, x, cfg, start + i, mode=mode,
+                                      positions=positions, cache=c,
+                                      cache_index=cache_index, enc_out=enc_out,
+                                      rows=rows)
             aux += a
+            if r is not None:
+                routed.append(r[None])
             ncs.append(nc)
         if mode != "train":
             new_caches["blocks"] = ncs
     x = apply_norm(params["final_norm"], x, cfg.norm_kind)
-    return x, new_caches, aux
+    return x, new_caches, aux, (jnp.concatenate(routed) if routed else None)
 
 
 def _index_cache(blk_cache, i):
@@ -178,8 +188,8 @@ def _assemble_inputs(params, cfg, batch) -> tuple[jax.Array, jax.Array, Any, int
 def forward(params, cfg, batch) -> tuple[jax.Array, jax.Array]:
     """Training forward.  Returns (logits over token positions, aux_loss)."""
     x, positions, enc_out, prefix = _assemble_inputs(params, cfg, batch)
-    x, _, aux = _trunk(params, cfg, x, mode="train", positions=positions,
-                       enc_out=enc_out)
+    x, _, aux, _ = _trunk(params, cfg, x, mode="train", positions=positions,
+                          enc_out=enc_out)
     if prefix:
         x = x[:, prefix:]
     return _logits(params, cfg, x), aux
@@ -222,26 +232,33 @@ def init_cache(cfg, batch: int, max_len: int, enc_len: int = 0,
     return caches
 
 
-def prefill(params, cfg, batch, max_len: int):
+def prefill(params, cfg, batch, max_len: int, *, routed: bool = False):
     """Process the full prompt, build the cache in one shot.
 
-    Returns (last_position_logits, caches, next_index).
+    Returns (last_position_logits, caches, next_index), and with ``routed``
+    the (MoE layers, experts held) assignment counts as well (``None``
+    without experts).
     """
     x, positions, enc_out, prefix = _assemble_inputs(params, cfg, batch)
     b, total = positions.shape
     enc_len = cfg.frontend_tokens if cfg.encoder_layers else 0
     caches = init_cache(cfg, b, max_len, enc_len,
                         dtype=cfg.dtype)
-    x, new_caches, _ = _trunk(params, cfg, x, mode="prefill",
-                              positions=positions, caches=caches,
-                              cache_index=0, enc_out=enc_out)
+    x, new_caches, _, counts = _trunk(params, cfg, x, mode="prefill",
+                                      positions=positions, caches=caches,
+                                      cache_index=0, enc_out=enc_out)
     logits = _logits(params, cfg, x[:, -1:])
+    if routed:
+        return logits, new_caches, total, counts
     return logits, new_caches, total
 
 
-def decode_step(params, cfg, caches, tokens, index, slots=None):
+def decode_step(params, cfg, caches, tokens, index, slots=None, *,
+                routed: bool = False):
     """One decode step.  tokens: (B,1); index: scalar or (B,) per-sequence
-    current lengths (continuous batching).
+    current lengths (continuous batching).  Returns (logits, caches), and
+    with ``routed`` the (MoE layers, experts held) assignment counts as well
+    (``None`` without experts).
 
     Without ``slots`` the cache holds the B decoded rows, and the step
     returns their new cache.  With ``slots`` (B,) it is the resident cache
@@ -254,9 +271,12 @@ def decode_step(params, cfg, caches, tokens, index, slots=None):
     x = _embed_tokens(params, cfg, tokens)
     index = jnp.broadcast_to(jnp.asarray(index, jnp.int32), (b,))
     positions = index[:, None]
-    x, new_caches, _ = _trunk(params, cfg, x, mode="decode",
-                              positions=positions, caches=caches,
-                              cache_index=index, enc_out=None, slots=slots)
+    x, new_caches, _, counts = _trunk(params, cfg, x, mode="decode",
+                                      positions=positions, caches=caches,
+                                      cache_index=index, enc_out=None,
+                                      slots=slots)
+    if routed:
+        return _logits(params, cfg, x), new_caches, counts
     return _logits(params, cfg, x), new_caches
 
 
@@ -277,11 +297,13 @@ class Model:
     def loss(self, params, batch):
         return loss_fn(params, self.cfg, batch)
 
-    def prefill(self, params, batch, max_len: int):
-        return prefill(params, self.cfg, batch, max_len)
+    def prefill(self, params, batch, max_len: int, *, routed: bool = False):
+        return prefill(params, self.cfg, batch, max_len, routed=routed)
 
-    def decode_step(self, params, caches, tokens, index, slots=None):
-        return decode_step(params, self.cfg, caches, tokens, index, slots)
+    def decode_step(self, params, caches, tokens, index, slots=None, *,
+                    routed: bool = False):
+        return decode_step(params, self.cfg, caches, tokens, index, slots,
+                           routed=routed)
 
     def init_cache(self, batch: int, max_len: int, enc_len: int = 0):
         return init_cache(self.cfg, batch, max_len, enc_len, dtype=self.cfg.dtype)

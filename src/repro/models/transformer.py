@@ -98,7 +98,9 @@ def block_apply(p: Params, x: jax.Array, cfg, layer_idx: int, *, mode: str,
                 positions: jax.Array, cache: Optional[dict] = None,
                 cache_index=None, enc_out: Optional[jax.Array] = None,
                 causal: bool = True, rows: CacheRows = CacheRows()):
-    """Apply one block.  Returns (x, new_cache, aux_loss).
+    """Apply one block.  Returns (x, new_cache, aux_loss, routed): ``routed``
+    counts an MoE block's assignments to each expert held here (``None`` in
+    a block without experts).
 
     mode: "train" (no cache), "prefill" (build cache), "decode" (update).
     In decode, ``rows`` says where the decoded rows sit in ``cache``
@@ -109,6 +111,7 @@ def block_apply(p: Params, x: jax.Array, cfg, layer_idx: int, *, mode: str,
     """
     kind = _block_kind(cfg, layer_idx)
     aux = jnp.zeros((), F32)
+    routed = None
     new_cache: dict = {}
 
     if kind in ("mlstm", "slstm"):
@@ -128,7 +131,7 @@ def block_apply(p: Params, x: jax.Array, cfg, layer_idx: int, *, mode: str,
         x = x + y
         if mode != "train":
             new_cache["ssm"] = state
-        return x, new_cache, aux
+        return x, new_cache, aux, routed
 
     window = _layer_window(cfg, layer_idx)
     h = apply_norm(p["attn_norm"], x, cfg.norm_kind)
@@ -193,13 +196,16 @@ def block_apply(p: Params, x: jax.Array, cfg, layer_idx: int, *, mode: str,
 
     h = apply_norm(p["mlp_norm"], x, cfg.norm_kind)
     if kind == "moe":
-        y, aux = moe_block(p["mlp"], h, cfg, capacity_factor=cfg.capacity_factor,
-                           no_drop=(mode == "decode"))
+        # serving routes every token (prefill and decode); training keeps
+        # its capacity factor
+        y, aux, routed = moe_block(p["mlp"], h, cfg,
+                                   capacity_factor=cfg.capacity_factor,
+                                   no_drop=(mode != "train"))
     else:
         y = mlp_block(p["mlp"], h, cfg)
     x = x + y
     x = shard_hint(x, ("batch", "seq", "embed"))
-    return x, new_cache, aux
+    return x, new_cache, aux, routed
 
 
 # ---------------------------------------------------------------------------------
@@ -366,7 +372,8 @@ def scan_blocks(stacked: Params, x: jax.Array, cfg, *, mode: str, positions,
     the carry and each layer updates it in place at (layer, slot, ...), so
     no layer's cache is sliced out and none is stacked again as the scan's
     output.
-    Returns (x, new_stacked_cache, total_aux).
+    Returns (x, new_stacked_cache, total_aux, routed): ``routed`` stacks each
+    layer's ``block_apply`` counts (``None`` without experts).
     """
     values = jax.tree.map(lambda p: p.value, stacked, is_leaf=_is_param)
 
@@ -374,28 +381,28 @@ def scan_blocks(stacked: Params, x: jax.Array, cfg, *, mode: str, positions,
         def resident_body(carry, xs):
             h, aux, cache = carry
             layer_values, layer = xs
-            h, cache, a = block_apply(
+            h, cache, a, routed = block_apply(
                 _slice_layer(stacked, layer_values), h, cfg, layer0_offset,
                 mode=mode, positions=positions, cache=cache,
                 cache_index=cache_index, rows=CacheRows(slots, (layer,)))
-            return (h, aux + a, cache), None
+            return (h, aux + a, cache), routed
 
         n_layers = jax.tree.leaves(values)[0].shape[0]
-        (x, aux, cache), _ = lax.scan(
+        (x, aux, cache), routed = lax.scan(
             resident_body, (x, jnp.zeros((), F32), cache),
             (values, jnp.arange(n_layers)))
-        return x, cache, aux
+        return x, cache, aux, routed
 
     def body(carry, xs):
         h, aux = carry
         layer_values, layer_cache = xs
         p = _slice_layer(stacked, layer_values)
-        h, new_cache, a = block_apply(
+        h, new_cache, a, routed = block_apply(
             p, h, cfg, layer0_offset, mode=mode, positions=positions,
             cache=layer_cache, cache_index=cache_index, enc_out=enc_out)
-        return (h, aux + a), new_cache
+        return (h, aux + a), (new_cache, routed)
 
     body = _remat(body, cfg) if mode == "train" else body
-    (x, aux), new_cache = lax.scan(body, (x, jnp.zeros((), F32)),
-                                   (values, cache))
-    return x, new_cache, aux
+    (x, aux), (new_cache, routed) = lax.scan(
+        body, (x, jnp.zeros((), F32)), (values, cache))
+    return x, new_cache, aux, routed

@@ -29,6 +29,7 @@ import queue
 import threading
 import time
 import warnings
+import weakref
 from dataclasses import dataclass, field
 from typing import Any, Optional
 
@@ -97,22 +98,33 @@ class StepTrace:
     packed: int = 0
 
 
+def _and_routed(logits, caches, routed) -> tuple:
+    """A serving program's outputs: (logits, caches), and in a model with
+    experts its (MoE layers, experts held) assignment counts after them."""
+    return (logits, caches) if routed is None else (logits, caches, routed)
+
+
 @functools.partial(jax.jit, static_argnums=(0, 3))
 def prefill_logits_cache(cfg, params, tokens, max_len: int):
-    """One compiled prompt pass: (last-position logits, max_len cache).
+    """One compiled prompt pass: (last-position logits, max_len cache), and
+    the prompt's MoE assignment counts in a model with experts.
 
     Shared by every engine: jit keys on (cfg, max_len, prompt shape,
     device), so each prompt length compiles once per process.  The next
     cache index is the prompt length."""
-    logits, caches, _ = prefill(params, cfg, {"tokens": tokens}, max_len)
-    return logits, caches
+    logits, caches, _, routed = prefill(params, cfg, {"tokens": tokens},
+                                        max_len, routed=True)
+    return _and_routed(logits, caches, routed)
 
 
 def packed_step(model: Model, params, caches, tokens, index, slots):
     """Packed ragged decode (DESIGN.md §10) on the resident cache: row i
     decodes slot ``slots[i]`` at the packed width, writing its new entries
-    in place where the slot resides and attending over them there."""
-    return model.decode_step(params, caches, tokens, index, slots)
+    in place where the slot resides and attending over them there.
+    Returns (logits, caches), and in a model with experts the assignment
+    counts of every executed row."""
+    return _and_routed(*model.decode_step(params, caches, tokens, index,
+                                          slots, routed=True))
 
 
 def packed_step_of(model: Model):
@@ -129,6 +141,15 @@ def _upload(host: np.ndarray, op_class: str) -> jax.Array:
     """An engine input the jitted programs take straight from the host."""
     with wall.span("bridge.h2d", op_class=op_class, nbytes=int(host.nbytes)):
         return jnp.asarray(host)
+
+
+def _with_routed(tokens: jax.Array, routed: list) -> jax.Array:
+    """The sampled tokens with a program's MoE assignment counts (``routed``:
+    none, or the one array) after them, so that the one drain carries
+    both."""
+    if not routed:
+        return tokens
+    return jnp.concatenate([tokens, routed[0].reshape(-1).astype(tokens.dtype)])
 
 
 class ServingEngine:
@@ -231,6 +252,10 @@ class ServingEngine:
         self._packed_decode = packed_step_of(model)
         #: packed steps run on the donated resident cache (``stats()``)
         self.resident_decode_steps = 0
+        #: running total of the MoE programs' assignments to each (MoE
+        #: layer, expert held here), over prefills and packed steps
+        #: (``stats()``; ``None`` without experts)
+        self.routed_assignments: Optional[np.ndarray] = None
 
         # worker x coalescer composition: with a coalescer the drains queue
         # and the worker's seat becomes a secure channel the fused flushes
@@ -262,17 +287,23 @@ class ServingEngine:
     # -- worker thread (v10c) --------------------------------------------------------
 
     def _start_worker(self):
+        # the thread holds the queue and the gateway, not the engine, so an
+        # engine dropped without close() is still collected (its params and
+        # cache freed); collecting it stops the thread
+        drain_q, gateway = self._drain_q, self.gateway
+
         def loop():
             while True:
-                item = self._drain_q.get()
+                item = drain_q.get()
                 if item is None:
                     return
                 arr, cb, parent = item
                 with wall.under(parent):
-                    host = self.gateway.d2h(arr, op_class=oc.WORKER_DRAIN)
+                    host = gateway.d2h(arr, op_class=oc.WORKER_DRAIN)
                 cb(host)
         self._worker = threading.Thread(target=loop, daemon=True)
         self._worker.start()
+        weakref.finalize(self, drain_q.put, None)
 
     def close(self):
         if self.coalescer is not None:
@@ -389,7 +420,7 @@ class ServingEngine:
         else:
             self.gateway.h2d(prompt, op_class=oc.PROMPT_H2D)
         self._note_shape(("prefill", prompt.shape[1]))
-        logits, pre_cache = prefill_logits_cache(
+        logits, pre_cache, *routed = prefill_logits_cache(
             self.cfg, self.params, _upload(prompt, PROMPT_INPUT),
             self.max_len)
         idx0 = prompt.shape[1]
@@ -412,10 +443,12 @@ class ServingEngine:
         with wall.span("engine.sample"):
             self.key, sk = jax.random.split(self.key)
             first = sample(logits, sk, req.sampling)
+        first = _with_routed(first, routed)
         if self.coalescer is not None:
             first_host = self.coalescer.d2h(first, op_class=oc.SAMPLE_D2H)
         else:
             first_host = self.gateway.d2h(first, op_class=oc.SAMPLE_D2H)
+        first_host = self._take_routed(np.asarray(first_host), 1)
         tok = int(first_host[0])
         req.output_tokens.append(tok)
         req.first_token_t = self.clock.now
@@ -743,7 +776,7 @@ class ServingEngine:
                     [tokens, np.repeat(tokens[:1], pad, axis=0)])
                 exec_index = np.concatenate([index, np.repeat(index[:1], pad)])
             self._note_shape(("packed", width))
-            logits, self.caches = self._packed_decode(
+            logits, self.caches, *routed = self._packed_decode(
                 self.params, self.caches, _upload(exec_tokens, DECODE_INPUT),
                 _upload(exec_index, DECODE_INPUT),
                 _upload(exec_slots, DECODE_INPUT))
@@ -770,16 +803,19 @@ class ServingEngine:
             next_tokens = sample(logits[:n], sk,
                                  self.active[slots[0]].sampling)
 
-        # --- output drain: exactly the packed rows, nothing else
+        # --- output drain: exactly the packed rows, nothing else (and an
+        # MoE program's counts, on the same copy)
         with wall.span("engine.drain"):
-            host_tokens = self._drain(next_tokens)
+            host_tokens = self._drain(_with_routed(next_tokens, routed))
 
         with wall.span("engine.consume"):
+            drained = np.asarray(host_tokens)
+            host_tokens = self._take_routed(drained, n)
             self.trace.append(StepTrace(
                 step=self.step_count, active=n,
                 prep_crossings=len(small_inputs),
                 prep_bytes=sum(a.nbytes for a in small_inputs),
-                drain_bytes=int(np.asarray(host_tokens).nbytes),
+                drain_bytes=int(drained.nbytes),
                 policy=self.policy.value, virtual_t=self.clock.now,
                 deferred=len(deferred), packed=n))
             self._consume(ready, np.asarray(host_tokens), by_position=True)
@@ -844,6 +880,21 @@ class ServingEngine:
               else oc.DRAIN_D2H)
         return self.gateway.d2h(drain_tokens, op_class=op)
 
+    def _take_routed(self, host: np.ndarray, n: int) -> np.ndarray:
+        """The first ``n`` entries of a drained array, the tokens.  In an MoE
+        model the program's (MoE layers, experts held) assignment counts
+        follow them: they are added to ``routed_assignments`` and put on an
+        ``engine.routed`` span (attr ``counts``)."""
+        if host.shape[0] == n:
+            return host
+        counts = host[n:].reshape(-1, self.cfg.experts_held)
+        if self.routed_assignments is None:
+            self.routed_assignments = np.zeros(counts.shape, np.int64)
+        self.routed_assignments += counts
+        with wall.span("engine.routed", counts=counts):
+            pass
+        return host[:n]
+
     def _consume(self, ready: list, host: np.ndarray, *,
                  by_position: bool) -> None:
         """Append each ready slot's drained token and retire finished
@@ -888,6 +939,8 @@ class ServingEngine:
             "mean_ttft_s": float(np.mean(ttfts)) if ttfts else 0.0,
             "steps": self.step_count,
             "resident_decode_steps": self.resident_decode_steps,
+            "routed_assignments": (None if self.routed_assignments is None
+                                   else self.routed_assignments.tolist()),
             "overlap": self.overlap.stats_dict(),
             "closed_dirty": self.closed_dirty,
         }

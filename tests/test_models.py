@@ -96,7 +96,7 @@ def test_exact_assigned_configs():
         "nemotron-4-340b": (96, 18432, 96, 8, 73728, 256000),
         "seamless-m4t-medium": (12, 1024, 16, 16, 4096, 256206),
         "deepseek-moe-16b": (28, 2048, 16, 16, 1408, 102400),
-        "deepseek-v2-lite-16b": (27, 2048, 16, 16, 1408, 102400),
+        "deepseek-v2-lite-16b": (27, 2048, 16, 16, 10944, 102400),
         "xlstm-1.3b": (48, 2048, 4, 4, 0, 50304),
         "internvl2-76b": (80, 8192, 64, 8, 28672, 128256),
         "hymba-1.5b": (32, 1600, 25, 5, 5504, 32001),

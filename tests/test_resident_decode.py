@@ -121,7 +121,7 @@ def test_packed_step_matches_the_copying_composition(case):
         dup = np.asarray([first[s] for s in rows])
         tokens, index = tokens[dup].astype(np.int32), index.astype(np.int32)
         want, caches = old(params, caches, tokens, index, slots)
-        got, resident = new(params, resident, tokens, index, slots)
+        got, resident, *_ = new(params, resident, tokens, index, slots)
         assert np.isfinite(np.asarray(want, np.float32)).all()
         _assert_same(got, want, f"{case} step {step} logits")
         _assert_same(resident, caches, f"{case} step {step} cache")
