@@ -222,6 +222,15 @@ class TransferGateway:
             # against the batched path measures *batching*, not staging abuse
             return [self.h2d(a, op_class=op_class, reuse_staging=True)
                     for a in host_arrays]
+        self.price_batch_h2d(host_arrays, op_class=op_class)
+        return [device_put(a, self.device, op_class) for a in host_arrays]
+
+    def price_batch_h2d(self, host_arrays: Sequence[np.ndarray], *,
+                        op_class: str = "batch_h2d") -> None:
+        """The pricing half of a batched ``batch_h2d``: one staged crossing
+        of the arrays' total bytes is charged, recorded and counted, and
+        nothing moves.  For inputs the runtime models but no program reads
+        (the engine's per-step prep)."""
         with wall.span("account", what="batch_h2d"):
             total = sum(_nbytes(a) for a in host_arrays)
             if self.arena is not None:
@@ -239,7 +248,6 @@ class TransferGateway:
             end = self.clock.advance(cost)
             self._record(crossing, cost, op_class, t_end=end, tags=tags)
             self.stats.batched_crossings_saved += len(host_arrays) - 1
-        return [device_put(a, self.device, op_class) for a in host_arrays]
 
     def bulk_h2d_pooled(self, host_arrays: Sequence[np.ndarray], *,
                         op_class: str = "bulk_h2d", tags: tuple = (),

@@ -41,7 +41,7 @@ from repro.bridge_opt import CrossingCoalescer, StagingArena
 from repro.core.bridge import BridgeModel
 from repro.core.channels import VirtualClock
 from repro.core.compute import ComputeModel
-from repro.core.gateway import TransferGateway
+from repro.core.gateway import TransferGateway, device_put
 from repro.core.policy import RuntimeDefaults, SchedulingPolicy, cc_aware_defaults
 from repro.obs import Observatory, wall
 from repro.trace import opclasses as oc
@@ -51,9 +51,8 @@ from .overlap import OverlapScheduler
 from .sampler import SamplingParams, sample
 
 
-#: ``op_class`` of the uploads the engine makes itself, outside the gateway
-#: and its tape (an attr of their ``bridge.h2d`` wall-clock spans)
-PROMPT_INPUT = "prompt_input"
+#: ``op_class`` of the step inputs the engine uploads itself, outside the
+#: gateway and its tape (an attr of their ``bridge.h2d`` wall-clock spans)
 DECODE_INPUT = "decode_input"
 
 
@@ -127,14 +126,30 @@ def packed_step(model: Model, params, caches, tokens, index, slots):
                                           slots, routed=True))
 
 
-def packed_step_of(model: Model):
-    """``packed_step`` bound to ``model`` and jitted under its own name, so
-    the program a profiler trace shows is called ``packed_step``.  The
-    resident cache (argument 1) is donated: the step updates it in place."""
-    def bound(params, caches, tokens, index, slots):
-        return packed_step(model, params, caches, tokens, index, slots)
-    bound.__name__ = bound.__qualname__ = "packed_step"
-    return jax.jit(bound, donate_argnums=(1,))
+def packed_step_of(model: Model, device: Optional[jax.Device] = None):
+    """``packed_step`` bound to ``model``, behind one upload of its inputs.
+
+    The returned callable takes the step's host int32 inputs (tokens
+    ``[w, 1]``, positions and slots ``[w]``), stacks them into one
+    ``[3, w]`` buffer, puts that on ``device`` in one transfer, and runs
+    the program (its ``program`` attribute) that unpacks the buffer and
+    steps.  The program is jitted under the name ``packed_step``, so a
+    profiler trace shows it so, and the resident cache (argument 1) is
+    donated: the step updates it in place."""
+    def program(params, caches, inputs):
+        return packed_step(model, params, caches, inputs[0][:, None],
+                           inputs[1], inputs[2])
+    program.__name__ = program.__qualname__ = "packed_step"
+    program = jax.jit(program, donate_argnums=(1,))
+
+    def step(params, caches, tokens, index, slots):
+        inputs = np.stack([np.asarray(tokens, np.int32).reshape(-1),
+                           np.asarray(index, np.int32),
+                           np.asarray(slots, np.int32)])
+        return program(params, caches,
+                       device_put(inputs, device, DECODE_INPUT))
+    step.program = program
+    return step
 
 
 def _upload(host: np.ndarray, op_class: str) -> jax.Array:
@@ -249,7 +264,7 @@ class ServingEngine:
         # `_bucket` pads widths to powers of two so the trace count stays
         # O(log max_batch) even when the ready set raggedly shrinks slot by
         # slot as requests finish.
-        self._packed_decode = packed_step_of(model)
+        self._packed_decode = packed_step_of(model, self.device)
         #: packed steps run on the donated resident cache (``stats()``)
         self.resident_decode_steps = 0
         #: running total of the MoE programs' assignments to each (MoE
@@ -415,14 +430,14 @@ class ServingEngine:
         prompt = np.asarray(req.prompt, np.int32)[None]     # (1, P)
         # prompt upload crosses the bridge (registered: steady-state serving
         # reuses the prompt staging buffer; coalesced when bridge_opt is on)
+        # and the prompt program reads what it put
         if self.coalescer is not None:
-            self.coalescer.h2d(prompt, op_class=oc.PROMPT_H2D)
+            prompt_dev = self.coalescer.h2d(prompt, op_class=oc.PROMPT_H2D)
         else:
-            self.gateway.h2d(prompt, op_class=oc.PROMPT_H2D)
+            prompt_dev = self.gateway.h2d(prompt, op_class=oc.PROMPT_H2D)
         self._note_shape(("prefill", prompt.shape[1]))
         logits, pre_cache, *routed = prefill_logits_cache(
-            self.cfg, self.params, _upload(prompt, PROMPT_INPUT),
-            self.max_len)
+            self.cfg, self.params, prompt_dev, self.max_len)
         idx0 = prompt.shape[1]
         # prompt processing is device compute, charged like any interval;
         # warm (restored / externally-priced) tokens skip the forward
@@ -777,9 +792,8 @@ class ServingEngine:
                 exec_index = np.concatenate([index, np.repeat(index[:1], pad)])
             self._note_shape(("packed", width))
             logits, self.caches, *routed = self._packed_decode(
-                self.params, self.caches, _upload(exec_tokens, DECODE_INPUT),
-                _upload(exec_index, DECODE_INPUT),
-                _upload(exec_slots, DECODE_INPUT))
+                self.params, self.caches, exec_tokens, exec_index,
+                exec_slots)
             self.resident_decode_steps += 1
 
         if self.compute is not None:
@@ -826,9 +840,11 @@ class ServingEngine:
     # -- shared step plumbing (dense + packed) -----------------------------------------
 
     def _emit_prep(self, small_inputs: list) -> None:
-        """Upload one step's small input arrays under the active policy:
-        coalesced (bridge_opt), fresh-staging per array (async — the 44x
-        class), or one batched registered crossing (sync/worker)."""
+        """Cross one step's small modelled input arrays under the active
+        policy: coalesced (bridge_opt), fresh-staging per array (async — the
+        44x class), or one batched registered crossing (sync/worker).  No
+        program reads them, so the batched crossing is priced and nothing
+        moves; the step's executed inputs go up with the step."""
         if self.coalescer is not None:
             prep_class = (oc.ALLOC_H2D
                           if self.policy is SchedulingPolicy.ASYNC_OVERLAP
@@ -839,6 +855,9 @@ class ServingEngine:
             for arr in small_inputs:
                 self.gateway.h2d(arr, op_class=oc.ALLOC_H2D,
                                  reuse_staging=False)
+        elif self.gateway.defaults.batch_small_crossings:
+            self.gateway.price_batch_h2d(small_inputs,
+                                         op_class=oc.PREP_BATCHED_H2D)
         else:
             self.gateway.batch_h2d(small_inputs, op_class=oc.PREP_BATCHED_H2D)
 

@@ -132,10 +132,9 @@ def test_olmo_packed_step_decodes_in_place(one_chip, tpu_branch, olmo):
     K or V cache (the loop writes one row a layer and slot in place)."""
     params = _params_of(olmo, one_chip)
     caches = _olmo_cache(olmo, one_chip)
-    rows = _on(one_chip, (8,), jnp.int32)
-    tokens = _on(one_chip, (8, 1), jnp.int32)
-    compiled = packed_step_of(olmo).lower(params, caches, tokens, rows,
-                                          rows).compile()
+    inputs = _on(one_chip, (3, 8), jnp.int32)
+    compiled = packed_step_of(olmo).program.lower(params, caches,
+                                                  inputs).compile()
     mem = compiled.memory_analysis()
     cache_bytes = sum(leaf.size * leaf.dtype.itemsize
                       for leaf in jax.tree.leaves(caches))
@@ -175,10 +174,9 @@ def test_dsv2lite_packed_step_fits_and_decodes_in_place(one_chip, tpu_branch):
     caches = jax.tree.map(
         lambda s: _on(one_chip, s.shape, s.dtype),
         jax.eval_shape(lambda: model.init_cache(32, 2048)))
-    rows = _on(one_chip, (32,), jnp.int32)
-    tokens = _on(one_chip, (32, 1), jnp.int32)
-    compiled = packed_step_of(model).lower(params, caches, tokens, rows,
-                                           rows).compile()
+    inputs = _on(one_chip, (3, 32), jnp.int32)
+    compiled = packed_step_of(model).program.lower(params, caches,
+                                                   inputs).compile()
     mem = compiled.memory_analysis()
     cache_bytes = sum(leaf.size * leaf.dtype.itemsize
                       for leaf in jax.tree.leaves(caches))

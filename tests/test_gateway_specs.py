@@ -1,21 +1,24 @@
 """TransferGateway discipline + input-spec construction tests."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.bridge_opt import StagingArena
 from repro.core.bridge import B300, TPU_V5E, BridgeModel
 from repro.core.gateway import TransferGateway
 from repro.core.policy import cc_aware_defaults
 from repro.configs.base import SHAPES, get_config
+from repro.obs import wall
 
 
 class TestGateway:
     def _gw(self, cc_on, batching=None):
         defaults = cc_aware_defaults(cc_on)
         if batching is not None:
-            import dataclasses
             defaults = dataclasses.replace(defaults,
                                            batch_small_crossings=batching)
         return TransferGateway(BridgeModel(B300, cc_on=cc_on), defaults,
@@ -38,6 +41,37 @@ class TestGateway:
         # 8 fresh crossings vs 1 registered crossing: >> 8x time difference
         assert unbatched.clock.now > 5 * batched.clock.now
         assert batched.stats.batched_crossings_saved == 7
+
+    @pytest.mark.parametrize("arena", [False, True])
+    def test_pricing_half_prices_as_batch_h2d(self, monkeypatch, arena):
+        """``price_batch_h2d`` leaves the records, clock and stats that
+        ``batch_h2d`` leaves over the same arrays, and moves nothing."""
+        monkeypatch.setenv("REPRO_OBS", "1")
+        wall.refresh()
+        arrays = [np.arange(3, dtype=np.int32).reshape(3, 1),
+                  np.arange(3, dtype=np.int32)] + [
+            np.zeros(3, np.int32) for _ in range(4)]
+        moved, priced = self._gw(True), self._gw(True)
+        if arena:
+            moved.arena = StagingArena(1 << 20)
+            priced.arena = StagingArena(1 << 20)
+        wall.clear()
+        for _ in range(2):      # first touch, then warm
+            moved.batch_h2d(arrays, op_class="prep_batched_h2d")
+        assert [r.name for r in wall.records()].count("bridge.h2d") == 12
+        wall.clear()
+        for _ in range(2):
+            priced.price_batch_h2d(arrays, op_class="prep_batched_h2d")
+        names = [r.name for r in wall.records()]
+        monkeypatch.undo()
+        wall.refresh()
+        wall.clear()
+        assert "bridge.h2d" not in names and "account" in names
+        assert priced.records == moved.records and len(priced.records) == 2
+        assert priced.clock.now == moved.clock.now > 0
+        assert dataclasses.asdict(priced.stats) == dataclasses.asdict(
+            moved.stats)
+        assert priced.stats.batched_crossings_saved == 10
 
     def test_fresh_staging_registers_on_reuse(self):
         gw = self._gw(True)

@@ -25,6 +25,8 @@ The laws pinned here:
 """
 
 import dataclasses
+import hashlib
+import json
 
 import numpy as np
 import pytest
@@ -43,7 +45,7 @@ from repro.serving.offload import HostBlock, OffloadManager
 from repro.serving.sampler import SamplingParams
 from repro.trace import TraceRecorder, check_tape
 from repro.trace import opclasses as oc
-from repro.trace.harness import smoke_model
+from repro.trace.harness import GOLDEN, smoke_model
 
 
 @pytest.fixture(scope="module")
@@ -273,6 +275,57 @@ class TestPackedEngine:
         assert tp[0].prep_bytes < td[0].prep_bytes
         assert tp[0].drain_bytes < td[0].drain_bytes
         assert td[0].drain_bytes == 8 * 4      # dense drains max_batch rows
+
+    #: sha256 of the records (``to_dict``, keys sorted) and the virtual
+    #: clock of ``test_pad_row_run_keeps_tokens_and_tape``'s run, captured
+    #: from the engine as it was when it still moved the prep arrays it
+    #: prices and uploaded the step inputs one by one
+    PAD_RUN_TAPES = {
+        SP.SYNC_DRAIN: ("32be01d3b4e95adc71265eff3a391c45"
+                        "ed0fbadbe75ff0c8b74793d286fa77ec"),
+        SP.WORKER_DRAIN: ("74a75db67215fd25a48c4d1ec02de697"
+                          "ebc8423e7050e4830ff29bb6c17182d9"),
+    }
+    PAD_RUN_CLOCK = 0.002221757953601953
+
+    @pytest.mark.parametrize("policy", [SP.SYNC_DRAIN, SP.WORKER_DRAIN])
+    def test_pad_row_run_keeps_tokens_and_tape(self, tiny_model, policy):
+        """Three requests in a four-slot engine: the three-row step runs at
+        width 4 with a pad row, its inputs go up as one ``[3, 4]`` buffer,
+        the greedy tokens equal the dense path's, and the bridge tape and
+        clock equal those of the engine that moved every prep array."""
+        def run(packed):
+            eng = ServingEngine(
+                tiny_model, max_batch=4, max_len=64, cc_on=True,
+                policy=policy, seed=GOLDEN["seed"],
+                defaults=dataclasses.replace(cc_aware_defaults(True),
+                                             packed_decode=packed))
+            step, widths = eng._packed_decode, []
+
+            def spy(params, caches, tokens, index, slots):
+                widths.append((len(set(np.asarray(slots).tolist())),
+                               len(slots)))
+                return step(params, caches, tokens, index, slots)
+            eng._packed_decode = spy
+            for i in range(3):
+                eng.submit(Request(f"r{i}", prompt=[5, 7 + i, 11, 2 * i + 1],
+                                   sampling=SamplingParams(
+                                       max_new_tokens=4 + 2 * i)))
+            with TraceRecorder(eng.gateway, label="pad") as rec:
+                eng.run()
+            eng.close()
+            toks = {r.request_id: list(r.output_tokens) for r in eng.finished}
+            return toks, rec.tape(), eng.clock.now, widths
+
+        toks, tape, clock, widths = run(True)
+        dense_toks, *_ = run(False)
+        assert (3, 4) in widths
+        assert toks == dense_toks and len(toks) == 3
+        records = json.dumps([r.to_dict() for r in tape.records],
+                             sort_keys=True)
+        assert hashlib.sha256(records.encode()).hexdigest() == \
+            self.PAD_RUN_TAPES[policy]
+        assert clock == self.PAD_RUN_CLOCK
 
     def test_bucket_widths_are_pow2_capped_at_max_batch(self, tiny_model):
         eng = ServingEngine(tiny_model, max_batch=6, max_len=64,

@@ -11,10 +11,11 @@ The laws pinned here:
     is a shared no-op that stamps nothing;
   * a decode-only tick is one ``sched.tick`` holding the admission, the
     dispatch, the sampling, the drain (its wait and copy on the worker
-    thread) and the consumption, and it makes exactly ten real crossings:
-    six prep uploads, three step inputs, one drain; an admission makes
-    three: the prompt through the gateway, the prompt the prefill takes,
-    the first token;
+    thread) and the consumption, and it makes exactly two real crossings:
+    the step's tokens, positions and slots in one ``[3, width]`` upload,
+    and one drain (the modelled prep is priced, and nothing moves); an
+    admission makes two: the prompt through the gateway, which the prefill
+    reads, and the first token;
   * the instrument is passive: token streams and the bridge tape are equal
     with the recorder on and off.
 """
@@ -188,10 +189,12 @@ def test_decode_tick_span_tree_and_crossings(recorder):
         assert worker["bridge.d2h"].thread != main
         assert worker["bridge.d2h"].attrs["op_class"] == "worker_drain"
         below = _below(kids, t)
+        dispatch = next(r for r in kids[t.id] if r.name == "engine.dispatch")
         ups = [r for r in below if r.name == "bridge.h2d"]
-        assert sorted(r.attrs["op_class"] for r in ups) == (
-            ["decode_input"] * 3 + ["prep_batched_h2d"] * 6)
-        assert sum(r.name in CROSSINGS for r in below) == 10
+        assert [(r.attrs["op_class"], r.attrs["nbytes"], r.parent)
+                for r in ups] == [
+            ("decode_input", 12 * dispatch.attrs["width"], dispatch.id)]
+        assert sum(r.name in CROSSINGS for r in below) == 2
         assert any(r.name == "account" for r in below)
         assert all(t.start_ns <= r.start_ns <= r.end_ns <= t.end_ns
                    for r in below)
@@ -207,7 +210,7 @@ def test_admission_crossings(recorder):
         below = _below(kids, p)
         assert sorted(r.attrs["op_class"] for r in below
                       if r.name in CROSSINGS) == [
-            "prompt_h2d", "prompt_input", "sample_d2h"]
+            "prompt_h2d", "sample_d2h"]
         assert {"engine.insert", "engine.sample"} <= {r.name for r in below}
         assert p.attrs["prompt_len"] == len(next(
             r for r in engine.finished if r.request_id == p.attrs["req"]
