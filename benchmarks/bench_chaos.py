@@ -70,8 +70,8 @@ def _model():
 
 def _cfg() -> ReplicaConfig:
     # coalescing ON so the fused-ciphertext fault semantics (and the
-    # bypass rung) are live; uniform output lengths keep the dense-step
-    # rung shape-neutral (ready set == batch, packed == dense bytes)
+    # bypass rung) are live; uniform output lengths keep the last rung's
+    # full-width step shape-neutral (ready set == max_batch)
     return ReplicaConfig(max_batch=2, max_len=64,
                          coalesce_small_crossings=True)
 

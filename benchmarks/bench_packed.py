@@ -1,41 +1,26 @@
-"""Packed ragged decode benchmark: roofline table + packed-vs-dense gate.
+"""Packed ragged decode benchmark: the peak-throughput roofline table.
 
-Two payloads, one module (DESIGN.md §10):
-
-1. **The peak-throughput roofline table.**  The unified step-pricing
-   roofline (``ComputeModel`` — the same object the engine charges and the
-   simulator's ``forward_ms`` derives from) priced across model configs x
-   batch x input/output lengths, CC-on B300.  Decode is weight-read
-   memory-bound at serving batch sizes, so tok/s climbs near-linearly with
-   batch until the KV read stream takes over at long contexts — the table
-   makes that crossover visible per config.  Pure virtual-clock arithmetic:
-   bit-deterministic, checked into ``BENCH_packed.json``
-   (CI drift gate: ``python -m benchmarks.bench_packed --check``).
-
-2. **Does packing ever lose?**  The guardrail sweep runs the real engine on
-   ragged workloads (heterogeneous ``max_new_tokens``, so the ready set
-   shrinks slot by slot) twice per point — ``packed_decode`` on vs off —
-   and demands identical token streams with packed virtual tok/s >= the
-   dense path at every swept batch.  Packed prep/drain crossings cover
-   exactly the ready rows while dense ships ``max_batch``-shaped bytes, so
-   packing can only win; the sweep pins that it actually does.
+The unified step-pricing roofline (``ComputeModel`` — the same object the
+engine charges and the simulator's ``forward_ms`` derives from; DESIGN.md
+§10) priced across model configs x batch x input/output lengths, CC-on
+B300.  Decode is weight-read memory-bound at serving batch sizes, so tok/s
+climbs near-linearly with batch until the KV read stream takes over at long
+contexts — the table makes that crossover visible per config.  Pure
+virtual-clock arithmetic: bit-deterministic, checked into
+``BENCH_packed.json`` (CI drift gate: ``python -m benchmarks.bench_packed
+--check``).
 """
 
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import json
 import os
 import sys
 
-from repro.configs.base import get_config, smoke_config
+from repro.configs.base import get_config
 from repro.core.bridge import B300, BridgeModel
 from repro.core.compute import ComputeModel
-from repro.core.policy import cc_aware_defaults
-from repro.models.model import Model
-from repro.serving.engine import Request, ServingEngine
-from repro.serving.sampler import SamplingParams
 
 #: the roofline table axes: >=3 configs x batch 8..512 x (input, output)
 CONFIGS = ("qwen1p5-4b", "deepseek-moe-16b", "qwen3p6-27b")
@@ -43,9 +28,6 @@ BATCHES = (8, 32, 128, 512)
 #: (input_len, output_len) pairs; the priced KV depth is the mean decode
 #: context, input + output/2
 LENGTH_PAIRS = ((128, 128), (1024, 512), (4096, 1024))
-
-#: engine guardrail sweep: max_batch values for the packed-vs-dense runs
-ENGINE_BATCHES = (4, 8, 16)
 
 #: relative tolerance for the BENCH_packed.json drift check (virtual-clock
 #: quantities are deterministic; this absorbs only float round-tripping)
@@ -73,7 +55,7 @@ def roofline_table() -> list[dict]:
         for batch in BATCHES:
             for in_len, out_len in LENGTH_PAIRS:
                 kv = float(in_len + out_len / 2)
-                charge = cm.decode_charge(batch, kv_len=kv)
+                charge = cm.decode_charge([kv] * batch)
                 step_s = charge.seconds
                 rows.append({
                     "config": name,
@@ -88,60 +70,10 @@ def roofline_table() -> list[dict]:
     return rows
 
 
-def _ragged_run(max_batch: int, *, packed: bool) -> dict:
-    """One engine run on a ragged workload (heterogeneous output lengths,
-    1.5x oversubscribed) with packed decode on or off."""
-    defaults = dataclasses.replace(
-        cc_aware_defaults(True, concurrency=max_batch),
-        packed_decode=packed)
-    engine = ServingEngine(
-        Model(smoke_config(_config("qwen1p5-4b"))),
-        max_batch=max_batch, max_len=64,
-        bridge=BridgeModel(B300, cc_on=True), defaults=defaults, seed=0)
-    try:
-        n_requests = max_batch + max_batch // 2
-        for i in range(n_requests):
-            # ragged finishes: output lengths cycle 3..12 so the ready set
-            # shrinks slot by slot and packed widths sweep the buckets
-            engine.submit(Request(
-                f"r{i}", prompt=[1, 2, 3 + (i % 5)],
-                sampling=SamplingParams(max_new_tokens=3 + (i * 3) % 10)))
-        stats = engine.run()
-        return {
-            "tokens": tuple(sorted(
-                (r.request_id, tuple(r.output_tokens))
-                for r in engine.finished)),
-            "tok_s": stats["total_tokens"] / stats["virtual_time_s"],
-            "steps": stats["steps"],
-            "finished": stats["finished"],
-        }
-    finally:
-        engine.close()
-
-
-def engine_guardrail() -> list[dict]:
-    """Packed-vs-dense sweep: identical token streams, packed tok/s >=
-    dense tok/s at every swept batch (the structural claim, measured)."""
-    rows = []
-    for max_batch in ENGINE_BATCHES:
-        p = _ragged_run(max_batch, packed=True)
-        d = _ragged_run(max_batch, packed=False)
-        rows.append({
-            "max_batch": max_batch,
-            "finished": p["finished"],
-            "packed_tok_s": p["tok_s"],
-            "dense_tok_s": d["tok_s"],
-            "ratio": p["tok_s"] / d["tok_s"],
-            "packed_steps": p["steps"],
-            "dense_steps": d["steps"],
-            "tokens_identical": p["tokens"] == d["tokens"],
-        })
-    return rows
-
-
 def payload() -> dict:
-    """The deterministic drift payload: both tables, virtual-clock only."""
-    return {"roofline": roofline_table(), "engine": engine_guardrail()}
+    """The deterministic drift payload: the roofline table, virtual-clock
+    only."""
+    return {"roofline": roofline_table()}
 
 
 def run() -> list[str]:
@@ -154,28 +86,6 @@ def run() -> list[str]:
             f"_i{r['input_len']}_o{r['output_len']},{r['tok_s']:.1f},"
             f"tok/s at kv={r['kv_len']:g} ({r['bound']}-bound, "
             f"step {r['step_ms']:.3f} ms; unified ComputeModel roofline)")
-    for e in data["engine"]:
-        lines.append(
-            f"packed/engine_b{e['max_batch']}_ratio,{e['ratio']:.6f},"
-            f"packed {e['packed_tok_s']:.1f} vs dense "
-            f"{e['dense_tok_s']:.1f} tok/s on a ragged workload "
-            f"({e['finished']} reqs)")
-        if not e["tokens_identical"]:
-            raise AssertionError(
-                f"packed token stream diverged from dense at "
-                f"max_batch={e['max_batch']}")
-        if e["ratio"] < 1.0 - REL_TOL:
-            raise AssertionError(
-                f"packed decode lost to dense at max_batch="
-                f"{e['max_batch']}: ratio {e['ratio']:.6f}")
-    identical = all(e["tokens_identical"] for e in data["engine"])
-    never_loses = all(e["ratio"] >= 1.0 - REL_TOL for e in data["engine"])
-    lines.append(
-        f"packed/tokens_identical,{float(identical):.1f},"
-        f"packed == dense token streams at every swept batch (greedy)")
-    lines.append(
-        f"packed/never_loses,{float(never_loses):.1f},"
-        f"packed tok/s >= dense at every swept batch (guardrail)")
     return lines
 
 
@@ -210,8 +120,6 @@ def check_drift(path: str) -> list[str]:
     problems: list[str] = []
     _diff_rows("roofline", golden.get("roofline", []), fresh["roofline"],
                ("config", "batch", "input_len", "output_len"), problems)
-    _diff_rows("engine", golden.get("engine", []), fresh["engine"],
-               ("max_batch",), problems)
     return problems
 
 

@@ -110,8 +110,7 @@ def run_restore(model, kv_quant: str) -> dict:
     bridge = BridgeModel(B300, cc_on=True)
     defaults = dataclasses.replace(
         cc_aware_defaults(True), scheduling=SP.SYNC_DRAIN,
-        loader_pool_workers=8, pipelined_restore=True,
-        slot_masked_decode=True, kv_quant=kv_quant)
+        loader_pool_workers=8, pipelined_restore=True, kv_quant=kv_quant)
     compute = ComputeModel(get_config(PAPER_MODEL), bridge)
     engine = ServingEngine(model, max_batch=4, max_len=64,
                            policy=SP.SYNC_DRAIN, bridge=bridge,

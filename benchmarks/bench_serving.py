@@ -190,7 +190,7 @@ def compute_window_rows() -> list[tuple[str, float, str]]:
     cm = ComputeModel(get_config("qwen3p6-27b"), on)
     out = []
     for c, w in W.sweep_workloads().items():
-        window = cm.decode_step_s(c)
+        window = cm.decode_step_s([0.0] * c)
         fresh = on.crossing_time(
             Crossing(w.small_bytes, Direction.H2D, StagingKind.FRESH))
         reg = on.crossing_time(

@@ -67,7 +67,7 @@ def ladder_table() -> list[dict]:
     rows = []
     for tp in TP_DEGREES:
         cm = ComputeModel(cfg, bridge, tp_degree=tp)
-        charge = cm.decode_charge(LADDER_BATCH, kv_len=LADDER_KV_LEN)
+        charge = cm.decode_charge([LADDER_KV_LEN] * LADDER_BATCH)
         ar_bytes = cm.allreduce_bytes(LADDER_BATCH)
         ar_s = cm.allreduce_seconds(LADDER_BATCH, B300.fabric_p2p_bw)
         step_s = charge.seconds + ar_s

@@ -33,7 +33,7 @@ def main() -> None:
         ("bridge_opt (SS5.2 x SS8 arena+coalescer+pipelined-restore)",
          bench_bridge_opt),
         ("obs (SS9 spans+stall-attribution+overhead)", bench_obs),
-        ("packed (SS10 ragged decode roofline + packed-vs-dense gate)",
+        ("packed (SS10 ragged decode roofline)",
          bench_packed),
         ("chaos (SS11 fault injection + recovery ladder)", bench_chaos),
         ("tp (SS12 fabric-P2P tensor parallelism + fallback repricing)",

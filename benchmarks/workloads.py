@@ -29,7 +29,7 @@ def qwen27b_c128() -> ServingWorkload:
     """§5.4 table: Qwen3.6-27B-FP8, c=128, B300.
 
     Roofline-anchored (DESIGN.md §10): the forward term is
-    ``eff x ComputeModel.decode_step_s(128, kv_len=PAPER_KV_LEN)`` — the
+    ``eff x ComputeModel.decode_step_s([PAPER_KV_LEN] * 128)`` — the
     same pricing source the engine's compute-charged clock uses.
     """
     obs = [

@@ -88,8 +88,7 @@ def one_chip(cfg, clock: CompileClock) -> None:
     stats, finished = phase("serve, first run", clock, run_serve)
     gc.collect()
     print(f"engine policy={stats['policy']} steps={stats['steps']} "
-          f"finished={stats['finished']} preemptions={stats['preemptions']} "
-          f"closed_dirty={stats['closed_dirty']}")
+          f"finished={stats['finished']} preemptions={stats['preemptions']}")
     print(f"virtual clock (modelled, not measured): "
           f"virtual_time_s={stats['virtual_time_s']:.6f} "
           f"bridge_time_s={stats['bridge_time_s']:.6f} "
@@ -98,7 +97,6 @@ def one_chip(cfg, clock: CompileClock) -> None:
     check(stats["finished"] == len(prompts) == len(finished),
           f"{stats['finished']} of {len(prompts)} requests finished")
     check(stats["preemptions"] == 0, f"{stats['preemptions']} preemptions")
-    check(not stats["closed_dirty"], "engine closed dirty")
     check(all(len(r.output_tokens) == MAX_NEW_TOKENS for r in finished),
           "a request stopped short of its token budget")
 

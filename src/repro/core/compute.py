@@ -152,45 +152,19 @@ class ComputeModel:
 
     # -- decode -------------------------------------------------------------------------
 
-    def decode_charge(self, batch: int, *, kv_len: float = 0.0) -> ComputeCharge:
-        """One batched decode step: every active param touched once (weight
-        reads dominate), plus the KV read for each sequence's cached prefix.
+    def decode_charge(self, kv_lens: Sequence[float]) -> ComputeCharge:
+        """One decode step over the slots with per-slot KV lengths
+        ``kv_lens``: every active param touched once (weight reads dominate,
+        and they stream once per step however many slots consume them),
+        plus the KV read of each slot's cached prefix (the sum, not the
+        batch times the longest).  A slot that sits the step out is not in
+        ``kv_lens`` and costs neither FLOPs nor KV traffic, so the clock
+        never bills work that did not run.
 
-        An empty batch charges zero seconds: the engine's nothing-ready path
-        takes the pipeline barrier, never a forward — the old
-        ``max(1, batch)`` clamp billed one slot's FLOPs *and* the full
-        weight stream for a step that never ran.
-        """
-        batch = max(0, int(batch))
-        if batch == 0:
-            return ComputeCharge("decode", 0.0, 0.0, 0.0, "compute")
-        flops = 2.0 * self.active_params * batch
-        hbm = (self.active_params * self.bytes_per_param
-               + batch * max(0.0, kv_len) * self.kv_bytes_per_token())
-        return self._charge("decode", flops, hbm)
-
-    def decode_step_s(self, batch: int, *, kv_len: float = 0.0) -> float:
-        return self.decode_charge(batch, kv_len=kv_len).seconds
-
-    # -- masked decode (slot-masked execution; DESIGN.md §8) ----------------------------
-
-    def decode_charge_masked(self, kv_lens: "Sequence[float]") -> ComputeCharge:
-        """One slot-masked decode step priced for exactly the ready slots.
-
-        The engine steps only slots whose KV restores have landed; deferred
-        slots stay resident but contribute neither FLOPs nor KV reads this
-        step.  The weight-read term is batch-independent (every active param
-        streams once per step regardless of how many slots consume it), so a
-        masked step is cheaper than the full batch only by the deferred
-        slots' FLOPs and KV traffic — which is exactly the charge the
-        coalescer deadlines and restore-overlap windows must see, or the
-        clock would bill deferred work that never ran.  Per-slot ``kv_lens``
-        (not a batch mean) because the ready set's prefix lengths are known.
-
-        An empty ready set charges zero (see ``decode_charge``): zero ready
-        slots means no forward ran, so billing one phantom slot — as the
-        old ``max(1, len(kv_lens))`` did — charged a full weight stream for
-        nothing.
+        An empty set charges zero seconds: the engine's nothing-ready path
+        takes the pipeline barrier, never a forward — a ``max(1, ...)``
+        clamp would bill one slot's FLOPs *and* the full weight stream for
+        a step that never ran.
         """
         ready = len(kv_lens)
         if ready == 0:
@@ -200,29 +174,8 @@ class ComputeModel:
                + sum(max(0.0, k) for k in kv_lens) * self.kv_bytes_per_token())
         return self._charge("decode", flops, hbm)
 
-    def decode_step_masked_s(self, kv_lens: "Sequence[float]") -> float:
-        return self.decode_charge_masked(kv_lens).seconds
-
-    # -- packed ragged decode (DESIGN.md §10) -------------------------------------------
-
-    def decode_charge_packed(self, kv_lens: "Sequence[float]") -> ComputeCharge:
-        """One packed ragged decode step priced for the packed set.
-
-        Packing is an *execution* change — the forward runs over exactly the
-        packed rows instead of a dense batch padded to the widest slot set —
-        not a pricing change: the packed set's charge is identical to a
-        slot-masked step over the same per-slot KV lengths (weights stream
-        once regardless of how the rows are laid out; KV traffic sums the
-        packed prefixes).  Kept as its own entry point so the engine's
-        DECODE_PACKED records and the parity property
-        (``packed == masked == dense`` for equal lengths) both have a named
-        subject, and so a future paged-attention packed kernel can diverge
-        here without touching the masked path.
-        """
-        return self.decode_charge_masked(kv_lens)
-
-    def decode_step_packed_s(self, kv_lens: "Sequence[float]") -> float:
-        return self.decode_charge_packed(kv_lens).seconds
+    def decode_step_s(self, kv_lens: Sequence[float]) -> float:
+        return self.decode_charge(kv_lens).seconds
 
     # -- prefill ------------------------------------------------------------------------
 

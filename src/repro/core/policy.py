@@ -93,24 +93,6 @@ class RuntimeDefaults:
     #: other decode work fills the window).  The restore_barrier correctness
     #: edge is ALWAYS enforced; this flag only controls the preference.
     overlap_scheduler: bool = field(default_factory=overlap_scheduler_default)
-    # ---- slot-masked decode (DESIGN.md §8) ------------------------------------
-    #: step only the slots whose KV restores have landed (slot-granular read
-    #: sets) instead of barriering the whole decode batch on any one slot's
-    #: pending restore.  Inert without late restores in flight — with no
-    #: pending restore the masked path is byte-identical to the fused batch
-    #: step, which is what keeps the golden tapes stable with the flag on.
-    slot_masked_decode: bool = True
-    # ---- packed ragged decode (DESIGN.md §10) ---------------------------------
-    #: execute decode over a packed (non-padded) batch of exactly the ready
-    #: slots instead of a dense batch padded to max_batch: prep crossings,
-    #: the drain and the compute charge all cover the packed set, so a
-    #: half-empty engine stops paying full-batch bridge bytes and phantom
-    #: lanes.  Token streams are byte-identical to the dense/slot-masked
-    #: paths under greedy decode (rows are batch-independent); with the flag
-    #: off the engine takes the legacy dense step.  Packing is what lets
-    #: max_batch climb into the hundreds–thousands without every step
-    #: paying the widest slot set.
-    packed_decode: bool = True
     # ---- observability (DESIGN.md §9) ------------------------------------------
     #: create a repro.obs.Observatory for engines/replicas that are not
     #: handed one explicitly (metrics registry + request spans wired into

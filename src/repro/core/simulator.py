@@ -234,7 +234,7 @@ def roofline_forward_ms(cfg, profile: BridgeProfile, batch: int, *,
     """
     from .compute import ComputeModel
     cm = ComputeModel(cfg, BridgeModel(profile, cc_on=False), spec=spec)
-    return cm.decode_step_s(batch, kv_len=kv_len) / MS
+    return cm.decode_step_s([kv_len] * batch) / MS
 
 
 def roofline_workload(name: str, cfg, profile: BridgeProfile,

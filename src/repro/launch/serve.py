@@ -63,9 +63,9 @@ def serve(cfg: ModelConfig, prompts: Sequence[Sequence[int]], *,
     """Serve ``prompts`` through ``Model`` -> ``ServingEngine`` ->
     ``Scheduler`` and close the engine.
 
-    Returns the scheduler's stats (``closed_dirty`` read after the close)
-    and the finished requests in submission order.  The engine's params
-    are ``Model(cfg).init(jax.random.PRNGKey(seed))``."""
+    Returns the scheduler's stats and the finished requests in submission
+    order.  The engine's params are
+    ``Model(cfg).init(jax.random.PRNGKey(seed))``."""
     engine = ServingEngine(Model(cfg), max_batch=max_batch, max_len=max_len,
                            policy=policy, cc_on=cc_on, seed=seed)
     sched = Scheduler(engine)
@@ -78,7 +78,6 @@ def serve(cfg: ModelConfig, prompts: Sequence[Sequence[int]], *,
         stats = sched.run()
     finally:
         engine.close()
-    stats["closed_dirty"] = engine.closed_dirty
     stats["policy"] = engine.policy.value
     order = {f"req-{i}": i for i in range(len(prompts))}
     return stats, sorted(engine.finished, key=lambda r: order[r.request_id])

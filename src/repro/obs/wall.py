@@ -11,9 +11,7 @@ the real one.  ``span(name, **attrs)`` is a context manager that
     planes, beside the ops it dispatched.
 
 ``perf_counter`` is the clock of the benchmark's own stamps, so spans and
-client stamps compare directly.  Parents are tracked per thread; a thread
-that works for a span opened elsewhere (the engine's drain worker) enters
-``under(parent)`` so its spans hang below that span.
+client stamps compare directly.  Parents are tracked per thread.
 
 The recorder hands out raw records only (``records()``); turning them into
 numbers is the reader's business.  The ring keeps the newest ``CAPACITY``
@@ -109,20 +107,6 @@ class _Span:
         return False
 
 
-class _Under:
-    __slots__ = ("parent",)
-
-    def __init__(self, parent: int):
-        self.parent = parent
-
-    def __enter__(self) -> None:
-        _stack().append(self.parent)
-
-    def __exit__(self, *exc) -> bool:
-        _stack().pop()
-        return False
-
-
 class _Noop:
     __slots__ = ()
 
@@ -145,20 +129,6 @@ def span(name: str, *, start_ns: Optional[int] = None, **attrs):
     if not _on:
         return _NOOP
     return _Span(name, attrs, start_ns)
-
-
-def current() -> int:
-    """Id of the innermost span open on this thread (0: none, or off)."""
-    stack = _stack() if _on else None
-    return stack[-1] if stack else 0
-
-
-def under(parent: int):
-    """Hang the spans this thread opens inside the block below span
-    ``parent`` (opened on another thread)."""
-    if not _on or not parent:
-        return _NOOP
-    return _Under(parent)
 
 
 def records() -> list:

@@ -3,7 +3,8 @@ bridge faults, then recover with hysteresis once the channel quiets down.
 
 Rungs (cumulative — rung N implies all rungs below it):
 
-  0  full-opt        pipelined restore, coalescer, packed decode
+  0  full-opt        pipelined restore, coalescer, packed decode at
+                     power-of-two widths
   1  sync_restore    pipelined -> drained sync restore.  The pipelined path
                      MACs the whole transfer as one stream, so an integrity
                      failure re-pays the entire prefix; the sync path
@@ -12,8 +13,10 @@ Rungs (cumulative — rung N implies all rungs below it):
                      entry).  A fused flush is one ciphertext — any
                      constituent MAC reject re-pays the whole flush; bypassed
                      crossings retry only themselves.
-  3  dense_step      packed -> dense decode, the maximally predictable step
-                     shape (last resort; byte-identical tokens either way).
+  3  dense_step      the packed step at its fixed full width (max_batch),
+                     the maximally predictable step shape (last resort;
+                     pad rows duplicate a real slot, so the tokens and the
+                     charges, which price the real rows, are unchanged).
 
 Escalation is driven by the retry budget (``RetryBudget.consume`` returning
 True); recovery steps one rung down after ``recovery_quiet_s`` of virtual

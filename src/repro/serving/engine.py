@@ -16,20 +16,16 @@ Policy structure per decode step (paper §5):
                 staging for the scatter/sampling-index uploads.  Under CC
                 the gateway blocks anyway (L2) and fresh staging pays the
                 bounce-buffer toll (L3): the measured 44x op class.
-  WORKER_DRAIN  v10c: the blocking drain runs on a real worker thread (a
-                blocked crossing releases the GIL); the engine thread keeps
-                preparing step N+1.  Input crossings return to warm staging.
+  WORKER_DRAIN  v10c: the drain is priced as a worker-channel crossing
+                (``worker_drain``) and runs inline on the engine's thread.
+                Input crossings return to warm staging.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
-import queue
-import threading
 import time
-import warnings
-import weakref
 from dataclasses import dataclass, field
 from typing import Any, Optional
 
@@ -88,12 +84,10 @@ class StepTrace:
     policy: str
     virtual_t: float
     #: slots that sat this step out because their KV restore pipeline was
-    #: still draining (slot-masked decode; 0 on every unmasked step, so a
-    #: non-restore workload's trace is identical with the flag on or off)
+    #: still draining (slot-masked decode; 0 while no restore is in flight)
     deferred: int = 0
-    #: rows the packed forward executed (packed ragged decode, DESIGN.md
-    #: §10); 0 on legacy dense steps — so `packed == active` on every packed
-    #: step and the trace distinguishes the two execution shapes
+    #: rows the packed forward decoded (DESIGN.md §10): equal to ``active``
+    #: on every step
     packed: int = 0
 
 
@@ -150,12 +144,6 @@ def packed_step_of(model: Model, device: Optional[jax.Device] = None):
                        device_put(inputs, device, DECODE_INPUT))
     step.program = program
     return step
-
-
-def _upload(host: np.ndarray, op_class: str) -> jax.Array:
-    """An engine input the jitted programs take straight from the host."""
-    with wall.span("bridge.h2d", op_class=op_class, nbytes=int(host.nbytes)):
-        return jnp.asarray(host)
 
 
 def _with_routed(tokens: jax.Array, routed: list) -> jax.Array:
@@ -251,15 +239,6 @@ class ServingEngine:
         self.step_compiled = False
         #: requests the last step admitted
         self.step_admitted = 0
-        self._worker: Optional[threading.Thread] = None
-        self._drain_q: "queue.Queue" = queue.Queue()
-        #: worker-drain join deadline at close(); a thread that outlives it
-        #: is surfaced loudly (closed_dirty + RuntimeWarning) instead of
-        #: silently leaked — the PR 7 hang class must never pass quiet again
-        self.drain_join_timeout_s: float = 5.0
-        self.closed_dirty = False
-        self._decode = jax.jit(
-            lambda p, c, t, i: self.model.decode_step(p, c, t, i))
         # packed ragged decode: jit caches one trace per packed width;
         # `_bucket` pads widths to powers of two so the trace count stays
         # O(log max_batch) even when the ready set raggedly shrinks slot by
@@ -272,16 +251,13 @@ class ServingEngine:
         #: (``stats()``; ``None`` without experts)
         self.routed_assignments: Optional[np.ndarray] = None
 
-        # worker x coalescer composition: with a coalescer the drains queue
-        # and the worker's seat becomes a secure channel the fused flushes
-        # serialize on — prewarm the pool so the first flush never pays
-        # context creation on the serving path (§6.1 discipline).  Without
-        # a coalescer the worker is a real thread doing blocking drains.
-        if self.policy is SchedulingPolicy.WORKER_DRAIN:
-            if self.coalescer is None:
-                self._start_worker()
-            else:
-                self.gateway.pool.prewarm()
+        # worker x coalescer composition: the drains queue and the worker's
+        # seat becomes a secure channel the fused flushes serialize on —
+        # prewarm the pool so the first flush never pays context creation
+        # on the serving path (§6.1 discipline)
+        if (self.policy is SchedulingPolicy.WORKER_DRAIN
+                and self.coalescer is not None):
+            self.gateway.pool.prewarm()
 
     def _note_shape(self, key: tuple) -> None:
         if key not in self._seen_shapes:
@@ -299,44 +275,9 @@ class ServingEngine:
             b <<= 1
         return min(b, self.max_batch)
 
-    # -- worker thread (v10c) --------------------------------------------------------
-
-    def _start_worker(self):
-        # the thread holds the queue and the gateway, not the engine, so an
-        # engine dropped without close() is still collected (its params and
-        # cache freed); collecting it stops the thread
-        drain_q, gateway = self._drain_q, self.gateway
-
-        def loop():
-            while True:
-                item = drain_q.get()
-                if item is None:
-                    return
-                arr, cb, parent = item
-                with wall.under(parent):
-                    host = gateway.d2h(arr, op_class=oc.WORKER_DRAIN)
-                cb(host)
-        self._worker = threading.Thread(target=loop, daemon=True)
-        self._worker.start()
-        weakref.finalize(self, drain_q.put, None)
-
     def close(self):
         if self.coalescer is not None:
             self.coalescer.barrier()
-        if self._worker is not None:
-            self._drain_q.put(None)
-            self._worker.join(timeout=self.drain_join_timeout_s)
-            if self._worker.is_alive():
-                # a wedged drain thread means a crossing (and possibly a
-                # caller blocked on its callback) is stranded — make the
-                # dirty shutdown impossible to miss
-                self.closed_dirty = True
-                warnings.warn(
-                    f"ServingEngine.close(): worker drain thread failed to "
-                    f"join within {self.drain_join_timeout_s}s — a drain is "
-                    f"wedged and its crossing is stranded (closed_dirty=True)",
-                    RuntimeWarning, stacklevel=2)
-            self._worker = None
 
     # -- request lifecycle -------------------------------------------------------------
 
@@ -379,8 +320,7 @@ class ServingEngine:
         # pays a barrier, not a forward, and the cost is honestly zero.
         if self.compute is not None:
             with wall.span("account", what="admission_price"):
-                step_cost = self.compute.decode_step_masked_s(
-                    self._ready_lens())
+                step_cost = self.compute.decode_step_s(self._ready_lens())
         else:
             step_cost = 0.0
         i = 0
@@ -404,13 +344,13 @@ class ServingEngine:
     def _ready_lens(self) -> list:
         """Per-slot KV lengths of the resident slots that would step now.
 
-        The masked-aware admission price: with slot masking active and
-        restores in flight, only the ready slots' lengths count; otherwise
-        every resident slot steps.  Empty when nothing would step — the
-        phantom-charge fix makes the resulting price exactly zero."""
+        The masked-aware admission price: with restores in flight, only the
+        ready slots' lengths count; otherwise every resident slot steps.
+        Empty when nothing would step — the phantom-charge fix makes the
+        resulting price exactly zero."""
         if not self.active:
             return []
-        if self.defaults.slot_masked_decode and self.overlap.pending:
+        if self.overlap.pending:
             key_of = {s: r.request_id for s, r in self.active.items()}
             mask = self.overlap.ready_mask(key_of)
             return [float(r.index) for s, r in self.active.items() if mask[s]]
@@ -580,11 +520,10 @@ class ServingEngine:
         batch.  The barrier law survives intact in two places: a ready slot
         with a *landed* restore resolves it here (a barrier no-op — the
         overlap win), and when no slot is ready the nearest pipeline is paid
-        as a real barrier so the batch always makes progress.  With the flag
-        off, or no restores in flight, every slot is ready and the step is
-        byte-identical to the fused batch step.
+        as a real barrier so the batch always makes progress.  With no
+        restores in flight every slot is ready.
         """
-        if not self.defaults.slot_masked_decode or not self.overlap.pending:
+        if not self.overlap.pending:
             return list(slots), []
         key_of = {s: self.active[s].request_id for s in slots}
         mask = self.overlap.ready_mask(key_of)
@@ -618,17 +557,12 @@ class ServingEngine:
     def step(self) -> int:
         """One engine iteration; returns number of active sequences stepped.
 
-        With ``slot_masked_decode`` on, slots whose restore pipelines are
-        still draining are masked out of the step (``_ready_slots``): prep
-        bytes, the compute charge and the drain cover only the ready subset,
-        while deferred slots stay resident and rejoin next step.
-
-        With ``packed_decode`` on (the default), the step executes over a
-        packed ragged batch of exactly the ready slots (``_step_packed``):
-        prep crossings, the forward, the compute charge and the drain are
-        all sized to the packed set instead of a dense batch padded to
-        ``max_batch``.  Token streams are byte-identical to the dense path
-        under greedy decode.
+        Slots whose restore pipelines are still draining are masked out of
+        the step (``_ready_slots``), and the step executes over a packed
+        ragged batch of exactly the ready slots (``_step_packed``): prep
+        crossings, the forward, the compute charge and the drain are all
+        sized to the packed set, while deferred slots stay resident and
+        rejoin next step.
         """
         self.step_compiled = False
         self.step_admitted = 0
@@ -641,126 +575,21 @@ class ServingEngine:
         slots = sorted(self.active)
         with wall.span("account", what="ready_slots"):
             ready, deferred = self._ready_slots(slots)
-        ladder = self._ladder()
-        use_packed = self.defaults.packed_decode and not (
-            ladder is not None and ladder.dense_step_forced)
-        if use_packed:
-            return self._step_packed(slots, ready, deferred)
-        return self._step_dense(slots, ready, deferred)
-
-    def _step_dense(self, slots: list, ready: list, deferred: list) -> int:
-        """Legacy dense decode step: the forward always runs at the fixed
-        ``max_batch`` width, with non-resident rows as zero padding and
-        deferred rows as idempotent rewrites.  Kept as the
-        ``packed_decode=False`` path and as the reference the packed path's
-        token-identity guarantee is stated against."""
-        b = self.max_batch
-
-        # every resident slot feeds the forward (the jitted step is a fixed
-        # full-batch shape); a deferred slot contributes its *current*
-        # (token, index), so its cache write this step is an idempotent
-        # rewrite of the one its rejoin step will perform — masking is an
-        # accounting and consumption boundary, not a shape change
-        tokens = np.zeros((b, 1), np.int32)
-        index = np.zeros((b,), np.int32)
-        for s in slots:
-            req = self.active[s]
-            tokens[s, 0] = req.output_tokens[-1]
-            index[s] = req.index
-
-        # --- input prep crossings (scatter/sampling-index analogue) ---
-        # mask-aware: per-slot prep covers only the slots actually stepping
-        small_inputs = [tokens, index] + [
-            np.zeros((len(ready),), np.int32) for _ in range(4)]
-        self._emit_prep(small_inputs)
-
-        self._whole_batch_barrier(slots)
-
-        self._note_shape(("dense",))
-        with wall.span("engine.dispatch", width=b):
-            logits, self.caches = self._decode(
-                self.params, self.caches, _upload(tokens, DECODE_INPUT),
-                _upload(index, DECODE_INPUT))
-        # the forward+sample is a first-class clock charge: this is what
-        # ages coalescer queues toward their deadline and opens the window
-        # pipelined restores drain into.  A masked step charges the *masked*
-        # batch — the clock, the coalescer deadlines and the overlap windows
-        # all see the true smaller charge, never the full-batch price.
-        if self.compute is not None:
-            with wall.span("account", what="decode_charge"):
-                if deferred:
-                    charge = self.compute.decode_charge_masked(
-                        [float(index[s]) for s in ready])
-                    # one MASKED per masked step, one DEFERRED per slot it
-                    # deferred: tape tag counts read as (masked steps,
-                    # deferred slot-steps) without decoding StepTraces
-                    self.gateway.charge_compute(
-                        charge.seconds, op_class=oc.DECODE_MASKED,
-                        tags=(oc.MASKED,) + (oc.DEFERRED,) * len(deferred)
-                        + self._degraded_tags(),
-                        bound=charge.bound)
-                else:
-                    kv_len = float(np.mean([index[s] for s in ready]))
-                    charge = self.compute.decode_charge(len(ready),
-                                                        kv_len=kv_len)
-                    self.gateway.charge_compute(
-                        charge.seconds, op_class=oc.DECODE_COMPUTE,
-                        tags=self._degraded_tags(),
-                        bound=charge.bound)
-                self._charge_allreduce(len(ready))
-        with wall.span("engine.sample"):
-            self.key, sk = jax.random.split(self.key)
-            # batch sampling params come from the lowest *resident* slot — a
-            # mask-independent choice, so masking cannot change which
-            # request's params price the batch (the one-params-per-batch
-            # limitation itself predates masking)
-            next_tokens = sample(logits, sk, self.active[slots[0]].sampling)
-
-        # --- output drain (the policy-defining crossing) ---
-        # mask-aware: only the ready slots' tokens drain — a deferred slot's
-        # sampled value is discarded and its rejoin step recomputes it from
-        # the identical cache state.  Under greedy decode (temperature 0,
-        # the serving default) that reproduces the unmasked run's tokens
-        # exactly; stochastic sampling draws the rejoin token under a later
-        # step's key, so token identity across the flag is a greedy-only
-        # guarantee
-        drain_tokens = (
-            next_tokens[_upload(np.asarray(ready, np.int32), DECODE_INPUT)]
-            if deferred else next_tokens)
-        with wall.span("engine.drain"):
-            host_tokens = self._drain(drain_tokens)
-
-        with wall.span("engine.consume"):
-            self.trace.append(StepTrace(
-                step=self.step_count, active=len(ready),
-                prep_crossings=len(small_inputs),
-                prep_bytes=sum(a.nbytes for a in small_inputs),
-                drain_bytes=int(np.asarray(host_tokens).nbytes),
-                policy=self.policy.value, virtual_t=self.clock.now,
-                deferred=len(deferred)))
-            self._consume(ready, np.asarray(host_tokens),
-                          by_position=bool(deferred))
-        if self.coalescer is not None:
-            # compute moved the clock this step: let aged queues meet their
-            # deadline now instead of waiting for the next submission
-            self.coalescer.poll()
-        return len(ready)
+        return self._step_packed(slots, ready, deferred)
 
     def _step_packed(self, slots: list, ready: list, deferred: list) -> int:
-        """Packed ragged decode step (DESIGN.md §10, the default path).
+        """Packed ragged decode step (DESIGN.md §10).
 
         The step executes over exactly the ready slots: a ``RaggedBatch``
         names the packed rows and their per-slot KV lengths, and the forward
         decodes them at the packed width on the donated resident cache,
         writing each row's new entries where its slot resides.  Prep
-        crossings, the
-        compute charge (``DECODE_PACKED``, priced per slot KV length — the
-        same terms as the masked charge, which the parity property pins)
-        and the drain all cover the packed set and nothing else, so a
-        half-empty engine stops shipping ``max_batch``-shaped bytes across
-        the bridge and billing phantom lanes.  Under greedy decode the
-        token stream is byte-identical to the dense path: rows are
-        batch-independent and consume in the same ascending slot order.
+        crossings, the compute charge (``DECODE_PACKED``, priced per slot KV
+        length) and the drain all cover the packed set and nothing else, so
+        a half-empty engine stops shipping ``max_batch``-shaped bytes across
+        the bridge and billing phantom lanes.  Rows are batch-independent
+        and consume in ascending slot order, so under greedy decode the
+        tokens do not depend on the width.
         """
         batch = RaggedBatch.from_slots(
             [(s, self.active[s].index) for s in ready])
@@ -773,13 +602,16 @@ class ServingEngine:
         small_inputs = [tokens, index] + [
             np.zeros((n,), np.int32) for _ in range(4)]
         self._emit_prep(small_inputs)
-        self._whole_batch_barrier(slots)
 
-        # --- packed forward at the bucketed width.  Pad rows (if any)
+        # --- packed forward at the bucketed width, or at the fixed full
+        # width while the ladder forces its last rung.  Pad rows (if any)
         # duplicate the first packed slot: the duplicate cache writes
-        # carry identical values, so bucketing is invisible to state —
+        # carry identical values, so the width is invisible to state —
         # and ALL accounting above/below prices the real size n.
-        width = self._bucket(n)
+        ladder = self._ladder()
+        width = (self.max_batch
+                 if ladder is not None and ladder.dense_step_forced
+                 else self._bucket(n))
         with wall.span("engine.dispatch", width=width):
             exec_slots, exec_tokens, exec_index = (batch.slot_array(), tokens,
                                                    index)
@@ -798,10 +630,9 @@ class ServingEngine:
 
         if self.compute is not None:
             with wall.span("account", what="decode_charge"):
-                charge = self.compute.decode_charge_packed(
+                charge = self.compute.decode_charge(
                     [float(k) for k in batch.kv_lens])
-                # one PACKED per packed step, one DEFERRED per slot it
-                # deferred (mirroring the MASKED/DEFERRED tag convention)
+                # one PACKED per step, one DEFERRED per slot it deferred
                 self.gateway.charge_compute(
                     charge.seconds, op_class=oc.DECODE_PACKED,
                     tags=(oc.PACKED,) + (oc.DEFERRED,) * len(deferred)
@@ -810,10 +641,9 @@ class ServingEngine:
                 self._charge_allreduce(n)
         with wall.span("engine.sample"):
             self.key, sk = jax.random.split(self.key)
-            # sampling params come from the lowest *resident* slot — the
-            # dense path's mask-independent convention, kept so packed vs
-            # dense can never disagree on which request's params price the
-            # batch
+            # sampling params come from the lowest *resident* slot — a
+            # mask-independent choice, so masking cannot change which
+            # request's params price the batch
             next_tokens = sample(logits[:n], sk,
                                  self.active[slots[0]].sampling)
 
@@ -832,12 +662,12 @@ class ServingEngine:
                 drain_bytes=int(drained.nbytes),
                 policy=self.policy.value, virtual_t=self.clock.now,
                 deferred=len(deferred), packed=n))
-            self._consume(ready, np.asarray(host_tokens), by_position=True)
+            self._consume(ready, np.asarray(host_tokens))
         if self.coalescer is not None:
             self.coalescer.poll()
         return n
 
-    # -- shared step plumbing (dense + packed) -----------------------------------------
+    # -- step plumbing -----------------------------------------------------------------
 
     def _emit_prep(self, small_inputs: list) -> None:
         """Cross one step's small modelled input arrays under the active
@@ -861,24 +691,6 @@ class ServingEngine:
         else:
             self.gateway.batch_h2d(small_inputs, op_class=oc.PREP_BATCHED_H2D)
 
-    def _whole_batch_barrier(self, slots: list) -> None:
-        """Legacy flag-off restore barrier: a decode step reads every
-        stepping slot's KV, so any restore still in flight must land first
-        (PipeLLM law).  With slot masking on, ``_ready_slots`` already
-        resolved the stepping slots' restores — this is a no-op."""
-        if self.defaults.slot_masked_decode or not self.overlap.pending:
-            return
-        with wall.span("account", what="batch_barrier"):
-            waited = 0.0
-            for s in slots:
-                w = self.overlap.restore_barrier(self.active[s].request_id)
-                if w and self.obs is not None:
-                    self.obs.spans.on_restore_wait(self.active[s].request_id,
-                                                   w)
-                waited += w
-            if waited and self.coalescer is not None:
-                self.coalescer.poll()   # the barrier wait moved the clock
-
     def _drain(self, drain_tokens) -> Any:
         """Drain one step's sampled tokens to the host under the active
         policy (the policy-defining crossing)."""
@@ -886,17 +698,9 @@ class ServingEngine:
             # bridge_opt: token values land now (they stay usable on-device
             # for the next step); the drain's toll joins the fused flush
             return self.coalescer.d2h(drain_tokens, op_class=oc.DRAIN_D2H)
-        if self.policy is SchedulingPolicy.WORKER_DRAIN:
-            done = threading.Event()
-            result = {}
-            self._drain_q.put((drain_tokens, lambda h: (result.update(h=h),
-                                                        done.set()),
-                               wall.current()))
-            done.wait()
-            return result["h"]
-        op = (oc.DRAIN_D2H_NONBLOCKING
-              if self.policy is SchedulingPolicy.ASYNC_OVERLAP
-              else oc.DRAIN_D2H)
+        op = {SchedulingPolicy.ASYNC_OVERLAP: oc.DRAIN_D2H_NONBLOCKING,
+              SchedulingPolicy.WORKER_DRAIN: oc.WORKER_DRAIN,
+              }.get(self.policy, oc.DRAIN_D2H)
         return self.gateway.d2h(drain_tokens, op_class=op)
 
     def _take_routed(self, host: np.ndarray, n: int) -> np.ndarray:
@@ -914,15 +718,12 @@ class ServingEngine:
             pass
         return host[:n]
 
-    def _consume(self, ready: list, host: np.ndarray, *,
-                 by_position: bool) -> None:
-        """Append each ready slot's drained token and retire finished
-        requests.  ``by_position`` indexes ``host`` by packed row position
-        (packed steps, masked dense steps); otherwise by slot id (full
-        dense steps, where the drain carried all ``max_batch`` rows)."""
+    def _consume(self, ready: list, host: np.ndarray) -> None:
+        """Append each ready slot's drained token (``host`` holds them by
+        packed row position) and retire finished requests."""
         for pos, s in enumerate(ready):
             req = self.active[s]
-            tok = int(host[pos] if by_position else host[s])
+            tok = int(host[pos])
             req.output_tokens.append(tok)
             if self.obs is not None:
                 self.obs.spans.on_token(req.request_id, self.clock.now)
@@ -961,5 +762,4 @@ class ServingEngine:
             "routed_assignments": (None if self.routed_assignments is None
                                    else self.routed_assignments.tolist()),
             "overlap": self.overlap.stats_dict(),
-            "closed_dirty": self.closed_dirty,
         }

@@ -164,7 +164,7 @@ class RaggedBatch:
 
     No padding anywhere: ``size`` is exactly the number of rows the forward
     executes, ``kv_lens`` are the per-slot prefix depths the pricing reads
-    (``ComputeModel.decode_charge_packed``), and ``total_kv_tokens`` is the
+    (``ComputeModel.decode_charge``), and ``total_kv_tokens`` is the
     step's KV read traffic in tokens.  Slots keep engine order (ascending),
     so row ``i`` of the packed batch is slot ``slots[i]`` — the scatter back
     into the resident cache and the per-row token drain both key on that.

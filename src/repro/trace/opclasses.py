@@ -22,7 +22,7 @@ SAMPLE_D2H = "sample_d2h"
 DRAIN_D2H = "drain_d2h"
 #: per-step output drain issued "non-blocking" (blocks anyway under CC — L2)
 DRAIN_D2H_NONBLOCKING = "drain_d2h_nonblocking"
-#: per-step output drain executed on a worker thread (v10c)
+#: per-step output drain priced on the worker's channel (v10c)
 WORKER_DRAIN = "worker_drain"
 
 # -- KV offload (§6.2) ----------------------------------------------------------------
@@ -76,18 +76,16 @@ KV_RESTORE_PIPELINED = "kv_restore_pipelined"
 # key on kind == "compute", so a new compute op class needs only to be
 # emitted via `TransferGateway.charge_compute` (which stamps the kind).
 #: one batched decode step's forward+sample compute (ComputeModel roofline)
+#: of the dense step engines no longer take; read from recorded tapes
 DECODE_COMPUTE = "decode_compute"
-#: a slot-masked decode step's compute (DESIGN.md §8): only the ready slots
-#: (restores landed) stepped; priced for the masked batch, never the full
-#: one.  A separate class from DECODE_COMPUTE so tapes distinguish masked
-#: steps — replay and attribution must not average the two shapes together.
+#: a slot-masked dense decode step's compute (DESIGN.md §8): only the ready
+#: slots (restores landed) stepped.  Read from recorded tapes; the engine
+#: now emits DECODE_PACKED, with DEFERRED tags, for a masked step.
 DECODE_MASKED = "decode_masked"
-#: a packed ragged decode step's compute (DESIGN.md §10): the forward ran
-#: over exactly the packed ready rows — no dense padding to the widest slot
-#: set — priced per slot-KV-length like DECODE_MASKED (the two charge
-#: identically for equal lengths; the parity property pins it).  A separate
-#: class so tapes distinguish packed execution from the dense/masked legacy
-#: shapes in attribution and replay.
+#: a packed ragged decode step's compute (DESIGN.md §10), the engine's one
+#: decode compute record: the forward ran over exactly the packed ready
+#: rows — no dense padding to the widest slot set — priced per
+#: slot-KV-length (``ComputeModel.decode_charge``).
 DECODE_PACKED = "decode_packed"
 #: prompt-processing compute at admission (cold tokens only — restored/warm
 #: prefix tokens skip the forward and therefore the charge)
@@ -102,17 +100,13 @@ DEQUANT_COMPUTE = "dequant_compute"
 #: arena resolved a crossing's staging buffer
 ARENA_HIT = "arena_hit"
 ARENA_MISS = "arena_miss"
-#: slot-masked decode tags on DECODE_MASKED compute records: MASKED appears
-#: once per masked step, DEFERRED once per slot that step deferred — so a
-#: tape's tag counts read directly as (masked steps, deferred slot-steps).
-#: A step with an all-ready mask takes the plain DECODE_COMPUTE path and
-#: carries neither.
+#: slot-masked decode tags: DEFERRED once per slot a step deferred; MASKED
+#: once per masked step on the DECODE_MASKED records of recorded tapes
 MASKED = "masked"
 DEFERRED = "deferred"
 #: packed ragged decode tags on DECODE_PACKED compute records: PACKED once
-#: per packed step; DEFERRED (above) once per slot that step deferred — so
-#: tag counts read as (packed steps, deferred slot-steps), mirroring the
-#: MASKED/DEFERRED convention.
+#: per step; DEFERRED (above) once per slot that step deferred — so tag
+#: counts read as (steps, deferred slot-steps)
 PACKED = "packed"
 #: resilience tags (DESIGN.md §11): RETRY marks a fault-recovery re-charge —
 #: a failed crossing attempt re-recorded with backoff, or a restore-redo

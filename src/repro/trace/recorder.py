@@ -2,7 +2,7 @@
 
 The recorder subscribes to the gateway's ``on_record`` emit hook, so it sees
 every crossing the moment it is priced — including crossings issued from
-worker threads (the v10c drain) and pool-scheduled bulk transfers.  It is a
+other threads and pool-scheduled bulk transfers.  It is a
 context manager; recording stops when it detaches, and ``tape()`` snapshots
 what has been captured so far.
 """

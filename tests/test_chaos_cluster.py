@@ -1,10 +1,10 @@
 """Failure-path hardening around the cluster (ISSUE 8 satellites): restore
-callback isolation, dirty-shutdown surfacing, lease/page leak audits,
+callback isolation, an engine shutdown with no thread to strand, lease/page
+leak audits,
 attestation-denied routing, autoscaler spawn backoff, and drain-and-re-route
 failover end to end."""
 
 import threading
-import time
 
 import pytest
 
@@ -20,6 +20,8 @@ from repro.core.policy import OffloadPolicy, SchedulingPolicy, cc_aware_defaults
 from repro.serving.engine import Request, ServingEngine
 from repro.serving.offload import OffloadManager
 from repro.serving.sampler import SamplingParams
+from repro.trace import TraceRecorder
+from repro.trace import opclasses as oc
 
 
 @pytest.fixture(scope="module")
@@ -76,31 +78,39 @@ class TestRestoreCallbackIsolation:
 
 
 # ---------------------------------------------------------------------------------
-# Satellite 2: Engine.close() surfaces a wedged drain thread
+# Satellite 2: the engine drains on its own thread, so close() strands nothing
 # ---------------------------------------------------------------------------------
 
 
-class TestEngineDirtyShutdown:
-    def test_wedged_drain_thread_warns_and_marks_dirty(self, tiny_model):
-        eng = ServingEngine(tiny_model, max_batch=2, max_len=32,
-                            policy=SchedulingPolicy.SYNC_DRAIN, cc_on=True)
-        # simulate the hang class: a worker that never services the queue
-        wedged = threading.Thread(target=time.sleep, args=(10,), daemon=True)
-        wedged.start()
-        eng._worker = wedged
-        eng.drain_join_timeout_s = 0.05
-        with pytest.warns(RuntimeWarning, match="wedged"):
-            eng.close()
-        assert eng.closed_dirty
-        assert eng.stats()["closed_dirty"] is True
+class TestEngineShutdown:
+    def test_worker_drain_starts_no_thread(self, tiny_model):
+        """WORKER_DRAIN prices a worker-channel drain and drains inline:
+        serving starts no thread, and every drain records ``worker_drain``."""
+        before = set(threading.enumerate())
 
-    def test_clean_close_stays_clean(self, tiny_model):
+        def started():
+            return set(threading.enumerate()) - before
+        eng = ServingEngine(tiny_model, max_batch=2, max_len=32,
+                            policy=SchedulingPolicy.WORKER_DRAIN, cc_on=True)
+        with TraceRecorder(eng.gateway, label="inline") as rec:
+            for i in range(3):
+                eng.submit(_req(f"r{i}", [1, 2, 3 + i], n_tokens=4))
+            stats = eng.run()
+            assert not started()
+        eng.close()
+        assert not started()
+        assert stats["finished"] == 3
+        drains = rec.tape().op_class_mix().get(oc.WORKER_DRAIN, 0)
+        assert drains == eng.step_count > 0
+
+    def test_close_twice_is_harmless(self, tiny_model):
         eng = ServingEngine(tiny_model, max_batch=2, max_len=32,
                             policy=SchedulingPolicy.WORKER_DRAIN, cc_on=True)
         eng.submit(_req("r0", [1, 2, 3]))
         eng.run()
         eng.close()
-        assert not eng.closed_dirty
+        eng.close()
+        assert [len(r.output_tokens) for r in eng.finished] == [2]
 
 
 # ---------------------------------------------------------------------------------

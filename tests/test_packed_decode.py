@@ -2,20 +2,16 @@
 
 The laws pinned here:
 
-  * empty ready set charges ZERO: ``decode_charge(0)``,
-    ``decode_charge_masked([])`` and ``decode_charge_packed([])`` are all
-    0 s / 0 flops / 0 bytes — the phantom-slot clamps (``max(1, ...)``) are
-    gone;
+  * empty ready set charges ZERO: ``decode_charge([])`` is 0 s / 0 flops /
+    0 bytes — the phantom-slot clamp (``max(1, ...)``) is gone;
   * unknown bridge-profile names raise instead of silently pricing the TPU
     v5e roofline (``spec_for_profile``); an explicit ``spec=`` still wins;
-  * pricing parity: ``decode_charge_masked([k]*b)`` equals
-    ``decode_charge(b, kv_len=k)`` exactly, and the packed charge equals
-    the masked charge for equal per-slot lengths — one roofline, three
-    entry points;
-  * the packed engine produces byte-identical token streams to the dense
-    path (greedy) at >= its virtual tok/s, emits DECODE_PACKED records
-    with PACKED/DEFERRED tags, ships packed-sized prep/drain bytes, and
-    keeps the deferral accounting of the masked path;
+  * the one decode charge is the closed form: FLOPs ``2 * params * n``,
+    HBM ``params * bytes + sum(kv) * kv_bytes_per_token``;
+  * the packed engine produces the straight-line greedy loop's token
+    streams, emits DECODE_PACKED records with PACKED/DEFERRED tags, ships
+    packed-sized prep/drain bytes, and keeps the deferral accounting of
+    the masked path;
   * admission prices the READY set (masked-aware admission cost): a
     resident slot whose restore is still draining never inflates the
     deferral threshold, and an engine with nothing ready prices zero;
@@ -28,6 +24,7 @@ import dataclasses
 import hashlib
 import json
 
+import jax
 import numpy as np
 import pytest
 
@@ -38,7 +35,9 @@ from repro.core.policy import (OffloadPolicy, SchedulingPolicy as SP,
                                cc_aware_defaults)
 from repro.core.simulator import (Observation, fit_workload,
                                   roofline_forward_ms)
+from repro.launch.serve import greedy_reference
 from repro.models.model import Model
+from repro.resilience import DegradationLadder, FaultInjector, FaultPlan
 from repro.serving.engine import Request, ServingEngine
 from repro.serving.kv_cache import RaggedBatch, ragged_block_tables
 from repro.serving.offload import HostBlock, OffloadManager
@@ -70,30 +69,26 @@ def _zero(charge):
 class TestPhantomCharges:
     """Satellite bugfix: an empty ready set must charge exactly zero."""
 
-    def test_decode_charge_empty_batch_is_zero(self):
-        assert _zero(_cm().decode_charge(0))
-        assert _zero(_cm().decode_charge(0, kv_len=4096.0))
+    @pytest.mark.parametrize("empty", [[], (), np.zeros(0)],
+                             ids=["list", "tuple", "ndarray"])
+    def test_decode_charge_empty_batch_is_zero(self, empty):
+        assert _zero(_cm().decode_charge(empty))
 
-    def test_decode_charge_negative_batch_clamps_to_zero(self):
-        assert _zero(_cm().decode_charge(-3))
-
-    def test_decode_charge_masked_empty_is_zero(self):
-        assert _zero(_cm().decode_charge_masked([]))
-
-    def test_decode_charge_packed_empty_is_zero(self):
-        assert _zero(_cm().decode_charge_packed([]))
+    def test_decode_charge_negative_kv_length_clamps_to_zero(self):
+        cm = _cm()
+        assert cm.decode_charge([-3.0, 512.0]) == cm.decode_charge([0.0,
+                                                                    512.0])
 
     def test_step_seconds_helpers_follow(self):
         cm = _cm()
-        assert cm.decode_step_s(0) == 0.0
-        assert cm.decode_step_masked_s([]) == 0.0
-        assert cm.decode_step_packed_s([]) == 0.0
+        assert cm.decode_step_s([]) == 0.0
+        assert cm.decode_step_s([512.0]) == cm.decode_charge([512.0]).seconds
 
     def test_nonempty_still_prices_the_weight_read_floor(self):
         """The fix removes the phantom, not the floor: one real slot still
         pays the full weight-read stream."""
         cm = _cm()
-        one = cm.decode_charge(1)
+        one = cm.decode_charge([0.0])
         assert one.seconds > 0.0
         floor = (cm.active_params * cm.bytes_per_param
                  / COMPUTE_SPECS["b300-hgx"].hbm_bw)
@@ -103,7 +98,7 @@ class TestPhantomCharges:
         """Regression shape: the old clamp priced an empty set like one
         slot — ms-scale phantom time per idle poll."""
         cm = _cm()
-        assert cm.decode_charge_masked([]).seconds < cm.decode_charge(1).seconds
+        assert cm.decode_charge([]).seconds < cm.decode_charge([0.0]).seconds
 
 
 class TestUnknownProfileSpec:
@@ -126,43 +121,55 @@ class TestUnknownProfileSpec:
         cm = ComputeModel(get_config("qwen3p6-27b"),
                           BridgeModel(ghost, cc_on=True),
                           spec=COMPUTE_SPECS["b300-hgx"])
-        assert cm.decode_charge(4).seconds > 0.0
+        assert cm.decode_charge([0.0] * 4).seconds > 0.0
 
     def test_spec_for_profile_direct(self):
         with pytest.raises(ValueError, match="no ComputeSpec"):
             spec_for_profile("never-heard-of-it")
 
 
+def _closed_form(cm, lens):
+    """(FLOPs, HBM bytes) of one decode step over per-slot KV ``lens``:
+    every active param once per row, the weights streamed once, and each
+    row's cached prefix read."""
+    return (2.0 * cm.active_params * len(lens),
+            cm.active_params * cm.bytes_per_param
+            + sum(lens) * cm.kv_bytes_per_token())
+
+
+def _assert_closed_form(cm, lens):
+    charge = cm.decode_charge(lens)
+    flops, hbm = _closed_form(cm, lens)
+    assert charge.flops == flops
+    assert charge.hbm_bytes == pytest.approx(hbm, rel=1e-12)
+    compute_s = cm.bridge.compute_time(flops, cm.spec.peak_flops)
+    memory_s = cm.bridge.hbm_time(hbm, cm.spec.hbm_bw)
+    assert charge.seconds == pytest.approx(max(compute_s, memory_s),
+                                           rel=1e-12)
+    assert charge.bound == ("compute" if compute_s >= memory_s else "memory")
+
+
 class TestPricingParity:
-    """One roofline, three entry points: masked == dense for uniform
-    lengths, packed == masked always."""
+    """One roofline, one entry point: the charge is the closed form, for
+    uniform and ragged per-slot lengths alike."""
 
     @pytest.mark.parametrize("batch,kv", [(1, 0.0), (3, 512.0), (8, 1536.0),
                                           (64, 4096.0), (512, 128.0)])
-    def test_masked_equals_dense_for_uniform_lengths(self, batch, kv):
-        cm = _cm()
-        masked = cm.decode_charge_masked([kv] * batch)
-        dense = cm.decode_charge(batch, kv_len=kv)
-        assert masked.seconds == pytest.approx(dense.seconds, rel=1e-12)
-        assert masked.flops == dense.flops
-        assert masked.hbm_bytes == pytest.approx(dense.hbm_bytes, rel=1e-12)
-        assert masked.bound == dense.bound
+    def test_uniform_lengths_match_the_closed_form(self, batch, kv):
+        _assert_closed_form(_cm(), [kv] * batch)
 
     @pytest.mark.parametrize("lens", [[128.0], [1.0, 2.0, 3.0],
                                       [4096.0, 0.0, 512.0, 512.0],
                                       list(np.linspace(0, 2048, 33))])
-    def test_packed_equals_masked_for_equal_lengths(self, lens):
-        cm = _cm()
-        packed = cm.decode_charge_packed(lens)
-        masked = cm.decode_charge_masked(lens)
-        assert packed == masked
+    def test_ragged_lengths_match_the_closed_form(self, lens):
+        _assert_closed_form(_cm(), lens)
 
     def test_ragged_prices_by_total_kv_not_width(self):
         """Packed pricing reads the KV sum, not batch * max: a ragged set
         charges strictly less than its dense-padded shape."""
         cm = _cm()
-        ragged = cm.decode_charge_packed([4096.0, 64.0, 64.0, 64.0])
-        padded = cm.decode_charge(4, kv_len=4096.0)
+        ragged = cm.decode_charge([4096.0, 64.0, 64.0, 64.0])
+        padded = cm.decode_charge([4096.0] * 4)
         assert ragged.seconds < padded.seconds
 
 
@@ -188,12 +195,10 @@ class TestRaggedBatch:
         assert flat.size == 6 < 3 * 3
 
 
-def _ragged_engine(model, *, packed, max_batch=4, n_requests=6, seed=0,
-                   **default_overrides):
+def _ragged_engine(model, *, max_batch=4, n_requests=6, seed=0):
     eng = ServingEngine(
         model, max_batch=max_batch, max_len=64, policy=SP.SYNC_DRAIN,
-        bridge=BridgeModel(B300, cc_on=True),
-        defaults=_defaults(packed_decode=packed, **default_overrides),
+        bridge=BridgeModel(B300, cc_on=True), defaults=_defaults(),
         seed=seed)
     for i in range(n_requests):
         eng.submit(Request(f"r{i}", prompt=[1, 2, 3 + (i % 5)],
@@ -202,31 +207,58 @@ def _ragged_engine(model, *, packed, max_batch=4, n_requests=6, seed=0,
     return eng
 
 
+def _reference_tokens(model, requests, seed):
+    """Each request's tokens from the straight-line greedy loop over all
+    prompts at once, cut to the request's own budget."""
+    budget = max(r.sampling.max_new_tokens for r in requests)
+    ref, finite = greedy_reference(
+        model.cfg, model.init(jax.random.PRNGKey(seed)),
+        [r.prompt for r in requests], max_new_tokens=budget, max_len=64)
+    assert finite
+    return {r.request_id: toks[:r.sampling.max_new_tokens]
+            for r, toks in zip(requests, ref)}
+
+
+def _full_width(eng):
+    """Hold ``eng``'s ladder at its last rung: every step runs at the
+    fixed full width."""
+    ladder = DegradationLadder(recovery_quiet_s=float("inf"))
+    FaultInjector(FaultPlan(seed=0), ladder=ladder).attach(eng.gateway)
+    ladder.observe_fault(eng.clock.now)
+    for _ in range(3):
+        ladder.escalate(eng.clock.now)
+    assert ladder.dense_step_forced
+
+
 class TestPackedEngine:
     def test_token_streams_identical_and_never_slower(self, tiny_model,
                                                       deterministic_seed):
-        """The tentpole guarantee: packed == dense token streams (greedy)
-        on a ragged workload, at >= the dense virtual tok/s."""
-        def run(packed):
-            eng = _ragged_engine(tiny_model, packed=packed,
-                                 seed=deterministic_seed)
+        """The tentpole guarantee: on a ragged workload the packed engine
+        gives the straight-line greedy loop's tokens, and its bucketed
+        widths run at >= the virtual tok/s of the fixed full width."""
+        def run(full_width):
+            eng = _ragged_engine(tiny_model, seed=deterministic_seed)
+            if full_width:
+                _full_width(eng)
+            requests = list(eng.queue)
             stats = eng.run()
             toks = {r.request_id: list(r.output_tokens)
                     for r in eng.finished}
             eng.close()
-            return toks, stats
-        tok_p, p = run(True)
-        tok_d, d = run(False)
-        assert tok_p == tok_d
-        assert p["finished"] == d["finished"] == 6
+            return toks, stats, requests
+        tok_p, p, requests = run(False)
+        tok_f, f, _ = run(True)
+        assert tok_p == tok_f == _reference_tokens(tiny_model, requests,
+                                                   deterministic_seed)
+        assert p["finished"] == f["finished"] == 6
         tps_p = p["total_tokens"] / p["virtual_time_s"]
-        tps_d = d["total_tokens"] / d["virtual_time_s"]
-        assert tps_p >= tps_d
+        tps_f = f["total_tokens"] / f["virtual_time_s"]
+        assert tps_p >= tps_f
 
     def test_packed_records_on_tape(self, tiny_model, deterministic_seed):
         """Every packed step's compute lands as one DECODE_PACKED record
         tagged PACKED, and the stream conforms."""
-        eng = _ragged_engine(tiny_model, packed=True, seed=deterministic_seed)
+        eng = _ragged_engine(tiny_model, seed=deterministic_seed)
         with TraceRecorder(eng.gateway, label="packed") as rec:
             eng.run()
         eng.close()
@@ -244,37 +276,27 @@ class TestPackedEngine:
 
     def test_step_trace_packed_matches_active(self, tiny_model,
                                               deterministic_seed):
-        eng = _ragged_engine(tiny_model, packed=True, seed=deterministic_seed)
+        eng = _ragged_engine(tiny_model, seed=deterministic_seed)
         eng.run()
         eng.close()
         assert all(t.packed == t.active for t in eng.trace)
         # ragged finishes actually exercised sub-max widths
         assert {t.packed for t in eng.trace} > {eng.max_batch}
 
-    def test_dense_trace_never_marks_packed(self, tiny_model,
-                                            deterministic_seed):
-        eng = _ragged_engine(tiny_model, packed=False,
-                             seed=deterministic_seed)
-        eng.run()
-        eng.close()
-        assert all(t.packed == 0 for t in eng.trace)
-
     def test_half_empty_engine_ships_packed_bytes(self, tiny_model,
                                                   deterministic_seed):
         """The bridge-byte win: 3 residents in an 8-slot engine prep and
         drain 3-row arrays, not max_batch-shaped ones."""
-        def run(packed):
-            eng = _ragged_engine(tiny_model, packed=packed, max_batch=8,
-                                 n_requests=3, seed=deterministic_seed)
-            eng.run()
-            eng.close()
-            return eng.trace
-        tp = run(True)
-        td = run(False)
-        assert tp[0].active == td[0].active == 3
-        assert tp[0].prep_bytes < td[0].prep_bytes
-        assert tp[0].drain_bytes < td[0].drain_bytes
-        assert td[0].drain_bytes == 8 * 4      # dense drains max_batch rows
+        eng = _ragged_engine(tiny_model, max_batch=8, n_requests=3,
+                             seed=deterministic_seed)
+        eng.run()
+        eng.close()
+        first = eng.trace[0]
+        assert first.active == first.packed == 3
+        # six int32 prep arrays of 3 rows (tokens, positions and four
+        # modelled step inputs), and 3 int32 tokens drained
+        assert first.prep_bytes == 6 * 3 * 4
+        assert first.drain_bytes == 3 * 4
 
     #: sha256 of the records (``to_dict``, keys sorted) and the virtual
     #: clock of ``test_pad_row_run_keeps_tokens_and_tape``'s run, captured
@@ -292,35 +314,32 @@ class TestPackedEngine:
     def test_pad_row_run_keeps_tokens_and_tape(self, tiny_model, policy):
         """Three requests in a four-slot engine: the three-row step runs at
         width 4 with a pad row, its inputs go up as one ``[3, 4]`` buffer,
-        the greedy tokens equal the dense path's, and the bridge tape and
-        clock equal those of the engine that moved every prep array."""
-        def run(packed):
-            eng = ServingEngine(
-                tiny_model, max_batch=4, max_len=64, cc_on=True,
-                policy=policy, seed=GOLDEN["seed"],
-                defaults=dataclasses.replace(cc_aware_defaults(True),
-                                             packed_decode=packed))
-            step, widths = eng._packed_decode, []
+        the greedy tokens equal the straight-line loop's, and the bridge
+        tape and clock equal those of the engine that moved every prep
+        array."""
+        eng = ServingEngine(
+            tiny_model, max_batch=4, max_len=64, cc_on=True,
+            policy=policy, seed=GOLDEN["seed"],
+            defaults=cc_aware_defaults(True))
+        step, widths = eng._packed_decode, []
 
-            def spy(params, caches, tokens, index, slots):
-                widths.append((len(set(np.asarray(slots).tolist())),
-                               len(slots)))
-                return step(params, caches, tokens, index, slots)
-            eng._packed_decode = spy
-            for i in range(3):
-                eng.submit(Request(f"r{i}", prompt=[5, 7 + i, 11, 2 * i + 1],
-                                   sampling=SamplingParams(
-                                       max_new_tokens=4 + 2 * i)))
-            with TraceRecorder(eng.gateway, label="pad") as rec:
-                eng.run()
-            eng.close()
-            toks = {r.request_id: list(r.output_tokens) for r in eng.finished}
-            return toks, rec.tape(), eng.clock.now, widths
-
-        toks, tape, clock, widths = run(True)
-        dense_toks, *_ = run(False)
+        def spy(params, caches, tokens, index, slots):
+            widths.append((len(set(np.asarray(slots).tolist())), len(slots)))
+            return step(params, caches, tokens, index, slots)
+        eng._packed_decode = spy
+        for i in range(3):
+            eng.submit(Request(f"r{i}", prompt=[5, 7 + i, 11, 2 * i + 1],
+                               sampling=SamplingParams(
+                                   max_new_tokens=4 + 2 * i)))
+        requests = list(eng.queue)
+        with TraceRecorder(eng.gateway, label="pad") as rec:
+            eng.run()
+        eng.close()
+        toks = {r.request_id: list(r.output_tokens) for r in eng.finished}
+        tape, clock = rec.tape(), eng.clock.now
         assert (3, 4) in widths
-        assert toks == dense_toks and len(toks) == 3
+        assert toks == _reference_tokens(tiny_model, requests, GOLDEN["seed"])
+        assert len(toks) == 3
         records = json.dumps([r.to_dict() for r in tape.records],
                              sort_keys=True)
         assert hashlib.sha256(records.encode()).hexdigest() == \
@@ -340,12 +359,13 @@ class TestPackedEngine:
                                                          deterministic_seed):
         """Slot masking composes with packing: a draining restore defers
         its slot out of the packed set, DEFERRED tags count deferred
-        slot-steps, and the token streams still match the legacy run."""
-        def run(packed):
+        slot-steps, and the token streams still match a run with no
+        restore."""
+        def run(restore):
             bridge = BridgeModel(B300, cc_on=True)
             eng = ServingEngine(
                 tiny_model, max_batch=4, max_len=64, policy=SP.SYNC_DRAIN,
-                bridge=bridge, defaults=_defaults(packed_decode=packed),
+                bridge=bridge, defaults=_defaults(),
                 compute_model=ComputeModel(get_config("qwen3p6-27b"), bridge),
                 seed=deterministic_seed)
             eng.gateway.pool.prewarm()
@@ -361,7 +381,8 @@ class TestPackedEngine:
             for b in range(96):
                 mgr.host_store[b] = HostBlock(b, 128 << 10, 2, None)
             mgr.on_restore_done.append(eng.mark_restore)
-            mgr.restore(list(range(96)), key="r0")
+            if restore:
+                mgr.restore(list(range(96)), key="r0")
             with TraceRecorder(eng.gateway, label="packed-defer") as rec:
                 stats = eng.run()
             eng.close()
@@ -370,21 +391,21 @@ class TestPackedEngine:
             return toks, stats, eng, rec.tape()
 
         tok_p, stats_p, eng_p, tape = run(True)
-        tok_d, stats_d, _, _ = run(False)
-        assert tok_p == tok_d
+        tok_free, stats_free, _, _ = run(False)
+        assert tok_p == tok_free
         assert stats_p["overlap"]["deferred_slots"] > 0
+        assert stats_free["overlap"]["deferred_slots"] == 0
         tags = tape.tag_counts()
         assert tags.get(oc.DEFERRED, 0) == sum(t.deferred
                                                for t in eng_p.trace) > 0
         # the recorder attached after the warm-up step, so the tape holds
         # one PACKED record per packed step except that first one
-        assert tags.get(oc.PACKED, 0) == sum(1 for t in eng_p.trace
-                                             if t.packed) - 1
+        assert tags.get(oc.PACKED, 0) == len(eng_p.trace) - 1
         assert oc.DECODE_MASKED not in tape.op_class_mix()
 
     def test_packed_charge_prices_exactly_the_packed_rows(self, tiny_model,
                                                           deterministic_seed):
-        """The tape's DECODE_PACKED durations equal decode_charge_packed of
+        """The tape's DECODE_PACKED durations equal decode_charge of
         the per-step ready KV lengths — accounting at the REAL size even
         when the executed bucket is wider."""
         bridge = BridgeModel(B300, cc_on=True)
@@ -404,7 +425,7 @@ class TestPackedEngine:
                        if r.op_class == oc.DECODE_PACKED]
         assert len(packed_recs) == 1
         expected = ComputeModel(
-            get_config("qwen3p6-27b"), bridge).decode_charge_packed(kv)
+            get_config("qwen3p6-27b"), bridge).decode_charge(kv)
         assert packed_recs[0].duration_s == pytest.approx(expected.seconds,
                                                           rel=1e-12)
 
@@ -441,7 +462,7 @@ class TestMaskedAwareAdmission:
         eng = self._engine(tiny_model, deterministic_seed)
         try:
             assert eng._ready_lens() == []
-            assert eng.compute.decode_step_masked_s(eng._ready_lens()) == 0.0
+            assert eng.compute.decode_step_s(eng._ready_lens()) == 0.0
         finally:
             eng.close()
 
@@ -464,21 +485,7 @@ class TestMaskedAwareAdmission:
             # and with both draining the admission price is honestly zero
             eng.mark_restore("r1", eng.clock.now + 10.0)
             assert eng._ready_lens() == []
-            assert eng.compute.decode_step_masked_s([]) == 0.0
-        finally:
-            eng.close()
-
-    def test_legacy_flag_off_prices_all_residents(self, tiny_model,
-                                                  deterministic_seed):
-        eng = self._engine(tiny_model, deterministic_seed,
-                           slot_masked_decode=False)
-        try:
-            for rid in ("r0", "r1"):
-                eng.submit(Request(rid, prompt=[1, 2, 3],
-                                   sampling=SamplingParams(max_new_tokens=8)))
-            eng.step()
-            eng.mark_restore("r0", eng.clock.now + 10.0)
-            assert len(eng._ready_lens()) == 2  # whole-batch semantics
+            assert eng.compute.decode_step_s([]) == 0.0
         finally:
             eng.close()
 
@@ -529,4 +536,4 @@ class TestSimulatorRooflineFusion:
         ms = roofline_forward_ms(cfg, B300, 64, kv_len=1536.0)
         cm = ComputeModel(cfg, BridgeModel(B300, cc_on=False))
         assert ms == pytest.approx(
-            cm.decode_step_s(64, kv_len=1536.0) * 1e3, rel=1e-12)
+            cm.decode_step_s([1536.0] * 64) * 1e3, rel=1e-12)

@@ -1,13 +1,10 @@
 """Property-based tests for the unified step-pricing roofline.
 
-The parity that makes "one roofline, three entry points" safe to rely on:
-for ANY batch size and KV depth, ``decode_charge_masked([k]*b)`` is exactly
-``decode_charge(b, kv_len=k)``, and the packed charge is exactly the masked
-charge for equal per-slot lengths — so the engine's dense, masked and
-packed paths can never drift apart in pricing, only in which rows they
-price.  Plus the phantom-charge law (empty set => exactly zero) and the
-monotonicity that keeps admission deferral sane (more KV never gets
-cheaper).
+For ANY batch size and KV depths, ``decode_charge(kv_lens)`` is the closed
+form: FLOPs ``2 * params * n``, HBM ``params * bytes + sum(kv) *
+kv_bytes_per_token``, priced by the roofline.  Plus the phantom-charge law
+(empty set => exactly zero) and the monotonicity that keeps admission
+deferral sane (more KV never gets cheaper).
 """
 
 import dataclasses
@@ -32,30 +29,39 @@ kv_lens = st.lists(st.floats(min_value=0.0, max_value=65536.0,
                    min_size=0, max_size=256)
 
 
+def _assert_closed_form(cm, lens):
+    charge = cm.decode_charge(lens)
+    flops = 2.0 * cm.active_params * len(lens)
+    hbm = (cm.active_params * cm.bytes_per_param
+           + sum(lens) * cm.kv_bytes_per_token())
+    compute_s = cm.bridge.compute_time(flops, cm.spec.peak_flops)
+    memory_s = cm.bridge.hbm_time(hbm, cm.spec.hbm_bw)
+    assert charge.flops == flops
+    assert charge.hbm_bytes == pytest.approx(hbm, rel=1e-12)
+    assert charge.seconds == pytest.approx(max(compute_s, memory_s),
+                                           rel=1e-12)
+    assert charge.bound == ("compute" if compute_s >= memory_s else "memory")
+
+
 @pytest.mark.parametrize("cm", [CM, CM_OFF], ids=["b300-on", "v5e-off"])
 @settings(max_examples=200, deadline=None)
 @given(batch=st.integers(min_value=1, max_value=4096),
        kv=st.floats(min_value=0.0, max_value=65536.0, allow_nan=False))
-def test_masked_equals_dense_for_uniform_lengths(cm, batch, kv):
-    masked = cm.decode_charge_masked([kv] * batch)
-    dense = cm.decode_charge(batch, kv_len=kv)
-    assert masked.flops == dense.flops
-    assert masked.hbm_bytes == pytest.approx(dense.hbm_bytes, rel=1e-12)
-    assert masked.seconds == pytest.approx(dense.seconds, rel=1e-12)
-    assert masked.bound == dense.bound
+def test_uniform_lengths_match_the_closed_form(cm, batch, kv):
+    _assert_closed_form(cm, [kv] * batch)
 
 
 @pytest.mark.parametrize("cm", [CM, CM_OFF], ids=["b300-on", "v5e-off"])
 @settings(max_examples=200, deadline=None)
-@given(lens=kv_lens)
-def test_packed_equals_masked_for_equal_lengths(cm, lens):
-    assert cm.decode_charge_packed(lens) == cm.decode_charge_masked(lens)
+@given(lens=kv_lens.filter(bool))
+def test_ragged_lengths_match_the_closed_form(cm, lens):
+    _assert_closed_form(cm, lens)
 
 
 @settings(max_examples=100, deadline=None)
 @given(lens=kv_lens)
 def test_empty_is_zero_nonempty_is_positive(lens):
-    charge = CM.decode_charge_packed(lens)
+    charge = CM.decode_charge(lens)
     if not lens:
         assert charge.seconds == charge.flops == charge.hbm_bytes == 0.0
     else:
@@ -69,9 +75,9 @@ def test_empty_is_zero_nonempty_is_positive(lens):
 def test_more_kv_never_cheaper(lens, extra):
     """Monotonicity: growing any slot's prefix (or adding a slot) can only
     add HBM traffic — the admission price never drops as work grows."""
-    base = CM.decode_charge_packed(lens)
-    deeper = CM.decode_charge_packed([lens[0] + extra] + lens[1:])
-    wider = CM.decode_charge_packed(lens + [extra])
+    base = CM.decode_charge(lens)
+    deeper = CM.decode_charge([lens[0] + extra] + lens[1:])
+    wider = CM.decode_charge(lens + [extra])
     assert deeper.seconds >= base.seconds
     assert wider.seconds >= base.seconds
 
@@ -80,8 +86,8 @@ def test_more_kv_never_cheaper(lens, extra):
 @given(lens=kv_lens)
 def test_pricing_is_permutation_invariant(lens):
     """Packed pricing reads the KV *sum*: slot order cannot matter."""
-    assert (CM.decode_charge_packed(list(reversed(lens)))
-            == CM.decode_charge_packed(lens))
+    assert (CM.decode_charge(list(reversed(lens)))
+            == CM.decode_charge(lens))
 
 
 @settings(max_examples=100, deadline=None)

@@ -6,7 +6,8 @@ copy them back (``scatter_slot_cache``) — it gives the same logits and the
 same cache, bit for bit, step after step: at full and partial width, with
 bucket padding, stacked and per-layer caches, a ring cache past its window,
 latent (MLA) caches, a dense first block, recurrent state and read-only
-cross-attention K/V.  The engine counts the packed steps that ran it.
+cross-attention K/V.  The engine counts the packed steps that ran it, and
+the ladder's last rung runs the same step at its full width.
 """
 
 import dataclasses
@@ -24,6 +25,8 @@ from repro.resilience import DegradationLadder, FaultInjector, FaultPlan
 from repro.serving.engine import Request, ServingEngine, packed_step_of
 from repro.serving.kv_cache import gather_slot_cache, scatter_slot_cache
 from repro.serving.sampler import SamplingParams
+from repro.trace import TraceRecorder
+from repro.trace import opclasses as oc
 
 MAX_BATCH = 8
 STEPS = 6
@@ -140,38 +143,95 @@ def test_packed_step_donates_the_resident_cache():
     assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(out))
 
 
-def _engine(model, ladder=None):
+def _engine(model, ladder=None, n_requests=4, max_batch=4):
     eng = ServingEngine(
-        model, max_batch=4, max_len=64, policy=SP.SYNC_DRAIN,
+        model, max_batch=max_batch, max_len=64, policy=SP.SYNC_DRAIN,
         bridge=BridgeModel(B300, cc_on=True),
         defaults=cc_aware_defaults(True, concurrency=4), seed=0)
     if ladder is not None:
         FaultInjector(FaultPlan(seed=0), ladder=ladder).attach(eng.gateway)
-    for i in range(4):
+    for i in range(n_requests):
         eng.submit(Request(f"r{i}", prompt=[1, 2, 3 + i],
                            sampling=SamplingParams(max_new_tokens=4 + 2 * i)))
     return eng
 
 
-@pytest.mark.parametrize("dense_steps", [0, 3])
-def test_resident_decode_steps_counts_packed_steps(dense_steps):
-    """Every packed step runs the in-place path; a dense step the ladder
-    forces does not count."""
+def _spy_widths(eng) -> list:
+    """Record the executed width of each of ``eng``'s packed steps."""
+    step, widths = eng._packed_decode, []
+
+    def spy(params, caches, tokens, index, slots):
+        widths.append(len(slots))
+        return step(params, caches, tokens, index, slots)
+    eng._packed_decode = spy
+    return widths
+
+
+def _force_last_rung(ladder, now):
+    ladder.observe_fault(now)
+    for _ in range(3):
+        ladder.escalate(now)
+    assert ladder.dense_step_forced
+
+
+def _serve(forced_steps):
+    """Serve four requests in an eight-slot engine, the ladder forcing its
+    last rung for ``forced_steps`` steps after the first; returns (engine,
+    stats, tokens, executed widths)."""
     ladder = DegradationLadder(recovery_quiet_s=float("inf"))
-    eng = _engine(_smoke("olmo-1b"), ladder)
+    eng = _engine(_smoke("olmo-1b"), ladder, max_batch=8)
+    widths = _spy_widths(eng)
     try:
         eng.step()
-        if dense_steps:
-            ladder.observe_fault(eng.clock.now)
-            for _ in range(3):
-                ladder.escalate(eng.clock.now)
-            assert ladder.dense_step_forced
-            for _ in range(dense_steps):
+        if forced_steps:
+            _force_last_rung(ladder, eng.clock.now)
+            for _ in range(forced_steps):
                 eng.step()
             ladder.recovery_quiet_s = 0.0   # one rung down a step from here
         stats = eng.run()
     finally:
         eng.close()
-    packed = sum(1 for t in eng.trace if t.packed)
-    assert stats["resident_decode_steps"] == packed > 0
-    assert sum(1 for t in eng.trace if not t.packed) == dense_steps
+    tokens = {r.request_id: r.output_tokens for r in eng.finished}
+    return eng, stats, tokens, widths
+
+
+@pytest.mark.parametrize("dense_steps", [0, 3])
+def test_resident_decode_steps_counts_packed_steps(dense_steps):
+    """Every step runs the in-place path; the steps the ladder's last rung
+    forces run it at the full width, with the same tokens."""
+    eng, stats, tokens, widths = _serve(dense_steps)
+    assert stats["resident_decode_steps"] == len(eng.trace) == len(widths)
+    forced = widths[1:1 + dense_steps]
+    assert forced == [eng.max_batch] * dense_steps
+    assert max(widths[:1] + widths[1 + dense_steps:]) == 4 < eng.max_batch
+    assert tokens == _serve(0)[2]
+
+
+@pytest.mark.parametrize("forced_steps", [0, 3])
+def test_every_step_is_packed(forced_steps):
+    eng, _, _, _ = _serve(forced_steps)
+    assert eng.trace and all(t.packed == t.active for t in eng.trace)
+
+
+def test_forced_full_width_prices_the_real_rows():
+    """At the last rung a three-row step runs at width 4, but its prep,
+    its drain and its compute charge cover the three real rows."""
+    ladder = DegradationLadder(recovery_quiet_s=float("inf"))
+    eng = _engine(_smoke("olmo-1b"), ladder, n_requests=3)
+    widths = _spy_widths(eng)
+    try:
+        _force_last_rung(ladder, eng.clock.now)
+        eng._admit()
+        kv = [float(r.index) for r in eng.active.values()]
+        with TraceRecorder(eng.gateway, label="rung3") as rec:
+            eng.step()
+    finally:
+        eng.close()
+    (step,) = eng.trace
+    assert widths == [4] and step.active == step.packed == 3
+    assert step.prep_bytes == 6 * 3 * 4 and step.drain_bytes == 3 * 4
+    (charge,) = [r for r in rec.tape().records
+                 if r.op_class == oc.DECODE_PACKED]
+    assert charge.duration_s == pytest.approx(
+        eng.compute.decode_charge(kv).seconds, rel=1e-9)
+    assert oc.DEGRADED in charge.tags

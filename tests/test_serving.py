@@ -95,7 +95,6 @@ class TestServeEntryPoint:
                                 max_new_tokens=8, seed=3, cc_on=True)
         assert stats["finished"] == len(prompts)
         assert stats["preemptions"] == 0
-        assert not stats["closed_dirty"]
         assert [r.prompt for r in finished] == prompts
         ref, finite = greedy_reference(
             cfg, Model(cfg).init(jax.random.PRNGKey(3)), prompts,

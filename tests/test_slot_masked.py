@@ -4,12 +4,12 @@ The laws pinned here:
 
   * ready-mask: a decode step steps exactly the slots whose restore
     pipelines have landed; deferred slots stay resident and rejoin with the
-    SAME output tokens an unmasked run produces (greedy decode — the mask
-    is an accounting/consumption boundary, not a numerics change);
-  * masked steps charge compute for the masked batch (DECODE_MASKED records
-    with masked/deferred tags), never the full batch;
-  * a non-restore workload's StepTrace is identical with the flag on or off
-    (what keeps the golden tapes stable across the flag);
+    SAME output tokens a run with no restore produces (greedy decode — the
+    mask is an accounting/consumption boundary, not a numerics change);
+  * masked steps charge compute for the ready slots only (DECODE_PACKED
+    records with deferred tags), never the full batch;
+  * a non-restore workload never masks, and its StepTrace does not depend
+    on the overlap preference;
   * when every slot is deferred, the nearest pipeline's barrier is paid —
     the batch always makes progress (law over preference);
   * PinnedBudget: arena bytes are a host-wide resource; over-subscription
@@ -48,11 +48,6 @@ def tiny_model():
 
 
 def _defaults(**overrides):
-    # this file pins the legacy masked/dense step semantics (DECODE_MASKED
-    # vs DECODE_COMPUTE records, dense prep/drain shapes), so the engines
-    # here run with packed ragged decode off; the packed path has its own
-    # suite (test_packed_decode.py) including packed-vs-masked parity
-    overrides.setdefault("packed_decode", False)
     return dataclasses.replace(cc_aware_defaults(True, concurrency=4),
                                **overrides)
 
@@ -71,14 +66,14 @@ def _stage_late_restore(engine, key, *, blocks=96, block_bytes=128 << 10,
     return mgr
 
 
-def _run_restore_under_decode(model, *, masked, seed=0, record=False):
+def _run_restore_under_decode(model, *, restore=True, seed=0, record=False):
     """The tentpole scenario: 4 slots decoding, one slot's restore pipeline
-    starts draining mid-run.  r0 has a short tail so masking can hide the
-    window entirely; the others keep the batch busy."""
+    starts draining mid-run (with ``restore``).  r0 has a short tail so
+    masking can hide the window entirely; the others keep the batch busy."""
     bridge = BridgeModel(B300, cc_on=True)
     engine = ServingEngine(
         model, max_batch=4, max_len=64, policy=SP.SYNC_DRAIN, bridge=bridge,
-        defaults=_defaults(slot_masked_decode=masked),
+        defaults=_defaults(),
         compute_model=ComputeModel(get_config("qwen3p6-27b"), bridge),
         seed=seed)
     engine.gateway.pool.prewarm()
@@ -90,7 +85,8 @@ def _run_restore_under_decode(model, *, masked, seed=0, record=False):
         engine.submit(Request(f"r{i}", prompt=[1, 2, 3],
                               sampling=SamplingParams(max_new_tokens=16)))
     engine.step()                      # everyone running
-    _stage_late_restore(engine, "r0")  # r0's pipeline starts draining
+    if restore:
+        _stage_late_restore(engine, "r0")  # r0's pipeline starts draining
     stats = engine.run()
     if recorder is not None:
         recorder.detach()
@@ -138,33 +134,18 @@ class TestSlotMaskedDecode:
                                                         deterministic_seed):
         """Rejoin correctness: masking changes timing, never tokens."""
         _, on, tok_on, _ = _run_restore_under_decode(
-            tiny_model, masked=True, seed=deterministic_seed)
+            tiny_model, seed=deterministic_seed)
         _, off, tok_off, _ = _run_restore_under_decode(
-            tiny_model, masked=False, seed=deterministic_seed)
+            tiny_model, restore=False, seed=deterministic_seed)
         assert tok_on == tok_off
         assert on["finished"] == off["finished"] == 4
         assert on["overlap"]["deferred_slots"] > 0
         assert off["overlap"]["deferred_slots"] == 0
 
-    def test_masked_throughput_strictly_beats_whole_batch_barrier(
-            self, tiny_model, deterministic_seed):
-        """The tentpole claim: while one slot's pipeline drains, the masked
-        engine keeps decoding — the whole-batch barrier pays the window as
-        an idle wait the masked run converts to tokens."""
-        _, on, _, _ = _run_restore_under_decode(
-            tiny_model, masked=True, seed=deterministic_seed)
-        _, off, _, _ = _run_restore_under_decode(
-            tiny_model, masked=False, seed=deterministic_seed)
-        tps_on = on["total_tokens"] / on["virtual_time_s"]
-        tps_off = off["total_tokens"] / off["virtual_time_s"]
-        assert tps_on > tps_off
-        # the barrier wait moved off the critical path, not elsewhere
-        assert on["overlap"]["barrier_wait_s"] < off["overlap"]["barrier_wait_s"]
-
     def test_step_trace_counts_deferrals_and_matches_stats(self, tiny_model,
                                                            deterministic_seed):
         eng, stats, _, _ = _run_restore_under_decode(
-            tiny_model, masked=True, seed=deterministic_seed)
+            tiny_model, seed=deterministic_seed)
         per_step = [t.deferred for t in eng.trace]
         assert sum(per_step) == stats["overlap"]["deferred_slots"] > 0
         # a masked step steps fewer slots; prep bytes shrink with the mask
@@ -177,21 +158,25 @@ class TestSlotMaskedDecode:
 
     def test_masked_compute_records_on_tape(self, tiny_model,
                                             deterministic_seed):
-        """Masked steps are tape-visible: DECODE_MASKED compute records
-        carrying the masked/deferred tags and a conformant stream."""
+        """Masked steps are tape-visible: DECODE_PACKED compute records
+        carrying one DEFERRED tag per deferred slot, and a conformant
+        stream."""
         eng, _, _, tape = _run_restore_under_decode(
-            tiny_model, masked=True, seed=deterministic_seed, record=True)
+            tiny_model, seed=deterministic_seed, record=True)
         mix = tape.op_class_mix()
         n_masked = sum(1 for t in eng.trace if t.deferred)
-        assert mix.get(oc.DECODE_MASKED, 0) == n_masked > 0
-        assert mix.get(oc.DECODE_COMPUTE, 0) == eng.step_count - n_masked
-        tags = tape.tag_counts()
-        assert tags.get(oc.MASKED, 0) == n_masked
-        # one DEFERRED per deferred slot-step: tag counts read as
-        # (masked steps, deferred slot-steps)
-        assert tags.get(oc.DEFERRED, 0) == sum(t.deferred for t in eng.trace)
+        assert n_masked > 0
+        assert mix.get(oc.DECODE_PACKED, 0) == eng.step_count
+        assert oc.DECODE_MASKED not in mix and oc.DECODE_COMPUTE not in mix
         masked_recs = [r for r in tape.records
-                       if r.op_class == oc.DECODE_MASKED]
+                       if r.op_class == oc.DECODE_PACKED
+                       and oc.DEFERRED in r.tags]
+        assert len(masked_recs) == n_masked
+        # one DEFERRED per deferred slot-step: tag counts read as
+        # deferred slot-steps
+        tags = tape.tag_counts()
+        assert tags.get(oc.DEFERRED, 0) == sum(t.deferred for t in eng.trace)
+        assert tags.get(oc.PACKED, 0) == eng.step_count
         assert all(r.is_compute and r.bound in ("compute", "memory")
                    for r in masked_recs)
         report = check_tape(tape)
@@ -205,7 +190,7 @@ class TestSlotMaskedDecode:
         bridge = BridgeModel(B300, cc_on=True)
         eng = ServingEngine(
             tiny_model, max_batch=2, max_len=64, policy=SP.SYNC_DRAIN,
-            bridge=bridge, defaults=_defaults(slot_masked_decode=True),
+            bridge=bridge, defaults=_defaults(),
             compute_model=ComputeModel(get_config("qwen3p6-27b"), bridge),
             seed=deterministic_seed)
         eng.gateway.pool.prewarm()
@@ -219,15 +204,15 @@ class TestSlotMaskedDecode:
         with TraceRecorder(eng.gateway, label="one-step") as rec:
             eng.step()                     # masks r0, steps r1 alone
         masked = [r for r in rec.tape().records
-                  if r.op_class == oc.DECODE_MASKED]
+                  if r.op_class == oc.DECODE_PACKED]
         eng.close()
-        assert len(masked) == 1
-        expected = eng.compute.decode_charge_masked([kv])
+        assert len(masked) == 1 and masked[0].tags.count(oc.DEFERRED) == 1
+        expected = eng.compute.decode_charge([kv])
         assert masked[0].duration_s == pytest.approx(expected.seconds,
                                                      rel=1e-12)
         assert masked[0].bound == expected.bound
         assert masked[0].duration_s < eng.compute.decode_charge(
-            2, kv_len=kv).seconds
+            [kv, kv]).seconds
 
     def test_all_slots_deferred_pays_nearest_barrier(self, tiny_model,
                                                      deterministic_seed):
@@ -235,8 +220,7 @@ class TestSlotMaskedDecode:
         nearest pipeline end instead of spinning."""
         eng = ServingEngine(
             tiny_model, max_batch=2, max_len=64, policy=SP.SYNC_DRAIN,
-            cc_on=True, defaults=_defaults(slot_masked_decode=True,
-                                           overlap_scheduler=False),
+            cc_on=True, defaults=_defaults(overlap_scheduler=False),
             seed=deterministic_seed)
         eng.gateway.pool.prewarm()
         eng.submit(Request("solo", prompt=[1, 2, 3],
@@ -253,12 +237,13 @@ class TestSlotMaskedDecode:
 
     def test_non_restore_workload_identical_step_trace(self, tiny_model,
                                                        deterministic_seed):
-        """Guardrail: with no restores in flight the flag changes nothing —
-        the StepTrace streams (and stats) are equal field for field."""
-        def run(masked):
+        """Guardrail: with no restores in flight nothing is masked, and the
+        overlap preference changes nothing — the StepTrace streams (and
+        stats) are equal field for field."""
+        def run(prefer):
             eng = ServingEngine(
                 tiny_model, max_batch=4, max_len=64, policy=SP.SYNC_DRAIN,
-                cc_on=True, defaults=_defaults(slot_masked_decode=masked),
+                cc_on=True, defaults=_defaults(overlap_scheduler=prefer),
                 seed=deterministic_seed)
             for i in range(6):
                 eng.submit(Request(f"r{i}", prompt=[1, 2, 3],
@@ -269,20 +254,22 @@ class TestSlotMaskedDecode:
         trace_on, on = run(True)
         trace_off, off = run(False)
         assert trace_on == trace_off
-        assert all(t.deferred == 0 for t in trace_on)
+        assert all(t.deferred == 0 and t.packed == t.active
+                   for t in trace_on)
         assert on["virtual_time_s"] == off["virtual_time_s"]
         assert on["overlap"]["deferred_slots"] == 0
 
     def test_masked_pricing_path(self):
-        """decode_charge_masked prices exactly the ready slots: weight reads
-        are batch-independent, KV traffic sums the ready prefixes only."""
+        """decode_charge prices exactly the ready slots: weight reads are
+        batch-independent, KV traffic sums the ready prefixes only."""
         cm = ComputeModel(get_config("qwen3p6-27b"),
                           BridgeModel(B300, cc_on=True))
-        full = cm.decode_charge(4, kv_len=1024.0)
-        masked = cm.decode_charge_masked([1024.0] * 3)
-        same = cm.decode_charge_masked([1024.0] * 4)
+        full = cm.decode_charge([1024.0] * 4)
+        masked = cm.decode_charge([1024.0] * 3)
         assert masked.seconds < full.seconds
-        assert same.seconds == pytest.approx(full.seconds, rel=1e-12)
+        assert full.flops - masked.flops == 2.0 * cm.active_params
+        assert full.hbm_bytes - masked.hbm_bytes == pytest.approx(
+            1024.0 * cm.kv_bytes_per_token(), rel=1e-9)
         assert masked.bound in ("compute", "memory")
 
 
@@ -368,7 +355,7 @@ class TestBoundRepricing:
                                for r in compute)
         # serving-scale decode is weight-read memory-bound (DESIGN.md §7)
         assert any(r.bound == "memory" for r in compute
-                   if r.op_class == oc.DECODE_COMPUTE)
+                   if r.op_class == oc.DECODE_PACKED)
         crossings = [r for r in tape.records if not r.is_compute]
         assert all(r.bound == "" for r in crossings)
 
@@ -387,8 +374,7 @@ class TestBoundRepricing:
         # compute-bound at compute_parity
         expect = rec_mem * B300.hbm_parity + rec_cmp * B300.compute_parity
         got = sum(r.calls * r.cc_off_avg_us * 1e-6 for r in res.rows
-                  if r.op_class in (oc.DECODE_COMPUTE, oc.DECODE_MASKED,
-                                    oc.PREFILL_COMPUTE))
+                  if r.op_class in (oc.DECODE_PACKED, oc.PREFILL_COMPUTE))
         assert got == pytest.approx(expect, rel=1e-9)
         # the old conservative scaling would have been measurably different
         assert abs(got - (rec_mem + rec_cmp) * B300.compute_parity) > 1e-6
@@ -404,7 +390,7 @@ class TestBoundRepricing:
         res = TraceReplayer(stripped).reprice(ReplaySpec(cc_on=False))
         rec_compute = stripped.compute_seconds()
         got = sum(r.calls * r.cc_off_avg_us * 1e-6 for r in res.rows
-                  if r.op_class in (oc.DECODE_COMPUTE, oc.PREFILL_COMPUTE))
+                  if r.op_class in (oc.DECODE_PACKED, oc.PREFILL_COMPUTE))
         assert got == pytest.approx(rec_compute * B300.compute_parity,
                                     rel=1e-9)
 

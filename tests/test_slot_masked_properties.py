@@ -8,7 +8,6 @@ rejoin (every request finishes), and the engine's deferral accounting is
 conserved between the per-step trace and the scheduler stats.
 """
 
-import dataclasses
 
 import pytest
 
@@ -39,8 +38,7 @@ def test_deferred_slots_never_exceed_pending_restores(n_requests, restored,
     bridge = BridgeModel(TPU_V5E, cc_on=True)
     engine = ServingEngine(
         MODEL, max_batch=4, max_len=64, policy=SP.SYNC_DRAIN, bridge=bridge,
-        defaults=dataclasses.replace(cc_aware_defaults(True, concurrency=4),
-                                     slot_masked_decode=True),
+        defaults=cc_aware_defaults(True, concurrency=4),
         compute_model=ComputeModel(MODEL.cfg, bridge), seed=0)
     engine.gateway.pool.prewarm()
     for i in range(n_requests):

@@ -313,9 +313,9 @@ class TestTPComputeModel:
         from repro.configs.base import get_config
         cfg = get_config("nemotron-4-340b")
         bridge = BridgeModel(B300, cc_on=True)
-        one = ComputeModel(cfg, bridge).decode_charge(8, kv_len=512)
+        one = ComputeModel(cfg, bridge).decode_charge([512.0] * 8)
         four = ComputeModel(cfg, bridge, tp_degree=4).decode_charge(
-            8, kv_len=512)
+            [512.0] * 8)
         assert four.seconds == pytest.approx(one.seconds / 4)
         assert four.flops == pytest.approx(one.flops / 4)
 
@@ -327,9 +327,9 @@ class TestTPComputeModel:
         cfg = get_config("nemotron-4-340b")
         bridge = BridgeModel(B300, cc_on=True)
         batch = 8
-        tp1 = ComputeModel(cfg, bridge).decode_charge(batch, kv_len=512)
+        tp1 = ComputeModel(cfg, bridge).decode_charge([512.0] * batch)
         cm4 = ComputeModel(cfg, bridge, tp_degree=4)
-        tp4 = (cm4.decode_charge(batch, kv_len=512).seconds
+        tp4 = (cm4.decode_charge([512.0] * batch).seconds
                + cm4.allreduce_seconds(batch, B300.fabric_p2p_bw))
         assert tp4 < tp1.seconds
 

@@ -1,16 +1,16 @@
 """Wall-clock spans (``repro.obs.wall``, DESIGN.md §9): the recorder, and
 the span tree one engine tick leaves under the serving defaults a CC
-deployment runs (``cc_on=True``: the worker drain, batched prep).
+deployment runs (``cc_on=True``: the worker-priced drain, batched prep).
 
 The laws pinned here:
 
   * spans nest by thread: a parent is the span open around it on the same
-    thread, or the one a working thread adopts with ``under``;
+    thread;
   * the ring keeps the newest ``CAPACITY`` records and says from when on it
     holds every span; ``clear()`` forgets them; under ``REPRO_OBS=0`` a span
     is a shared no-op that stamps nothing;
   * a decode-only tick is one ``sched.tick`` holding the admission, the
-    dispatch, the sampling, the drain (its wait and copy on the worker
+    dispatch, the sampling, the drain (its wait and copy, on the engine's
     thread) and the consumption, and it makes exactly two real crossings:
     the step's tokens, positions and slots in one ``[3, width]`` upload,
     and one drain (the modelled prep is priced, and nothing moves); an
@@ -70,32 +70,35 @@ def test_spans_nest_with_parents_and_attrs(recorder):
 
 def test_given_stamps_and_current(recorder):
     with wall.span("tick", start_ns=100) as span:
-        assert wall.current() == span.id
+        with wall.span("inner"):
+            pass
         span.end(250, stepped=3)
-    assert wall.current() == 0
-    (rec,) = wall.records()
-    assert (rec.start_ns, rec.end_ns, rec.attrs) == (100, 250, {"stepped": 3})
+    inner, tick = wall.records()
+    assert inner.parent == tick.id == span.id
+    assert (tick.start_ns, tick.end_ns, tick.attrs) == (100, 250,
+                                                        {"stepped": 3})
 
 
 def test_parents_are_per_thread_and_adoptable(recorder):
+    """A span opened on another thread, while a span is open on this one,
+    is a root there: parents never cross threads."""
     seen = {}
 
-    def work(parent):
+    def work():
         with wall.span("alone"):
-            pass
-        with wall.under(parent):
-            with wall.span("adopted"):
+            with wall.span("nested"):
                 pass
         seen["thread"] = threading.get_ident()
 
     with wall.span("main"):
-        t = threading.Thread(target=work, args=(wall.current(),))
+        t = threading.Thread(target=work)
         t.start()
-        t.join()
+        t.join(timeout=30)
+        assert not t.is_alive()
     by = {r.name: r for r in wall.records()}
     assert by["alone"].parent == 0
-    assert by["adopted"].parent == by["main"].id
-    assert by["adopted"].thread == seen["thread"] != by["main"].thread
+    assert by["nested"].parent == by["alone"].id
+    assert by["alone"].thread == seen["thread"] != by["main"].thread
 
 
 def test_ring_is_bounded_and_says_what_it_let_go(recorder):
@@ -118,9 +121,8 @@ def test_off_is_a_shared_noop(monkeypatch, recorder):
     assert a is b
     with a as s:
         s.end(5, k=2)
-        assert wall.current() == 0
-    with wall.under(7):
-        assert wall.current() == 0
+        with wall.span("z"):
+            pass
     assert wall.records() == []
 
 
@@ -182,12 +184,12 @@ def test_decode_tick_span_tree_and_crossings(recorder):
             "engine.admit", "engine.dispatch", "engine.sample",
             "engine.drain", "engine.consume"]
         drain = next(r for r in kids[t.id] if r.name == "engine.drain")
-        # the worker thread waits for the tokens and copies them, below
-        # the drain that handed them over
-        worker = {r.name: r for r in kids[drain.id]}
-        assert {"bridge.wait", "bridge.d2h"} <= set(worker)
-        assert worker["bridge.d2h"].thread != main
-        assert worker["bridge.d2h"].attrs["op_class"] == "worker_drain"
+        # the engine's thread waits for the tokens and copies them, below
+        # the drain, priced as a worker-channel drain
+        copy = {r.name: r for r in kids[drain.id]}
+        assert {"bridge.wait", "bridge.d2h"} <= set(copy)
+        assert all(r.thread == main for r in _below(kids, t))
+        assert copy["bridge.d2h"].attrs["op_class"] == "worker_drain"
         below = _below(kids, t)
         dispatch = next(r for r in kids[t.id] if r.name == "engine.dispatch")
         ups = [r for r in below if r.name == "bridge.h2d"]
