@@ -171,17 +171,22 @@ class CrossingCoalescer:
         self._enqueue(nbytes, Direction.H2D, op_class)
         return dev
 
-    def d2h(self, device_array: Any, *, op_class: str = "d2h") -> np.ndarray:
+    def d2h(self, device_array: Any, *, op_class: str = "d2h",
+            nbytes: Optional[int] = None) -> np.ndarray:
         """Device-to-host: values are available immediately (the engine needs
-        them to continue); the drain's toll joins the fused flush."""
+        them to continue); the drain's toll joins the fused flush.
+        ``nbytes``: the bytes to price, where the copy carries more."""
         # size from the device-side metadata: the actual copy happens once,
         # on whichever path the threshold picks
-        nbytes = (int(device_array.nbytes) if hasattr(device_array, "nbytes")
-                  else int(np.asarray(device_array).nbytes))
+        if nbytes is None:
+            nbytes = (int(device_array.nbytes)
+                      if hasattr(device_array, "nbytes")
+                      else int(np.asarray(device_array).nbytes))
         if self.bypass or nbytes > self.threshold_bytes:
             self.stats.passthrough += 1
             self.stats.passthrough_bytes += nbytes
-            host = self.gateway.d2h(device_array, op_class=op_class)
+            host = self.gateway.d2h(device_array, op_class=op_class,
+                                    nbytes=nbytes)
             self.poll()   # the passthrough charge moved the clock
             return host
         host = to_host(device_array, op_class, nbytes)

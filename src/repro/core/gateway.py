@@ -178,9 +178,24 @@ class TransferGateway:
         return device_put(arr, self.device, op_class)
 
     def d2h(self, device_array: jax.Array, *, op_class: str = "d2h",
-            tags: tuple = (), raw_bytes: int = 0,
-            codec: str = "") -> np.ndarray:
+            tags: tuple = (), raw_bytes: int = 0, codec: str = "",
+            nbytes: Optional[int] = None) -> np.ndarray:
         """One device-to-host crossing (the drain).  Blocking under CC (L2).
+
+        ``price_d2h`` of the array's bytes, or of ``nbytes`` where the copy
+        carries more than the crossing prices, then the copy."""
+        nbytes = _nbytes(device_array) if nbytes is None else int(nbytes)
+        self.price_d2h(nbytes, op_class=op_class, tags=tags,
+                       raw_bytes=raw_bytes, codec=codec)
+        return to_host(device_array, op_class, nbytes)
+
+    def price_d2h(self, nbytes: int, *, op_class: str = "d2h",
+                  tags: tuple = (), raw_bytes: int = 0,
+                  codec: str = "") -> None:
+        """The pricing half of ``d2h``: the crossing of ``nbytes`` is
+        charged, recorded and counted, and nothing moves.  The engine prices
+        a packed step's drain when it dispatches the step and copies it
+        while the next step runs.
 
         Drain staging follows the same economics as uploads: with a
         StagingArena attached the bounce buffer is a budgeted slab (first
@@ -190,7 +205,6 @@ class TransferGateway:
         the legacy model applies — the engine owns one persistent output
         staging buffer, so drains stay REGISTERED.
         """
-        nbytes = _nbytes(device_array)
         with wall.span("account", what="d2h"):
             if self.arena is not None:
                 staging, tag = self.arena.acquire(nbytes)
@@ -204,7 +218,6 @@ class TransferGateway:
             end = self.clock.advance(cost)
             self._record(crossing, cost, op_class, t_end=end, tags=tags,
                          raw_bytes=raw_bytes, codec=codec)
-        return to_host(device_array, op_class, nbytes)
 
     def batch_h2d(self, host_arrays: Sequence[np.ndarray], *,
                   op_class: str = "batch_h2d") -> list[jax.Array]:

@@ -19,6 +19,13 @@ Policy structure per decode step (paper §5):
   WORKER_DRAIN  v10c: the drain is priced as a worker-channel crossing
                 (``worker_drain``) and runs inline on the engine's thread.
                 Input crossings return to warm staging.
+
+Sampling runs inside the packed step, which keeps each slot's last token
+on the device.  Under SYNC_DRAIN and WORKER_DRAIN a step in which no row
+can end (no row reaches its length and none has a stop token) is priced
+when it is dispatched and drained in the next tick, after the next step
+is dispatched: one step stays in flight.  A step that can end a row
+drains in its own tick.
 """
 
 from __future__ import annotations
@@ -37,7 +44,7 @@ from repro.bridge_opt import CrossingCoalescer, StagingArena
 from repro.core.bridge import BridgeModel
 from repro.core.channels import VirtualClock
 from repro.core.compute import ComputeModel
-from repro.core.gateway import TransferGateway, device_put
+from repro.core.gateway import TransferGateway, device_put, to_host
 from repro.core.policy import RuntimeDefaults, SchedulingPolicy, cc_aware_defaults
 from repro.obs import Observatory, wall
 from repro.trace import opclasses as oc
@@ -91,6 +98,21 @@ class StepTrace:
     packed: int = 0
 
 
+@dataclass
+class _Flight:
+    """A dispatched packed step whose drain is priced and whose tokens have
+    not reached the host yet."""
+    #: the rows' slots, in packed row order
+    ready: list
+    #: per row: the step ends it by length (decided at dispatch)
+    ends: list
+    width: int
+    #: the program's drain array, on the device
+    drain: Any
+    #: the step's record; ``virtual_t`` is when its drain was priced
+    trace: StepTrace
+
+
 def _and_routed(logits, caches, routed) -> tuple:
     """A serving program's outputs: (logits, caches), and in a model with
     experts its (MoE layers, experts held) assignment counts after them."""
@@ -121,27 +143,45 @@ def packed_step(model: Model, params, caches, tokens, index, slots):
 
 
 def packed_step_of(model: Model, device: Optional[jax.Device] = None):
-    """``packed_step`` bound to ``model``, behind one upload of its inputs.
+    """``packed_step`` bound to ``model``, with sampling inside, behind one
+    upload of its inputs.
 
-    The returned callable takes the step's host int32 inputs (tokens
-    ``[w, 1]``, positions and slots ``[w]``), stacks them into one
-    ``[3, w]`` buffer, puts that on ``device`` in one transfer, and runs
-    the program (its ``program`` attribute) that unpacks the buffer and
-    steps.  The program is jitted under the name ``packed_step``, so a
-    profiler trace shows it so, and the resident cache (argument 1) is
-    donated: the step updates it in place."""
-    def program(params, caches, inputs):
-        return packed_step(model, params, caches, inputs[0][:, None],
-                           inputs[1], inputs[2])
+    The returned callable takes the resident last-token vector (int32
+    ``[max_batch]``), the sampling key, the step's host int32 inputs
+    (tokens ``[w, 1]``, positions and slots ``[w]``) and the sampling
+    parameters.  It stacks the inputs into one ``[3, w]`` buffer, puts that
+    on ``device`` in one transfer, and runs the program (its ``program``
+    attribute).  The program takes a row's input token from the vector by
+    slot where the host's token is negative, steps, splits the key, samples
+    every row at the executed width, writes the sampled tokens into the
+    vector by slot, and returns (logits, caches, drain, vector, key):
+    ``drain`` is the one int32 array the host copies, the sampled tokens
+    then, in a model with experts, the assignment counts.  The program is
+    jitted under the name ``packed_step``, so a profiler trace shows it so,
+    with the sampling parameters static; the resident cache and the vector
+    (arguments 1 and 2) are donated: the step updates them in place."""
+    def program(params, caches, last, key, inputs, sampling):
+        slots = inputs[2]
+        tokens = jnp.where(inputs[0] < 0, last[slots], inputs[0])
+        logits, caches, *routed = packed_step(
+            model, params, caches, tokens[:, None], inputs[1], slots)
+        key, sub = jax.random.split(key)
+        sampled = sample(logits, sub, sampling)
+        # pad rows repeat the first row's slot, and a draw at temperature
+        # differs by row: every write to that slot carries the first row's
+        last = last.at[slots].set(jnp.where(slots == slots[0], sampled[0],
+                                            sampled))
+        return logits, caches, _with_routed(sampled, routed), last, key
     program.__name__ = program.__qualname__ = "packed_step"
-    program = jax.jit(program, donate_argnums=(1,))
+    program = jax.jit(program, static_argnums=(5,), donate_argnums=(1, 2))
 
-    def step(params, caches, tokens, index, slots):
+    def step(params, caches, last, key, tokens, index, slots,
+             sampling: SamplingParams = SamplingParams()):
         inputs = np.stack([np.asarray(tokens, np.int32).reshape(-1),
                            np.asarray(index, np.int32),
                            np.asarray(slots, np.int32)])
-        return program(params, caches,
-                       device_put(inputs, device, DECODE_INPUT))
+        return program(params, caches, last, key,
+                       device_put(inputs, device, DECODE_INPUT), sampling)
     step.program = program
     return step
 
@@ -221,9 +261,12 @@ class ServingEngine:
         #: gives the same bits everywhere, then committed to the gateway's
         #: device: every jitted step runs on that chip (one replica per chip)
         self.device = self.gateway.device
-        self.params, self.caches = jax.device_put(
+        #: ``last_tokens``: each slot's last sampled token, written by the
+        #: packed step and read by the next, so it never waits on the host
+        self.params, self.caches, self.last_tokens = jax.device_put(
             (model.init(jax.random.PRNGKey(seed)),
-             model.init_cache(max_batch, max_len)), self.device)
+             model.init_cache(max_batch, max_len),
+             np.zeros(max_batch, np.int32)), self.device)
         self.key = jax.random.PRNGKey(seed + 1)
 
         self.free_slots = list(range(max_batch))
@@ -243,9 +286,14 @@ class ServingEngine:
         # `_bucket` pads widths to powers of two so the trace count stays
         # O(log max_batch) even when the ready set raggedly shrinks slot by
         # slot as requests finish.
-        self._packed_decode = packed_step_of(model, self.device)
+        self._packed_program = packed_step_of(model, self.device)
         #: packed steps run on the donated resident cache (``stats()``)
         self.resident_decode_steps = 0
+        #: the packed step dispatched and priced, not yet drained
+        self._flight: Optional[_Flight] = None
+        #: packed steps dispatched while their predecessor was in flight
+        #: (``stats()``)
+        self.lookahead_steps = 0
         #: running total of the MoE programs' assignments to each (MoE
         #: layer, expert held here), over prefills and packed steps
         #: (``stats()``; ``None`` without experts)
@@ -276,6 +324,7 @@ class ServingEngine:
         return min(b, self.max_batch)
 
     def close(self):
+        self.settle()
         if self.coalescer is not None:
             self.coalescer.barrier()
 
@@ -403,7 +452,7 @@ class ServingEngine:
             first_host = self.coalescer.d2h(first, op_class=oc.SAMPLE_D2H)
         else:
             first_host = self.gateway.d2h(first, op_class=oc.SAMPLE_D2H)
-        first_host = self._take_routed(np.asarray(first_host), 1)
+        first_host = self._take_routed(np.asarray(first_host), 1, 1)
         tok = int(first_host[0])
         req.output_tokens.append(tok)
         req.first_token_t = self.clock.now
@@ -436,6 +485,10 @@ class ServingEngine:
         self.caches = new
 
     def _release(self, req: Request, *, state: str = "finished") -> None:
+        """Free ``req``'s slot, finished or requeued.  A release from
+        outside the step lands the step in flight first, so the request
+        leaves with every token it decoded."""
+        self.settle()
         if req.slot >= 0:
             self.active.pop(req.slot, None)
             self.free_slots.append(req.slot)
@@ -563,9 +616,22 @@ class ServingEngine:
         crossings, the forward, the compute charge and the drain are all
         sized to the packed set, while deferred slots stay resident and
         rejoin next step.
+
+        Where a step is in flight and the next step can end a row, a
+        running one or one an admission adds, this iteration only lands the
+        step in flight (and returns its rows): the next step then drains in
+        its own iteration, and no iteration delivers two steps' tokens.
         """
         self.step_compiled = False
         self.step_admitted = 0
+        flight = self._flight
+        if flight is not None and (
+                any(self._may_end(s, req, flight.ready)
+                    for s, req in self.active.items())
+                or (self.free_slots
+                    and any(map(self._may_end_first, self.queue)))):
+            self.settle()
+            return len(flight.ready)
         self._sync_ladder()
         with wall.span("engine.admit"):
             self._admit()
@@ -575,27 +641,37 @@ class ServingEngine:
         slots = sorted(self.active)
         with wall.span("account", what="ready_slots"):
             ready, deferred = self._ready_slots(slots)
-        return self._step_packed(slots, ready, deferred)
+        return self._step_packed(ready, deferred)
 
-    def _step_packed(self, slots: list, ready: list, deferred: list) -> int:
+    def _step_packed(self, ready: list, deferred: list) -> int:
         """Packed ragged decode step (DESIGN.md §10).
 
         The step executes over exactly the ready slots: a ``RaggedBatch``
         names the packed rows and their per-slot KV lengths, and the forward
-        decodes them at the packed width on the donated resident cache,
-        writing each row's new entries where its slot resides.  Prep
+        decodes and samples them at the packed width on the donated resident
+        cache, writing each row's new entries where its slot resides.  Prep
         crossings, the compute charge (``DECODE_PACKED``, priced per slot KV
         length) and the drain all cover the packed set and nothing else, so
         a half-empty engine stops shipping ``max_batch``-shaped bytes across
         the bridge and billing phantom lanes.  Rows are batch-independent
         and consume in ascending slot order, so under greedy decode the
         tokens do not depend on the width.
+
+        The step is dispatched before the step in flight is drained.  Where
+        no row can end in it and the drain is the gateway's own (no
+        coalescer, not ``ASYNC_OVERLAP``), its drain is priced now and it
+        stays in flight until the next step is dispatched; otherwise it
+        drains in this tick.
         """
         batch = RaggedBatch.from_slots(
             [(s, self.active[s].index) for s in ready])
         n = batch.size
+        # a row whose last token the step in flight sampled reads it on the
+        # device, from the resident vector (host token -1)
+        behind = set(self._flight.ready) if self._flight is not None else ()
         tokens = np.asarray(
-            [[self.active[s].output_tokens[-1]] for s in ready], np.int32)
+            [[-1 if s in behind else self.active[s].output_tokens[-1]]
+             for s in ready], np.int32)
         index = np.asarray(batch.kv_lens, np.int32)
 
         # --- input prep crossings: sized to the packed set, never max_batch
@@ -612,7 +688,8 @@ class ServingEngine:
         width = (self.max_batch
                  if ladder is not None and ladder.dense_step_forced
                  else self._bucket(n))
-        with wall.span("engine.dispatch", width=width):
+        inflight = int(self._flight is not None)
+        with wall.span("engine.dispatch", width=width, inflight=inflight):
             exec_slots, exec_tokens, exec_index = (batch.slot_array(), tokens,
                                                    index)
             if width > n:
@@ -623,10 +700,12 @@ class ServingEngine:
                     [tokens, np.repeat(tokens[:1], pad, axis=0)])
                 exec_index = np.concatenate([index, np.repeat(index[:1], pad)])
             self._note_shape(("packed", width))
-            logits, self.caches, *routed = self._packed_decode(
+            _, self.caches, drain = self._packed_decode(
                 self.params, self.caches, exec_tokens, exec_index,
                 exec_slots)
             self.resident_decode_steps += 1
+            self.lookahead_steps += inflight
+            ends, may_end = self._advance(ready, behind)
 
         if self.compute is not None:
             with wall.span("account", what="decode_charge"):
@@ -639,33 +718,99 @@ class ServingEngine:
                     + self._degraded_tags(),
                     bound=charge.bound)
                 self._charge_allreduce(n)
-        with wall.span("engine.sample"):
-            self.key, sk = jax.random.split(self.key)
-            # sampling params come from the lowest *resident* slot — a
-            # mask-independent choice, so masking cannot change which
-            # request's params price the batch
-            next_tokens = sample(logits[:n], sk,
-                                 self.active[slots[0]].sampling)
 
         # --- output drain: exactly the packed rows, nothing else (and an
-        # MoE program's counts, on the same copy)
-        with wall.span("engine.drain"):
-            host_tokens = self._drain(_with_routed(next_tokens, routed))
-
-        with wall.span("engine.consume"):
-            drained = np.asarray(host_tokens)
-            host_tokens = self._take_routed(drained, n)
-            self.trace.append(StepTrace(
-                step=self.step_count, active=n,
-                prep_crossings=len(small_inputs),
-                prep_bytes=sum(a.nbytes for a in small_inputs),
-                drain_bytes=int(drained.nbytes),
-                policy=self.policy.value, virtual_t=self.clock.now,
-                deferred=len(deferred), packed=n))
-            self._consume(ready, np.asarray(host_tokens))
+        # MoE program's counts, on the same copy); the pad rows' tokens
+        # ride the copy unpriced
+        nbytes = int(drain.nbytes) - drain.dtype.itemsize * (width - n)
+        flight = _Flight(ready, ends, width, drain,
+                         StepTrace(step=self.step_count, active=n,
+                                   prep_crossings=len(small_inputs),
+                                   prep_bytes=sum(a.nbytes
+                                                  for a in small_inputs),
+                                   drain_bytes=nbytes,
+                                   policy=self.policy.value, virtual_t=0.0,
+                                   deferred=len(deferred), packed=n))
         if self.coalescer is not None:
+            # bridge_opt: token values land now (they stay usable on-device
+            # for the next step); the drain's toll joins the fused flush
+            with wall.span("engine.drain"):
+                host = self.coalescer.d2h(drain, op_class=oc.DRAIN_D2H,
+                                          nbytes=nbytes)
+            flight.trace.virtual_t = self.clock.now
+            self._land(flight, host)
             self.coalescer.poll()
+            return n
+        self.gateway.price_d2h(nbytes, op_class=self._drain_class())
+        flight.trace.virtual_t = self.clock.now
+        ahead = (not may_end
+                 and self.policy is not SchedulingPolicy.ASYNC_OVERLAP)
+        if ahead:
+            drain.copy_to_host_async()
+        self.settle()
+        if ahead:
+            self._flight = flight
+        else:
+            self._land(flight)
         return n
+
+    def _packed_decode(self, params, caches, tokens, index, slots) -> tuple:
+        """Run the packed step program on the engine's device state: the
+        resident last-token vector and the key go in and are replaced by
+        the program's.  Returns (logits, caches, drain).
+
+        The sampling parameters come from the lowest *resident* slot — a
+        mask-independent choice, so masking cannot change which request's
+        parameters sample the batch; only those that sample key the
+        program."""
+        sp = self.active[min(self.active)].sampling
+        logits, caches, drain, self.last_tokens, self.key = \
+            self._packed_program(
+                params, caches, self.last_tokens, self.key, tokens, index,
+                slots, SamplingParams(temperature=sp.temperature,
+                                      top_k=sp.top_k))
+        return logits, caches, drain
+
+    def _advance(self, ready: list, behind) -> tuple[list, bool]:
+        """A dispatched step's bookkeeping that no token value decides: each
+        row's position and step count move on, and whether the step ends
+        it by length.  ``behind``: the rows of the step in flight.  Returns
+        (per row, ends by length; whether any row may end, by length or at
+        its stop token)."""
+        ends = [self._ends(s, self.active[s], behind) for s in ready]
+        may_end = any(self._may_end(s, self.active[s], behind)
+                      for s in ready)
+        for s in ready:
+            self.active[s].index += 1
+            self.active[s].decode_steps += 1
+        return ends, may_end
+
+    def _ends(self, slot: int, req: Request, behind) -> bool:
+        """Whether ``req``'s next step ends it by length.  ``behind``: the
+        rows of the step in flight, whose tokens the host has not yet."""
+        # tokens after the step: the host's, the step in flight's, its own
+        outputs = len(req.output_tokens) + (slot in behind) + 1
+        return (outputs >= req.sampling.max_new_tokens
+                or req.index + 1 >= self.max_len - 1)
+
+    def _may_end(self, slot: int, req: Request, behind) -> bool:
+        """Whether ``req``'s next step can end it: by length, or at a stop
+        token."""
+        return req.sampling.stop_token >= 0 or self._ends(slot, req, behind)
+
+    def _may_end_first(self, req: Request) -> bool:
+        """Whether a queued request's first decode step can end it: the
+        prefill gives its first token and sets its index to the prompt's
+        length."""
+        sp = req.sampling
+        return (sp.stop_token >= 0 or sp.max_new_tokens <= 2
+                or len(req.prompt) + 1 >= self.max_len - 1)
+
+    def settle(self) -> None:
+        """Drain and consume the packed step in flight, if one is."""
+        flight, self._flight = self._flight, None
+        if flight is not None:
+            self._land(flight)
 
     # -- step plumbing -----------------------------------------------------------------
 
@@ -691,47 +836,54 @@ class ServingEngine:
         else:
             self.gateway.batch_h2d(small_inputs, op_class=oc.PREP_BATCHED_H2D)
 
-    def _drain(self, drain_tokens) -> Any:
-        """Drain one step's sampled tokens to the host under the active
+    def _drain_class(self) -> str:
+        """The op class of a packed step's gateway drain under the active
         policy (the policy-defining crossing)."""
-        if self.coalescer is not None:
-            # bridge_opt: token values land now (they stay usable on-device
-            # for the next step); the drain's toll joins the fused flush
-            return self.coalescer.d2h(drain_tokens, op_class=oc.DRAIN_D2H)
-        op = {SchedulingPolicy.ASYNC_OVERLAP: oc.DRAIN_D2H_NONBLOCKING,
-              SchedulingPolicy.WORKER_DRAIN: oc.WORKER_DRAIN,
-              }.get(self.policy, oc.DRAIN_D2H)
-        return self.gateway.d2h(drain_tokens, op_class=op)
+        return {SchedulingPolicy.ASYNC_OVERLAP: oc.DRAIN_D2H_NONBLOCKING,
+                SchedulingPolicy.WORKER_DRAIN: oc.WORKER_DRAIN,
+                }.get(self.policy, oc.DRAIN_D2H)
 
-    def _take_routed(self, host: np.ndarray, n: int) -> np.ndarray:
-        """The first ``n`` entries of a drained array, the tokens.  In an MoE
-        model the program's (MoE layers, experts held) assignment counts
-        follow them: they are added to ``routed_assignments`` and put on an
-        ``engine.routed`` span (attr ``counts``)."""
-        if host.shape[0] == n:
-            return host
-        counts = host[n:].reshape(-1, self.cfg.experts_held)
-        if self.routed_assignments is None:
-            self.routed_assignments = np.zeros(counts.shape, np.int64)
-        self.routed_assignments += counts
-        with wall.span("engine.routed", counts=counts):
-            pass
+    def _land(self, flight: _Flight, host: Optional[np.ndarray] = None) -> None:
+        """Copy a priced step's drain to the host (unless ``host`` holds it
+        already) and consume its tokens."""
+        trace = flight.trace
+        if host is None:
+            with wall.span("engine.drain"):
+                host = to_host(flight.drain, self._drain_class(),
+                               trace.drain_bytes)
+        with wall.span("engine.consume"):
+            tokens = self._take_routed(np.asarray(host), trace.packed,
+                                       flight.width)
+            self.trace.append(trace)
+            self._consume(flight, tokens)
+
+    def _take_routed(self, host: np.ndarray, n: int, width: int) -> np.ndarray:
+        """The first ``n`` of the ``width`` tokens a program drained.  In an
+        MoE model the program's (MoE layers, experts held) assignment counts
+        follow the tokens: they are added to ``routed_assignments`` and put
+        on an ``engine.routed`` span (attr ``counts``)."""
+        if host.shape[0] > width:
+            counts = host[width:].reshape(-1, self.cfg.experts_held)
+            if self.routed_assignments is None:
+                self.routed_assignments = np.zeros(counts.shape, np.int64)
+            self.routed_assignments += counts
+            with wall.span("engine.routed", counts=counts):
+                pass
         return host[:n]
 
-    def _consume(self, ready: list, host: np.ndarray) -> None:
-        """Append each ready slot's drained token (``host`` holds them by
-        packed row position) and retire finished requests."""
-        for pos, s in enumerate(ready):
+    def _consume(self, flight: _Flight, tokens: np.ndarray) -> None:
+        """Append each row's drained token (``tokens`` by packed row
+        position), stamped with the virtual time its drain was priced, and
+        retire the requests it ends (only a step drained in its own tick
+        ends one, so that time is now)."""
+        t = flight.trace.virtual_t
+        for pos, s in enumerate(flight.ready):
             req = self.active[s]
-            tok = int(host[pos])
+            tok = int(tokens[pos])
             req.output_tokens.append(tok)
             if self.obs is not None:
-                self.obs.spans.on_token(req.request_id, self.clock.now)
-            req.index += 1
-            req.decode_steps += 1
-            sp = req.sampling
-            if (len(req.output_tokens) >= sp.max_new_tokens
-                    or tok == sp.stop_token or req.index >= self.max_len - 1):
+                self.obs.spans.on_token(req.request_id, t)
+            if flight.ends[pos] or tok == req.sampling.stop_token:
                 self._release(req)
 
     def run(self, max_steps: int = 10_000) -> dict:
@@ -740,6 +892,7 @@ class ServingEngine:
             if self.step() == 0 and not self.queue:
                 break
             steps += 1
+        self.settle()
         if self.coalescer is not None:
             self.coalescer.barrier()    # nothing queued survives a run
         return self.stats()
@@ -759,6 +912,7 @@ class ServingEngine:
             "mean_ttft_s": float(np.mean(ttfts)) if ttfts else 0.0,
             "steps": self.step_count,
             "resident_decode_steps": self.resident_decode_steps,
+            "lookahead_steps": self.lookahead_steps,
             "routed_assignments": (None if self.routed_assignments is None
                                    else self.routed_assignments.tolist()),
             "overlap": self.overlap.stats_dict(),

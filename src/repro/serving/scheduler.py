@@ -108,6 +108,7 @@ class Scheduler:
             if self.tick() == 0 and not self.engine.queue:
                 break
             ticks += 1
+        self.engine.settle()
         stats = self.engine.stats()
         stats.update(preemptions=self.preemptions, rejected=self.rejected)
         return stats

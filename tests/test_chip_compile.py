@@ -21,6 +21,7 @@ from repro.kernels.paged_attention.ops import paged_attention
 from repro.models.model import Model
 from repro.serving.engine import (packed_step, packed_step_of,
                                   prefill_logits_cache)
+from repro.serving.sampler import SamplingParams
 
 #: HBM of one TPU v5e chip (Google Cloud documentation, "TPU v5e")
 V5E_HBM_BYTES = 16e9
@@ -68,6 +69,14 @@ def _compile(fn, *args):
 @pytest.fixture(scope="module")
 def olmo():
     return Model(get_config("olmo-1b"))
+
+
+def _step_state(sharding, width, max_batch):
+    """The packed step program's arguments after the cache: the resident
+    last-token vector, the key and the ``[3, width]`` input buffer."""
+    return (_on(sharding, (max_batch,), jnp.int32),
+            _on(sharding, (2,), jnp.uint32),
+            _on(sharding, (3, width), jnp.int32))
 
 
 def _params_of(model, sharding):
@@ -132,9 +141,9 @@ def test_olmo_packed_step_decodes_in_place(one_chip, tpu_branch, olmo):
     K or V cache (the loop writes one row a layer and slot in place)."""
     params = _params_of(olmo, one_chip)
     caches = _olmo_cache(olmo, one_chip)
-    inputs = _on(one_chip, (3, 8), jnp.int32)
-    compiled = packed_step_of(olmo).program.lower(params, caches,
-                                                  inputs).compile()
+    compiled = packed_step_of(olmo).program.lower(
+        params, caches, *_step_state(one_chip, 8, 8),
+        SamplingParams()).compile()
     mem = compiled.memory_analysis()
     cache_bytes = sum(leaf.size * leaf.dtype.itemsize
                       for leaf in jax.tree.leaves(caches))
@@ -174,9 +183,9 @@ def test_dsv2lite_packed_step_fits_and_decodes_in_place(one_chip, tpu_branch):
     caches = jax.tree.map(
         lambda s: _on(one_chip, s.shape, s.dtype),
         jax.eval_shape(lambda: model.init_cache(32, 2048)))
-    inputs = _on(one_chip, (3, 32), jnp.int32)
-    compiled = packed_step_of(model).program.lower(params, caches,
-                                                   inputs).compile()
+    compiled = packed_step_of(model).program.lower(
+        params, caches, *_step_state(one_chip, 32, 32),
+        SamplingParams()).compile()
     mem = compiled.memory_analysis()
     cache_bytes = sum(leaf.size * leaf.dtype.itemsize
                       for leaf in jax.tree.leaves(caches))

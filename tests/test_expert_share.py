@@ -218,8 +218,8 @@ def test_engine_carries_the_counts_on_the_token_drain(monkeypatch):
 
 
 def test_a_model_without_experts_serves_as_before():
-    """olmo's packed step hands back its logits and cache alone, those of
-    the plain decode step, and its drain is its tokens alone."""
+    """olmo's packed step hands back the logits and cache of the plain
+    decode step, and its drain is its sampled tokens alone."""
     model = Model(smoke_config(get_config("olmo-1b")))
     params = model.init(jax.random.PRNGKey(0))
     caches = model.init_cache(4, 32)
@@ -227,10 +227,12 @@ def test_a_model_without_experts_serves_as_before():
     tokens = np.ones((2, 1), np.int32)
     want, want_cache = model.decode_step(params, jax.tree.map(jnp.copy, caches),
                                          tokens, rows + 3, rows)
-    out = packed_step_of(model)(params, caches, tokens, rows + 3, rows)
-    assert len(out) == 2
-    got, got_cache = out
+    got, got_cache, drain, *_ = packed_step_of(model)(
+        params, caches, jnp.zeros(4, jnp.int32), jax.random.PRNGKey(0),
+        tokens, rows + 3, rows)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(
+        np.asarray(drain), np.argmax(np.asarray(want, np.float32)[:, -1], -1))
     for a, b in zip(jax.tree.leaves(got_cache), jax.tree.leaves(want_cache)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     eng = _serve(model, _requests(3, model.cfg.vocab_size))

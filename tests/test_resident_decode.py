@@ -115,6 +115,7 @@ def test_packed_step_matches_the_copying_composition(case):
     caches = _filled_cache(model, max_len, enc_len, starts, rng)
     old, new = _copy_step(model), packed_step_of(model)
     resident = jax.tree.map(jnp.copy, caches)
+    last, key = jnp.zeros(MAX_BATCH, jnp.int32), jax.random.PRNGKey(0)
     slots = np.asarray(rows, np.int32)
     for step in range(STEPS):
         tokens = rng.integers(0, model.cfg.vocab_size, (len(rows), 1))
@@ -124,7 +125,8 @@ def test_packed_step_matches_the_copying_composition(case):
         dup = np.asarray([first[s] for s in rows])
         tokens, index = tokens[dup].astype(np.int32), index.astype(np.int32)
         want, caches = old(params, caches, tokens, index, slots)
-        got, resident, *_ = new(params, resident, tokens, index, slots)
+        got, resident, _, last, key = new(params, resident, last, key,
+                                          tokens, index, slots)
         assert np.isfinite(np.asarray(want, np.float32)).all()
         _assert_same(got, want, f"{case} step {step} logits")
         _assert_same(resident, caches, f"{case} step {step} cache")
@@ -137,10 +139,14 @@ def test_packed_step_donates_the_resident_cache():
     params = model.init(jax.random.PRNGKey(0))
     caches = model.init_cache(MAX_BATCH, 32)
     rows = np.arange(2, dtype=np.int32)
-    _, out = packed_step_of(model)(params, caches, np.ones((2, 1), np.int32),
-                                   rows, rows)
+    last = jnp.zeros(MAX_BATCH, jnp.int32)
+    _, out, _, last_out, _ = packed_step_of(model)(
+        params, caches, last, jax.random.PRNGKey(0),
+        np.ones((2, 1), np.int32), rows, rows)
     assert all(leaf.is_deleted() for leaf in jax.tree.leaves(caches))
     assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(out))
+    # the last-token vector is donated with it
+    assert last.is_deleted() and not last_out.is_deleted()
 
 
 def _engine(model, ladder=None, n_requests=4, max_batch=4):
@@ -185,7 +191,8 @@ def _serve(forced_steps):
         eng.step()
         if forced_steps:
             _force_last_rung(ladder, eng.clock.now)
-            for _ in range(forced_steps):
+            # an iteration that only lands the step in flight runs none
+            while len(widths) < 1 + forced_steps:
                 eng.step()
             ladder.recovery_quiet_s = 0.0   # one rung down a step from here
         stats = eng.run()

@@ -10,12 +10,16 @@ The laws pinned here:
     holds every span; ``clear()`` forgets them; under ``REPRO_OBS=0`` a span
     is a shared no-op that stamps nothing;
   * a decode-only tick is one ``sched.tick`` holding the admission, the
-    dispatch, the sampling, the drain (its wait and copy, on the engine's
-    thread) and the consumption, and it makes exactly two real crossings:
-    the step's tokens, positions and slots in one ``[3, width]`` upload,
-    and one drain (the modelled prep is priced, and nothing moves); an
-    admission makes two: the prompt through the gateway, which the prefill
-    reads, and the first token;
+    dispatch of its step (which samples inside the program), then the
+    drain (its wait and copy, on the engine's thread) and consumption of
+    the step in flight before it and, where its own step can end a row, of
+    its own; before a step that can end a row, a tick only lands the step
+    in flight.  A tick whose step follows one in flight and stays in flight
+    makes exactly two real crossings: the step's tokens, positions and
+    slots in one ``[3, width]`` upload, and the drain of the step before
+    (the modelled prep is priced, and nothing moves); an admission makes
+    two: the prompt through the gateway, which the prefill reads, and the
+    first token;
   * the instrument is passive: token streams and the bridge tape are equal
     with the recorder on and off.
 """
@@ -178,28 +182,55 @@ def test_decode_tick_span_tree_and_crossings(recorder):
                    and t.attrs["admitted"] == 0 and not t.attrs["compiled"]]
     assert decode_only
     main = threading.get_ident()
+    ahead = landing = 0
     for t in decode_only:
-        top = [r.name for r in sorted(kids[t.id], key=lambda r: r.start_ns)]
-        assert [n for n in top if n.startswith("engine.")] == [
-            "engine.admit", "engine.dispatch", "engine.sample",
-            "engine.drain", "engine.consume"]
-        drain = next(r for r in kids[t.id] if r.name == "engine.drain")
-        # the engine's thread waits for the tokens and copies them, below
-        # the drain, priced as a worker-channel drain
-        copy = {r.name: r for r in kids[drain.id]}
-        assert {"bridge.wait", "bridge.d2h"} <= set(copy)
-        assert all(r.thread == main for r in _below(kids, t))
-        assert copy["bridge.d2h"].attrs["op_class"] == "worker_drain"
+        top = [r for r in sorted(kids[t.id], key=lambda r: r.start_ns)
+               if r.name.startswith("engine.")]
         below = _below(kids, t)
-        dispatch = next(r for r in kids[t.id] if r.name == "engine.dispatch")
+        assert "engine.sample" not in {r.name for r in below}
+        if top[0].name == "engine.drain":
+            # the next step can end a row: this tick lands the step in
+            # flight alone, with its one crossing
+            assert [r.name for r in top] == ["engine.drain",
+                                             "engine.consume"]
+            assert sum(r.name in CROSSINGS for r in below) == 1
+            landing += 1
+            continue
+        dispatch = top[1]
+        inflight = dispatch.attrs["inflight"]
+        # the step goes first, then the step in flight lands, then this
+        # one unless it stays in flight
+        drains = (len(top) - 2) // 2
+        assert drains in (inflight, inflight + 1)
+        assert [r.name for r in top] == [
+            "engine.admit", "engine.dispatch"] + [
+            "engine.drain", "engine.consume"] * drains
+        # the engine's thread waits for the tokens and copies them, below
+        # each drain, priced as a worker-channel drain
+        for drain in (r for r in top if r.name == "engine.drain"):
+            copy = {r.name: r for r in kids[drain.id]}
+            assert {"bridge.wait", "bridge.d2h"} <= set(copy)
+            assert copy["bridge.d2h"].attrs["op_class"] == "worker_drain"
+        assert all(r.thread == main for r in below)
         ups = [r for r in below if r.name == "bridge.h2d"]
         assert [(r.attrs["op_class"], r.attrs["nbytes"], r.parent)
                 for r in ups] == [
             ("decode_input", 12 * dispatch.attrs["width"], dispatch.id)]
-        assert sum(r.name in CROSSINGS for r in below) == 2
+        crossings = sum(r.name in CROSSINGS for r in below)
+        assert crossings == 1 + drains
+        if inflight and drains == 1:
+            ahead += 1
+            assert crossings == 2
         assert any(r.name == "account" for r in below)
         assert all(t.start_ns <= r.start_ns <= r.end_ns <= t.end_ns
                    for r in below)
+    assert ahead and landing
+    # every step drains once, and the dispatches that found a step in
+    # flight are the engine's count
+    assert sum(r.name == "engine.drain" for r in recs) == len(engine.trace)
+    assert sum(r.attrs["inflight"] for r in recs
+               if r.name == "engine.dispatch") == \
+        engine.stats()["lookahead_steps"] > 0
 
 
 def test_admission_crossings(recorder):
